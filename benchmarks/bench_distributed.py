@@ -30,6 +30,9 @@ def bench_distributed() -> None:
     """Outer harness entry: run the sweep on 8 fake host devices."""
     from repro.launch.mesh import ensure_host_devices
     env = ensure_host_devices(8, dict(os.environ))
+    # the child runs on fake CPU devices: keep it off the chip this
+    # process may hold
+    env["JAX_PLATFORMS"] = "cpu"
     r = subprocess.run([sys.executable, "-m", "benchmarks.bench_distributed"],
                        env=env, capture_output=True, text=True, timeout=1200,
                        cwd=str(Path(__file__).resolve().parents[1]))

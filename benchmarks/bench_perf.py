@@ -33,13 +33,14 @@ def bench_fig9_utilization():
         C.csv_row(f"fig9_cpu_mode{mode}", us,
                   f"tokens={tok};gflops_per_s={gflops:.2f}")
     # analytic v5e projections for the paper-scale configs
-    from repro.launch.roofline import PEAK_FLOPS
+    from repro.launch.roofline import PROJECTION_DEVICE, device_peaks
+    peak = device_peaks(PROJECTION_DEVICE).flops_bf16
     for arch in ("t2i-transformer", "video-dit"):
         full = get_config(arch)
         for mode in range(1 + len(full.dit.flex_patch_sizes)):
             fl = dit_nfe_flops(full, mode)
             tok = dit_mod.tokens_for_mode(full, mode)
-            us_ideal = fl / PEAK_FLOPS * 1e6
+            us_ideal = fl / peak * 1e6
             C.csv_row(f"fig9_v5e_{arch}_mode{mode}", us_ideal,
                       f"tokens={tok};tflops_per_nfe={fl/1e12:.2f}")
     return rows

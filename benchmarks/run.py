@@ -21,9 +21,11 @@ to run any bench when the static-analysis gate fails.
 from __future__ import annotations
 
 import json
+import os
 import subprocess
 import sys
 import time
+import traceback
 from datetime import datetime, timezone
 from pathlib import Path
 
@@ -148,7 +150,9 @@ def main() -> None:
                             bench_telemetry)
     from benchmarks.baseline import BaselineRegression
     from benchmarks.roofline_table import bench_roofline
+    from repro.runtime.compile_cache import enable_compile_cache
 
+    enable_compile_cache()
     benches = [
         ("fig2_spectral", bench_core.bench_fig2_spectral),
         ("fig4_pred_gap", bench_core.bench_fig4_pred_gap),
@@ -178,11 +182,13 @@ def main() -> None:
     if "--strict-analysis" in argv:
         argv.remove("--strict-analysis")
         root = Path(__file__).resolve().parents[1]
-        import os
         env = dict(os.environ)
         env["PYTHONPATH"] = (str(root / "src")
                              + (os.pathsep + env["PYTHONPATH"]
                                 if env.get("PYTHONPATH") else ""))
+        # the analysis CLI only traces jaxprs: keep it off the chip this
+        # process may hold
+        env["JAX_PLATFORMS"] = "cpu"
         rc = subprocess.run(
             [sys.executable, "-m", "repro.analysis", "--strict",
              "src/repro"], cwd=root, env=env).returncode
@@ -204,6 +210,7 @@ def main() -> None:
     sys.stdout = cap
     print("name,us_per_call,derived")
     regressions = []
+    errors = []
     try:
         for name, fn in benches:
             if suite is not None and name not in SUITES[suite]:
@@ -220,6 +227,10 @@ def main() -> None:
                 regressions.append((name, str(e)))
                 print(f"{name},0.0,REGRESSION:{e}", flush=True)
             except Exception as e:  # keep the harness running
+                # ... but fail it at the end: a bench that raised has no
+                # result, and an ERROR row must not read as a pass
+                traceback.print_exc()
+                errors.append((name, f"{type(e).__name__}: {e}"))
                 print(f"{name},0.0,ERROR:{type(e).__name__}:{e}", flush=True)
     finally:
         sys.stdout = cap._wrapped
@@ -230,10 +241,12 @@ def main() -> None:
               flush=True)
         traj = update_trajectory(suite or "all", cap.summaries, sha)
         print(f"# updated trajectory {traj}", flush=True)
-    if regressions:
-        for name, msg in regressions:
-            print(f"# BASELINE REGRESSION in {name}: {msg}",
-                  file=sys.stderr, flush=True)
+    for name, msg in regressions:
+        print(f"# BASELINE REGRESSION in {name}: {msg}",
+              file=sys.stderr, flush=True)
+    for name, msg in errors:
+        print(f"# BENCH ERROR in {name}: {msg}", file=sys.stderr, flush=True)
+    if regressions or errors:
         raise SystemExit(1)
 
 

@@ -50,6 +50,7 @@ import re
 from typing import Any, Callable, Dict, Iterable, List, Tuple
 
 import jax
+import jax.extend.core as jcore
 import jax.numpy as jnp
 import numpy as np
 
@@ -82,7 +83,7 @@ def _digest_value(x: Any) -> str:
 
 
 def _aval_str(v: Any) -> str:
-    if isinstance(v, jax.core.Literal):
+    if isinstance(v, jcore.Literal):
         return f"lit#{_digest_value(v.val)}"
     a = v.aval
     shape = getattr(a, "shape", None)
@@ -94,10 +95,10 @@ def _canon_param(v: Any) -> str:
     """Equation params, canonicalized: sub-jaxprs recurse structurally,
     callables reduce to qualnames, arrays to value digests, and memory
     addresses are stripped from reprs."""
-    if isinstance(v, jax.core.ClosedJaxpr):
+    if isinstance(v, jcore.ClosedJaxpr):
         return "{" + _canon_closed(v) + "}"
-    if isinstance(v, jax.core.Jaxpr):
-        return "{" + _canon_closed(jax.core.ClosedJaxpr(v, ())) + "}"
+    if isinstance(v, jcore.Jaxpr):
+        return "{" + _canon_closed(jcore.ClosedJaxpr(v, ())) + "}"
     if isinstance(v, (tuple, list)):
         return "(" + ",".join(_canon_param(x) for x in v) + ")"
     if isinstance(v, dict):
@@ -110,7 +111,7 @@ def _canon_param(v: Any) -> str:
     return _ADDR_RE.sub("0x", repr(v))
 
 
-def _canon_closed(closed: jax.core.ClosedJaxpr) -> str:
+def _canon_closed(closed: jcore.ClosedJaxpr) -> str:
     j = closed.jaxpr
     parts = ["in:" + ",".join(_aval_str(v) for v in j.invars),
              "const:" + ",".join(
@@ -127,13 +128,13 @@ def _canon_closed(closed: jax.core.ClosedJaxpr) -> str:
     return "\n".join(parts)
 
 
-def fingerprint(closed: jax.core.ClosedJaxpr) -> str:
+def fingerprint(closed: jcore.ClosedJaxpr) -> str:
     """Stable structural digest of a closed jaxpr (incl. constant
     values — baked data is a per-trace recompile hazard)."""
     return hashlib.sha256(_canon_closed(closed).encode()).hexdigest()[:32]
 
 
-def _iter_eqns(closed: jax.core.ClosedJaxpr):
+def _iter_eqns(closed: jcore.ClosedJaxpr):
     """Every equation, recursing into sub-jaxprs (scan/cond/pjit/...)."""
     stack = [closed.jaxpr]
     while stack:
@@ -144,13 +145,13 @@ def _iter_eqns(closed: jax.core.ClosedJaxpr):
                 stack.extend(_sub_jaxprs(v))
 
 
-def _sub_jaxprs(v: Any) -> List[jax.core.Jaxpr]:
-    if isinstance(v, jax.core.ClosedJaxpr):
+def _sub_jaxprs(v: Any) -> List[jcore.Jaxpr]:
+    if isinstance(v, jcore.ClosedJaxpr):
         return [v.jaxpr]
-    if isinstance(v, jax.core.Jaxpr):
+    if isinstance(v, jcore.Jaxpr):
         return [v]
     if isinstance(v, (tuple, list)):
-        out: List[jax.core.Jaxpr] = []
+        out: List[jcore.Jaxpr] = []
         for x in v:
             out.extend(_sub_jaxprs(x))
         return out
@@ -161,7 +162,7 @@ def _sub_jaxprs(v: Any) -> List[jax.core.Jaxpr]:
 # Per-jaxpr violation walks
 
 
-def check_jaxpr(closed: jax.core.ClosedJaxpr, unit: str,
+def check_jaxpr(closed: jcore.ClosedJaxpr, unit: str,
                 path: str = PIPELINE_PATH) -> List[Finding]:
     findings: List[Finding] = []
     for eqn in _iter_eqns(closed):
@@ -173,7 +174,7 @@ def check_jaxpr(closed: jax.core.ClosedJaxpr, unit: str,
                 f"calls back into Python", unit))
         elif name == "convert_element_type":
             src = eqn.invars[0]
-            if isinstance(src, jax.core.Literal):
+            if isinstance(src, jcore.Literal):
                 continue
             if getattr(src.aval, "weak_type", False):
                 continue          # python-scalar promotion, not a leak
@@ -231,7 +232,7 @@ def _drift(unit: str, fps: Dict[str, str], what: str) -> List[Finding]:
 
 
 def _trace(unit: str, fn: Callable, *args
-           ) -> Tuple[jax.core.ClosedJaxpr | None, List[Finding]]:
+           ) -> Tuple[jcore.ClosedJaxpr | None, List[Finding]]:
     try:
         return jax.make_jaxpr(fn)(*args), []
     except Exception as e:      # ConcretizationTypeError, shape leaks, ...
@@ -393,15 +394,15 @@ def audit_cached_runner() -> AuditReport:
     return AuditReport(findings, {"cached_runner": fps.get("interval", "")})
 
 
-def _dce_keep_primary(closed: jax.core.ClosedJaxpr,
-                      n_keep: int) -> jax.core.ClosedJaxpr:
+def _dce_keep_primary(closed: jcore.ClosedJaxpr,
+                      n_keep: int) -> jcore.ClosedJaxpr:
     """Dead-code-eliminate all but the first ``n_keep`` outputs, dropping
     the constants whose constvars die with them."""
     from jax.interpreters import partial_eval as pe
     used = [True] * n_keep + [False] * (len(closed.jaxpr.outvars) - n_keep)
     dj, used_consts, _used_in = pe.dce_jaxpr_consts(closed.jaxpr, used)
     consts = [c for c, u in zip(closed.consts, used_consts) if u]
-    return jax.core.ClosedJaxpr(dj, consts)
+    return jcore.ClosedJaxpr(dj, consts)
 
 
 def audit_tapped_step() -> AuditReport:

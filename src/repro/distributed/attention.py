@@ -27,7 +27,6 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 from repro.configs.base import AttnConfig
@@ -99,9 +98,10 @@ def ulysses_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
         return jax.lax.all_to_all(o, axis, split_axis=1, concat_axis=2,
                                   tiled=True)
 
-    return shard_map(inner, mesh=mesh,
-                     in_specs=(qspec, qspec, qspec, sspec),
-                     out_specs=qspec, check_rep=False)(q, k, v, segment_ids)
+    return jax.shard_map(inner, mesh=mesh,
+                         in_specs=(qspec, qspec, qspec, sspec),
+                         out_specs=qspec, check_vma=False)(q, k, v,
+                                                           segment_ids)
 
 
 def ring_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
@@ -166,9 +166,10 @@ def ring_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
         out = num / jnp.maximum(den, 1e-30)[..., None]
         return out.astype(q.dtype)
 
-    return shard_map(inner, mesh=mesh,
-                     in_specs=(qspec, qspec, qspec, sspec),
-                     out_specs=qspec, check_rep=False)(q, k, v, segment_ids)
+    return jax.shard_map(inner, mesh=mesh,
+                         in_specs=(qspec, qspec, qspec, sspec),
+                         out_specs=qspec, check_vma=False)(q, k, v,
+                                                           segment_ids)
 
 
 ATTN_FNS = {"ulysses": ulysses_attention, "ring": ring_attention}

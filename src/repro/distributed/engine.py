@@ -110,5 +110,8 @@ class SeqParallel:
         out = fn(q, k, v, mesh=self.mesh, axis=self.axis,
                  segment_ids=segment_ids, attn_backend=self.attn_backend)
         # ... and pin the collective's output the same way so downstream
-        # consumers never see a seq-sharded intermediate either.
-        return jax.lax.with_sharding_constraint(out, repl)
+        # consumers never see a seq-sharded intermediate either. On a mesh
+        # with Explicit axes (``jax.make_mesh``'s default) the sharding is
+        # part of the array's type, so this must be a ``reshard``: a bare
+        # constraint would leave the layer scan's carry typed seq-sharded.
+        return jax.sharding.reshard(out, repl)

@@ -55,6 +55,23 @@ ENGINE_KINDS = ("packed", "fixed")
 DEFAULT_SECONDS_PER_TOKEN = 1e-4
 
 
+def replica_pipelines(params: Any, cfg, sched: sch.DiffusionSchedule,
+                      n: int) -> List[FlexiPipeline]:
+    """One pipeline per one-chip replica: replica ``i``'s params are
+    committed to ``jax.devices()[i]`` (round-robin when the process sees
+    fewer devices than replicas), so its jitted steps run on that chip
+    and all replicas are driven from this one process."""
+    devs = jax.devices()
+    return [FlexiPipeline(jax.device_put(params, devs[i % len(devs)]),
+                          cfg, sched) for i in range(n)]
+
+
+def pipe_device(pipe: FlexiPipeline) -> jax.Device:
+    """The device a one-chip pipeline's params live on."""
+    (dev,) = jax.tree.leaves(pipe.params)[0].devices()
+    return dev
+
+
 class ReplicaClock:
     """Per-replica monotonic virtual clock (callable like
     ``time.monotonic``); the pump advances it by modeled dispatch cost."""

@@ -26,14 +26,6 @@ DEFAULT_BLOCK_Q = 128
 DEFAULT_BLOCK_K = 128
 
 
-def effective_blocks(S: int, block_q: int = DEFAULT_BLOCK_Q,
-                     block_k: int = DEFAULT_BLOCK_K) -> Tuple[int, int]:
-    """The (block_q, block_k) a ``flash_attention`` launch actually tiles
-    an S-token sequence with: clamped to S (each axis pads independently
-    to its own block multiple, mirroring the kernel wrapper)."""
-    return min(block_q, S), min(block_k, S)
-
-
 def dense_attention_flops(n_q: int, n_k: int, d_model: int) -> float:
     """QK^T + PV over full [n_q, n_k] scores (one layer, all heads)."""
     return float(2 * 2 * n_q * n_k * d_model)
@@ -59,9 +51,10 @@ def block_map_counts(seg_ids: np.ndarray, *, block_q: int = DEFAULT_BLOCK_Q,
                      block_k: int = DEFAULT_BLOCK_K, causal: bool = False,
                      window: int = 0) -> Tuple[int, int, int, int]:
     """(active, total, bq, bk) kv-block visits for [B, S] segment ids,
-    padded to block multiples exactly as the kernel pads."""
+    padded to block multiples exactly as the kernel pads (a row shorter
+    than a tile is padded up to one whole tile)."""
     B, S = seg_ids.shape
-    bq, bk = effective_blocks(S, block_q, block_k)
+    bq, bk = block_q, block_k
 
     def padded(ids, b):
         pad = (-S) % b

@@ -20,7 +20,9 @@ Padding: sequences are padded internally to block multiples; padded keys
 carry segment id -1 and are never attended, padded query rows are sliced
 off. Rows whose segment has no visible key (e.g. padding queries) return 0.
 
-Validated with ``interpret=True`` on CPU; compiled path targets TPU.
+Compiled to a Mosaic custom call on a TPU; on the CPU the same kernel
+runs through the Pallas interpreter (``repro.kernels.interpret_mode``),
+which is how the tests check it against the dense reference.
 """
 from __future__ import annotations
 
@@ -33,26 +35,22 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels import interpret_mode
 from repro.kernels.attention import mask as mask_mod
 from repro.runtime.padding import pad_to
-
-# jax 0.4.x names this TPUCompilerParams; 0.5+ renamed it CompilerParams
-_CompilerParams = getattr(pltpu, "CompilerParams",
-                          getattr(pltpu, "TPUCompilerParams", None))
 
 NEG_INF = -1e30
 
 
-def _flash_kernel(*refs, causal: bool, softcap: float, window: int,
-                  block_q: int, block_k: int, sm_scale: float, num_kv: int,
-                  segmented: bool):
+def _flash_kernel(bmap_ref, *refs, causal: bool, softcap: float,
+                  window: int, block_q: int, block_k: int, sm_scale: float,
+                  num_q: int, num_kv: int, heads: int, segmented: bool):
     if segmented:
-        (bmap_ref, qseg_ref, kseg_ref, q_ref, k_ref, v_ref, o_ref,
+        (qseg_ref, kseg_ref, q_ref, k_ref, v_ref, o_ref,
          m_scr, l_scr, acc_scr) = refs
     else:
-        (bmap_ref, q_ref, k_ref, v_ref, o_ref,
-         m_scr, l_scr, acc_scr) = refs
-        qseg_ref = kseg_ref = None
+        (q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr) = refs
+    bh = pl.program_id(0)
     qi = pl.program_id(1)
     ki = pl.program_id(2)
 
@@ -64,8 +62,9 @@ def _flash_kernel(*refs, causal: bool, softcap: float, window: int,
 
     # Skip the whole score tile when the block map proves it fully masked
     # (cross-segment, outside the window, or acausal). The map is traced
-    # data: layout switches replay this executable.
-    @pl.when(bmap_ref[0, 0, 0] > 0)
+    # data in SMEM (scalar prefetch): layout switches replay this
+    # executable.
+    @pl.when(bmap_ref[((bh // heads) * num_q + qi) * num_kv + ki] > 0)
     def _visit():
         q = q_ref[0]                                   # [bq, hd]
         k = k_ref[0]                                   # [bk, hd]
@@ -84,7 +83,9 @@ def _flash_kernel(*refs, causal: bool, softcap: float, window: int,
         allowed = mask_mod.position_allowed_grid(q_pos, k_pos, causal=causal,
                                                  window=window)
         if segmented:
-            allowed &= mask_mod.segment_allowed(qseg_ref[0], kseg_ref[0])
+            # q ids arrive as a [bq, 1] column, kv ids as a [1, bk] row
+            allowed &= mask_mod.segment_allowed_grid(qseg_ref[0],
+                                                     kseg_ref[0])
 
         # Streaming softmax with fully-masked-tile safety: probabilities
         # are zeroed where masked (a conservative block map may admit a
@@ -107,14 +108,13 @@ def _flash_kernel(*refs, causal: bool, softcap: float, window: int,
 
 
 @functools.partial(jax.jit, static_argnames=(
-    "causal", "softcap", "window", "block_q", "block_k", "interpret"))
+    "causal", "softcap", "window", "block_q", "block_k"))
 def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
                     causal: bool = True, softcap: float = 0.0,
                     window: int = 0,
                     segment_ids: Optional[jax.Array] = None,
                     block_map: Optional[jax.Array] = None,
-                    block_q: int = 128, block_k: int = 128,
-                    interpret: bool = True) -> jax.Array:
+                    block_q: int = 128, block_k: int = 128) -> jax.Array:
     """q: [B,S,H,hd]; k,v: [B,Sk,K,hd] (GQA) → [B,S,H,hd].
 
     ``segment_ids``: optional [B, S] int32 shared by queries and keys
@@ -124,14 +124,14 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
     derived from the segment ids / causal / window envelope when absent.
     Both are traced operands — pack-layout switches never recompile.
 
-    ``interpret=True`` runs the kernel body on CPU (this container);
-    pass False on real TPU hardware.
+    Tiles are ``block_q`` x ``block_k`` whatever the sequence length:
+    a row shorter than a tile is padded up to it, never given a smaller
+    tile, so every compiled tile stays lane-aligned.
     """
     B, S, H, hd = q.shape
     Sk, K = k.shape[1], k.shape[2]
     G = H // K
-    bq = min(block_q, S)
-    bk = min(block_k, Sk)
+    bq, bk = block_q, block_k
     nq = -(-S // bq)
     nk = -(-Sk // bk)
     Sp, Skp = nq * bq, nk * bk
@@ -164,40 +164,46 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
 
     kernel = functools.partial(
         _flash_kernel, causal=causal, softcap=softcap, window=window,
-        block_q=bq, block_k=bk, sm_scale=1.0 / np.sqrt(hd), num_kv=nk,
-        segmented=segmented)
+        block_q=bq, block_k=bk, sm_scale=1.0 / np.sqrt(hd), num_q=nq,
+        num_kv=nk, heads=H, segmented=segmented)
 
-    in_specs = [
-        pl.BlockSpec((1, 1, 1), lambda b, i, j, H=H: (b // H, i, j),
-                     memory_space=pltpu.SMEM),
-    ]
-    inputs = [jnp.asarray(block_map, jnp.int32)]
+    # Mosaic tiles the last two block dims by (8, 128) unless a dim spans
+    # the whole array: q ids go in as [B, Sp, 1] columns and kv ids as
+    # [B, 1, Skp] rows, so each tile is (bq, 1) / (1, bk). The block map
+    # is flattened into SMEM by scalar prefetch (the kernel's first ref;
+    # index maps receive it as a trailing argument).
+    in_specs = []
+    inputs = []
     if segmented:
         in_specs += [
-            pl.BlockSpec((1, bq), lambda b, i, j, H=H: (b // H, i)),
-            pl.BlockSpec((1, bk), lambda b, i, j, H=H: (b // H, j)),
+            pl.BlockSpec((1, bq, 1), lambda b, i, j, bm, H=H: (b // H, i, 0)),
+            pl.BlockSpec((1, 1, bk), lambda b, i, j, bm, H=H: (b // H, 0, j)),
         ]
-        inputs += [q_seg, k_seg]
+        inputs += [q_seg[:, :, None], k_seg[:, None, :]]
     in_specs += [
-        pl.BlockSpec((1, bq, hd), lambda b, i, j: (b, i, 0)),
-        pl.BlockSpec((1, bk, hd), lambda b, i, j, G=G: (b // G, j, 0)),
-        pl.BlockSpec((1, bk, hd), lambda b, i, j, G=G: (b // G, j, 0)),
+        pl.BlockSpec((1, bq, hd), lambda b, i, j, bm: (b, i, 0)),
+        pl.BlockSpec((1, bk, hd), lambda b, i, j, bm, G=G: (b // G, j, 0)),
+        pl.BlockSpec((1, bk, hd), lambda b, i, j, bm, G=G: (b // G, j, 0)),
     ]
     inputs += [qt, kt, vt]
 
     out = pl.pallas_call(
         kernel,
-        grid=(B * H, nq, nk),
-        in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, bq, hd), lambda b, i, j: (b, i, 0)),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(B * H, nq, nk),
+            in_specs=in_specs,
+            out_specs=pl.BlockSpec((1, bq, hd),
+                                   lambda b, i, j, bm: (b, i, 0)),
+            scratch_shapes=[
+                pltpu.VMEM((bq,), jnp.float32),
+                pltpu.VMEM((bq,), jnp.float32),
+                pltpu.VMEM((bq, hd), jnp.float32),
+            ]),
         out_shape=jax.ShapeDtypeStruct((B * H, Sp, hd), q.dtype),
-        scratch_shapes=[
-            pltpu.VMEM((bq,), jnp.float32),
-            pltpu.VMEM((bq,), jnp.float32),
-            pltpu.VMEM((bq, hd), jnp.float32),
-        ],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
-        interpret=interpret,
-    )(*inputs)
+        interpret=interpret_mode(),
+        name="flash_attention",
+    )(jnp.asarray(block_map, jnp.int32).reshape(-1), *inputs)
     return out.reshape(B, H, Sp, hd).transpose(0, 2, 1, 3)[:, :S]

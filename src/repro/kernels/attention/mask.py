@@ -44,9 +44,13 @@ def segment_allowed(q_seg, k_seg):
     Tokens attend only within their own segment; ids < 0 mark padding,
     which never attends (query side) nor is attended to (key side).
     """
-    xp = _xp(q_seg, k_seg)
-    qs = q_seg[..., :, None]
-    ks = k_seg[..., None, :]
+    return segment_allowed_grid(q_seg[..., :, None], k_seg[..., None, :])
+
+
+def segment_allowed_grid(qs, ks):
+    """Elementwise segment mask over broadcast-compatible grids: the
+    Pallas tile path feeds a [bq, 1] query column and a [1, bk] key row."""
+    xp = _xp(qs, ks)
     return xp.logical_and(xp.logical_and(qs == ks, qs >= 0), ks >= 0)
 
 

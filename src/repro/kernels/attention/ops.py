@@ -1,14 +1,8 @@
-"""Jitted public wrapper for the flash attention kernel."""
+"""Jitted public wrapper for the flash attention kernel (compiled on a
+TPU, interpreted on the CPU: ``repro.kernels.interpret_mode``)."""
 from __future__ import annotations
 
-import jax
-
 from repro.kernels.attention.flash_attention import flash_attention as _fa
-
-# On this CPU-only container the kernel body executes via interpret mode;
-# on TPU set REPRO_PALLAS_INTERPRET=0.
-import os
-INTERPRET = os.environ.get("REPRO_PALLAS_INTERPRET", "1") == "1"
 
 
 def flash_attention(q, k, v, *, causal=True, softcap=0.0, window=0,
@@ -16,7 +10,7 @@ def flash_attention(q, k, v, *, causal=True, softcap=0.0, window=0,
                     block_q=128, block_k=128):
     return _fa(q, k, v, causal=causal, softcap=softcap, window=window,
                segment_ids=segment_ids, block_map=block_map,
-               block_q=block_q, block_k=block_k, interpret=INTERPRET)
+               block_q=block_q, block_k=block_k)
 
 
 def compile_cache_size() -> int:

@@ -1,6 +1,8 @@
 """Pure-jnp oracle for the flash attention kernel."""
 from __future__ import annotations
 
+from typing import Optional
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -8,8 +10,13 @@ import numpy as np
 
 def attention_ref(q: jax.Array, k: jax.Array, v: jax.Array, *,
                   causal: bool = True, softcap: float = 0.0,
-                  window: int = 0) -> jax.Array:
-    """q: [B,S,H,hd]; k,v: [B,Sk,K,hd] (GQA) → [B,S,H,hd], f32 math."""
+                  window: int = 0,
+                  segment_ids: Optional[jax.Array] = None) -> jax.Array:
+    """q: [B,S,H,hd]; k,v: [B,Sk,K,hd] (GQA) → [B,S,H,hd], f32 math.
+
+    ``segment_ids`` ([B, S], self-attention only): tokens attend within
+    their own segment; ids < 0 are padding, which attends to nothing and
+    returns 0, and is never attended."""
     B, S, H, hd = q.shape
     Sk, K = k.shape[1], k.shape[2]
     G = H // K
@@ -25,7 +32,15 @@ def attention_ref(q: jax.Array, k: jax.Array, v: jax.Array, *,
         mask &= qp >= kp
     if window > 0:
         mask &= (qp - kp < window) & (kp - qp < window)
-    s = jnp.where(mask[None, None, None], s, -1e30)
+    mask = jnp.broadcast_to(mask, (B, S, Sk))
+    if segment_ids is not None:
+        qs = segment_ids[:, :, None]
+        ks = segment_ids[:, None, :]
+        mask = mask & (qs == ks) & (qs >= 0) & (ks >= 0)
+    s = jnp.where(mask[:, None, None], s, -1e30)
     p = jax.nn.softmax(s, axis=-1)
     o = jnp.einsum("bkgqs,bskh->bqkgh", p, v.astype(jnp.float32))
-    return o.reshape(B, S, H, hd).astype(q.dtype)
+    o = o.reshape(B, S, H, hd)
+    if segment_ids is not None:
+        o = jnp.where((segment_ids >= 0)[:, :, None, None], o, 0.0)
+    return o.astype(q.dtype)

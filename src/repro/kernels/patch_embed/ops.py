@@ -6,7 +6,6 @@ before the kernel runs, so mode switching costs nothing per NFE).
 """
 from __future__ import annotations
 
-import os
 from typing import Tuple
 
 import jax
@@ -16,8 +15,6 @@ from repro.core import patch as patch_mod
 from repro.core import resize
 from repro.kernels.patch_embed.patch_embed import (patch_deembed_pallas,
                                                    patch_embed_pallas)
-
-INTERPRET = os.environ.get("REPRO_PALLAS_INTERPRET", "1") == "1"
 
 Patch = Tuple[int, int, int]
 
@@ -33,7 +30,7 @@ def embed_tokens_flex(w_flex, b, x, p: Patch, p_prime: Patch,
     tok = patch_embed_pallas(flat, W.reshape(K, d).astype(x.dtype),
                              b.astype(x.dtype),
                              block_n=min(block_n, B * N),
-                             block_d=min(block_d, d), interpret=INTERPRET)
+                             block_d=min(block_d, d))
     return tok.reshape(B, N, d)
 
 
@@ -47,8 +44,7 @@ def deembed_tokens_flex(w_flex, b_flex, tok, latent_shape, p: Patch,
     out = patch_deembed_pallas(tok.reshape(B * N, d),
                                W.reshape(d, K).astype(tok.dtype),
                                Bb.reshape(K).astype(tok.dtype),
-                               block_n=min(block_n, B * N),
-                               interpret=INTERPRET)
+                               block_n=min(block_n, B * N))
     # kernel output layout is [.., c*pp]; unpatchify expects [.., pp, c]
     pp = W.shape[2]
     patches = out.reshape(B, N, c_out, pp).transpose(0, 1, 3, 2)

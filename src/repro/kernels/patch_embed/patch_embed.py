@@ -17,9 +17,7 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# jax 0.4.x names this TPUCompilerParams; 0.5+ renamed it CompilerParams
-_CompilerParams = getattr(pltpu, "CompilerParams",
-                          getattr(pltpu, "TPUCompilerParams", None))
+from repro.kernels import interpret_mode
 
 
 def _embed_kernel(x_ref, w_ref, b_ref, o_ref):
@@ -30,10 +28,9 @@ def _embed_kernel(x_ref, w_ref, b_ref, o_ref):
     o_ref[...] = (acc + b_ref[...].astype(jnp.float32)[None]).astype(o_ref.dtype)
 
 
-@functools.partial(jax.jit, static_argnames=("block_n", "block_d", "interpret"))
+@functools.partial(jax.jit, static_argnames=("block_n", "block_d"))
 def patch_embed_pallas(patches: jax.Array, w: jax.Array, b: jax.Array, *,
-                       block_n: int = 256, block_d: int = 256,
-                       interpret: bool = True) -> jax.Array:
+                       block_n: int = 256, block_d: int = 256) -> jax.Array:
     """patches: [N, K] (K = p_f·p_h·p_w·c); w: [K, d]; b: [d] → [N, d]."""
     N, K = patches.shape
     d = w.shape[1]
@@ -51,9 +48,9 @@ def patch_embed_pallas(patches: jax.Array, w: jax.Array, b: jax.Array, *,
         ],
         out_specs=pl.BlockSpec((bn, bd), lambda i, j: (i, j)),
         out_shape=jax.ShapeDtypeStruct((N, d), patches.dtype),
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel")),
-        interpret=interpret,
+        interpret=interpret_mode(),
     )(patches, w, b)
 
 
@@ -65,10 +62,9 @@ def _deembed_kernel(t_ref, w_ref, b_ref, o_ref):
     o_ref[...] = (acc + b_ref[...].astype(jnp.float32)[None]).astype(o_ref.dtype)
 
 
-@functools.partial(jax.jit, static_argnames=("block_n", "interpret"))
+@functools.partial(jax.jit, static_argnames=("block_n",))
 def patch_deembed_pallas(tokens: jax.Array, w: jax.Array, b: jax.Array, *,
-                         block_n: int = 256,
-                         interpret: bool = True) -> jax.Array:
+                         block_n: int = 256) -> jax.Array:
     """tokens: [N, d]; w: [d, K_out]; b: [K_out] → [N, K_out]."""
     N, d = tokens.shape
     K = w.shape[1]
@@ -85,7 +81,7 @@ def patch_deembed_pallas(tokens: jax.Array, w: jax.Array, b: jax.Array, *,
         ],
         out_specs=pl.BlockSpec((bn, K), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((N, K), tokens.dtype),
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel",)),
-        interpret=interpret,
+        interpret=interpret_mode(),
     )(tokens, w, b)

@@ -2,15 +2,12 @@
 recurrence. Drop-in for ``repro.models.ssm.ssd_chunked``."""
 from __future__ import annotations
 
-import os
 from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 
 from repro.kernels.ssd.ssd_chunk import ssd_chunk_pallas
-
-INTERPRET = os.environ.get("REPRO_PALLAS_INTERPRET", "1") == "1"
 
 
 def ssd(x: jax.Array, dt: jax.Array, A: jax.Array, Bm: jax.Array,
@@ -25,8 +22,7 @@ def ssd(x: jax.Array, dt: jax.Array, A: jax.Array, Bm: jax.Array,
         dt = jnp.pad(dt, ((0, 0), (0, pad), (0, 0)))
         Bm = jnp.pad(Bm, ((0, 0), (0, pad), (0, 0)))
         Cm = jnp.pad(Cm, ((0, 0), (0, pad), (0, 0)))
-    y_intra, Sc, Ltot = ssd_chunk_pallas(x, dt, A, Bm, Cm, chunk=chunk,
-                                         interpret=INTERPRET)
+    y_intra, Sc, Ltot = ssd_chunk_pallas(x, dt, A, Bm, Cm, chunk=chunk)
     N = Bm.shape[-1]
     if h0 is None:
         h0 = jnp.zeros((B, H, P, N), jnp.float32)

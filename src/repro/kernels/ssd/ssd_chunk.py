@@ -20,9 +20,7 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# jax 0.4.x names this TPUCompilerParams; 0.5+ renamed it CompilerParams
-_CompilerParams = getattr(pltpu, "CompilerParams",
-                          getattr(pltpu, "TPUCompilerParams", None))
+from repro.kernels import interpret_mode
 
 
 def _ssd_kernel(x_ref, dt_ref, a_ref, b_ref, c_ref,
@@ -56,10 +54,9 @@ def _ssd_kernel(x_ref, dt_ref, a_ref, b_ref, c_ref,
     ltot_ref[0] = Ltot
 
 
-@functools.partial(jax.jit, static_argnames=("chunk", "interpret"))
+@functools.partial(jax.jit, static_argnames=("chunk",))
 def ssd_chunk_pallas(x: jax.Array, dt: jax.Array, A: jax.Array,
-                     Bm: jax.Array, Cm: jax.Array, *, chunk: int,
-                     interpret: bool = True):
+                     Bm: jax.Array, Cm: jax.Array, *, chunk: int):
     """x: [B,S,H,P]; dt: [B,S,H]; A: [H]; Bm/Cm: [B,S,N].
     Returns (y_intra [B,S,H,P], Sc [B,nc,H,P,N], Ltot [B,nc,H])."""
     B, S, H, P = x.shape
@@ -91,9 +88,9 @@ def ssd_chunk_pallas(x: jax.Array, dt: jax.Array, A: jax.Array,
             jax.ShapeDtypeStruct((B * nc, H, P, N), jnp.float32),
             jax.ShapeDtypeStruct((B * nc, H), jnp.float32),
         ],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel",)),
-        interpret=interpret,
+        interpret=interpret_mode(),
     )(xc, dtc, A, Bc, Cc)
     return (y.reshape(B, nc, chunk, H, P).reshape(B, S, H, P),
             sc.reshape(B, nc, H, P, N),

@@ -7,20 +7,50 @@ Sources (per the assignment):
     all-gather / all-reduce / reduce-scatter / all-to-all /
     collective-permute and sum operand sizes.
 
-Hardware model (TPU v5e-class, per chip):
-  197 TFLOP/s bf16 · 819 GB/s HBM · ~50 GB/s/link ICI · 16 GiB HBM.
+Hardware model: per-chip peaks from :data:`PEAKS`, keyed by the
+``device_kind`` JAX reports. A device missing from the table is an error,
+never a default.
 """
 from __future__ import annotations
 
+import dataclasses
 import re
 from typing import Any, Dict, List, Optional, Tuple
 
 import numpy as np
 
-PEAK_FLOPS = 197e12
-HBM_BW = 819e9
-ICI_BW = 50e9
-HBM_BYTES = 16 * 1024 ** 3
+
+@dataclasses.dataclass(frozen=True)
+class DevicePeaks:
+    """Published per-chip peaks of one accelerator generation."""
+    flops_bf16: float        # FLOP/s
+    hbm_bw: float            # bytes/s
+    ici_bw: float            # bytes/s per link
+    hbm_bytes: float
+    source: str
+
+
+#: Keyed by ``jax.devices()[0].device_kind``.
+PEAKS: Dict[str, DevicePeaks] = {
+    "TPU v5 lite": DevicePeaks(
+        flops_bf16=197e12, hbm_bw=819e9, ici_bw=50e9, hbm_bytes=16e9,
+        source="Google Cloud TPU documentation, 'TPU v5e': 197 TFLOP/s "
+               "bf16, 16 GB HBM at 819 GB/s, 1,600 Gbit/s ICI per chip "
+               "(4 links, ~50 GB/s each)"),
+}
+
+#: The chip this repo's projections (dry runs, analytic benches) target.
+PROJECTION_DEVICE = "TPU v5 lite"
+
+
+def device_peaks(device_kind: str) -> DevicePeaks:
+    """The peak table's row for ``device_kind``; raises for a device the
+    table does not hold."""
+    try:
+        return PEAKS[device_kind]
+    except KeyError:
+        raise ValueError(f"no published peaks for device kind "
+                         f"{device_kind!r}; known: {sorted(PEAKS)}") from None
 
 COLLECTIVES = ("all-gather", "all-reduce", "reduce-scatter", "all-to-all",
                "collective-permute")
@@ -92,15 +122,17 @@ def parse_collectives(hlo_text: str) -> Dict[str, Dict[str, float]]:
 
 
 def roofline_terms(cost: Dict[str, float], collectives: Dict[str, Dict],
-                   n_devices: int, model_flops_global: Optional[float] = None
+                   n_devices: int, model_flops_global: Optional[float] = None,
+                   *, device_kind: str = PROJECTION_DEVICE
                    ) -> Dict[str, Any]:
+    peaks = device_peaks(device_kind)
     flops_dev = float(cost.get("flops", 0.0) or 0.0)
     bytes_dev = float(cost.get("bytes accessed", 0.0) or 0.0)
     coll_operand = sum(v["operand_bytes"] for v in collectives.values())
     coll_wire = sum(v["wire_bytes"] for v in collectives.values())
-    t_compute = flops_dev / PEAK_FLOPS
-    t_memory = bytes_dev / HBM_BW
-    t_coll = coll_wire / ICI_BW
+    t_compute = flops_dev / peaks.flops_bf16
+    t_memory = bytes_dev / peaks.hbm_bw
+    t_coll = coll_wire / peaks.ici_bw
     terms = {"compute_s": t_compute, "memory_s": t_memory,
              "collective_s": t_coll,
              "hlo_flops_per_device": flops_dev,

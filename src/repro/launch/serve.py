@@ -273,6 +273,12 @@ def _serve_dit_engine(cfg, args, pipe, plans) -> None:
             # harvest AOT compiled costs for the whole warm set and
             # reconcile: analytic ledger vs XLA vs measured wall
             hv = telemetry.profile.harvest(pipe)
+            if hv["errors"]:
+                failed = [f"{r.label}: {r.error}"
+                          for r in telemetry.profile.records.values()
+                          if r.error is not None]
+                raise SystemExit(f"[profile] {hv['errors']} runner(s) "
+                                 f"failed to compile: {failed}")
             hstats = engine.cache_stats()
             assert hstats["compiled"] == stats["compiled"], \
                 "AOT cost harvest must not touch the jit compile cache"
@@ -327,12 +333,15 @@ def _serve_dit_engine(cfg, args, pipe, plans) -> None:
 def _serve_dit_fleet(cfg, args) -> None:
     """The fleet path (DESIGN.md §fleet): ``--replicas N`` in-process
     replica engines behind the router. Without ``--mesh`` every replica
-    is a packed continuous-batching engine sharing one pipeline; with
+    is a packed continuous-batching engine with its own pipeline, its
+    params placed on its own device (round-robin when there are fewer
+    devices than replicas); with
     ``--mesh DATAxSEQ`` (DATA == N) each replica is a fixed-slot engine
     over its own contiguous SEQ-wide device slice, so sequence-parallel
     sharding composes with fleet routing."""
     from repro.diffusion import schedule as sch
     from repro.fleet import Fleet, partition_devices
+    from repro.fleet.replica import pipe_device, replica_pipelines
     from repro.launch.mesh import parse_mesh_arg
     from repro.models import dit as dit_mod
     from repro.pipeline import FlexiPipeline, ParallelSpec
@@ -371,17 +380,18 @@ def _serve_dit_fleet(cfg, args) -> None:
         engine_kwargs = {"policy": getattr(args, "policy", None) or "fifo",
                          "max_tokens_per_step":
                              getattr(args, "max_tokens_per_step", None)}
-    pipe = pipes[0] if pipes else FlexiPipeline(params, cfg, sched)
-    fleet = Fleet(pipe, plans, n, router=args.router,
+    if pipes is None:
+        # one-chip replicas: each owns a pipeline whose params live on its
+        # own device, so the replicas' steps run on distinct chips
+        pipes = replica_pipelines(params, cfg, sched, n)
+        print(f"[replicas] {n} packed replica(s) on devices "
+              f"{[pipe_device(p).id for p in pipes]}")
+    # warm each replica's small-cohort ladder off the serving path
+    fleet = Fleet(pipes[0], plans, n, router=args.router,
                   pipes=pipes, engine_kind=engine_kind,
                   seq_parallel=s_sz, batch_size=args.batch_slots,
-                  engine_kwargs=engine_kwargs)
-    if engine_kind == "packed":
-        # warm the small-cohort ladder off the serving path: replicas
-        # share one pipeline, so one background walk warms them all
-        from repro.fleet import BackgroundCompiler
-        fleet.warmers[0] = BackgroundCompiler(
-            fleet.replicas[0].engine, name="serve-warm").start()
+                  engine_kwargs=engine_kwargs,
+                  warm_background=True)
 
     levels = sorted(plans)
     rng = np.random.default_rng(0)
@@ -610,11 +620,17 @@ def main():
     ap.add_argument("--cfg-scale", type=float, default=1.5)
     args = ap.parse_args()
 
-    if args.mesh:
+    if args.mesh and os.environ.get("JAX_PLATFORMS") == "cpu":
         # CPU smoke runs: make sure enough host devices exist BEFORE the
-        # jax backend initializes.
+        # jax backend initializes. On an accelerator the mesh is built
+        # from its real devices.
         from repro.launch.mesh import ensure_host_devices, parse_mesh_arg
         ensure_host_devices(int(np.prod(parse_mesh_arg(args.mesh))))
+    from repro.runtime.compile_cache import enable_compile_cache
+    enable_compile_cache()
+    devs = jax.devices()
+    print(f"[device] platform={devs[0].platform} "
+          f"kind={devs[0].device_kind} count={len(devs)}")
 
     cfg = get_config(args.arch)
     if args.smoke:

@@ -138,23 +138,10 @@ def shard_tree(mesh: Mesh, spec_pytree: Any) -> Any:
 
 
 def _ambient_axis_names() -> Tuple[str, ...]:
-    """Axis names of the ambient mesh, () if none is set. Handles the jax
-    0.4.x API (no public get_abstract_mesh; ``with mesh:`` sets the
-    thread-local physical mesh) and the 0.5+ AbstractMesh API."""
-    get = getattr(jax.sharding, "get_abstract_mesh", None)
-    if get is not None:
-        mesh = get()
-        if mesh is None or mesh.empty:
-            return ()
-        return tuple(mesh.axis_names)
-    from jax._src import mesh as mesh_lib
-    mesh = mesh_lib.get_abstract_mesh()
-    if mesh and getattr(mesh, "axis_names", None):
-        return tuple(mesh.axis_names)
-    phys = mesh_lib.thread_resources.env.physical_mesh
-    if phys is not None and not phys.empty:
-        return tuple(phys.axis_names)
-    return ()
+    """Axis names of the ambient mesh set by ``jax.set_mesh``, () if none
+    is set."""
+    mesh = jax.sharding.get_abstract_mesh()
+    return () if mesh.empty else tuple(mesh.axis_names)
 
 
 def constrain(x: jax.Array, spec: P) -> jax.Array:

@@ -162,6 +162,12 @@ class ServingEngine:
         self._expire_queued = expire_queued
         self.pipe = pipe
         self.cfg = pipe.cfg
+        # the one device the params live on: every dispatch input is
+        # committed there, so warm-up and served dispatches share one
+        # signature per runner and host uploads go straight to that chip
+        devs = {d for a in jax.tree.leaves(pipe.params)
+                if isinstance(a, jax.Array) for d in a.devices()}
+        self._device = devs.pop() if len(devs) == 1 else None
         self.clock = clock or time.monotonic
         # telemetry (DESIGN.md §telemetry): spans stamp the engine's own
         # clock; taps route every dispatch through the tapped step family
@@ -468,7 +474,14 @@ class ServingEngine:
                 z = self._zero_blocks[pad] = jnp.zeros(
                     (pad,) + self.cfg.dit.latent_shape)
             parts.append(z)
-        return parts[0] if len(parts) == 1 else jnp.concatenate(parts)
+        return self._put(parts[0] if len(parts) == 1
+                         else jnp.concatenate(parts))
+
+    def _put(self, a) -> jax.Array:
+        """A dispatch input on the engine's device."""
+        if self._device is None:
+            return jnp.asarray(a)
+        return jax.device_put(a, self._device)
 
     # ------------------------------------------------------------------
     # Warm-set shaping
@@ -533,16 +546,17 @@ class ServingEngine:
             attn_backend=self.attn_backend, taps=self._taps)
         xs, metas, keys, deltas, refreshes = [], [], [], [], []
         for mode, cap in layout.groups:
-            xs.append(jnp.zeros((cap,) + self.cfg.dit.latent_shape))
+            xs.append(self._put(np.zeros((cap,) + self.cfg.dit.latent_shape,
+                                         np.float32)))
             meta = np.zeros((k, 3, cap), np.int32)
             meta[:, 1, :] = -1
-            metas.append(jnp.asarray(meta))
-            keys.append(jnp.zeros((k, cap, 2), jnp.uint32))
+            metas.append(self._put(meta))
+            keys.append(self._put(np.zeros((k, cap, 2), np.uint32)))
             if self.cache is not None:
-                deltas.append(jnp.zeros(
+                deltas.append(self._put(jnp.zeros(
                     (cap, self.store.mult, self._seg_tokens[mode],
-                     self.cfg.d_model), self.store.dtype))
-                refreshes.append(jnp.zeros((k, cap), bool))
+                     self.cfg.d_model), self.store.dtype)))
+                refreshes.append(self._put(np.zeros((k, cap), bool)))
         if self.cache is not None:
             out = runner(self.pipe.params, tuple(xs), tuple(metas),
                          tuple(keys), tuple(deltas), tuple(refreshes))
@@ -699,11 +713,11 @@ class ServingEngine:
                         f.refresh_mask[s:s + k] = True
                     rf[:, i] = f.refresh_mask[s:s + k]
                     slots.append(f.cache_slot)
-            metas.append(jnp.asarray(meta))
-            keys.append(jnp.asarray(kk))
+            metas.append(self._put(meta))
+            keys.append(self._put(kk))
             real_tokens += mult * self._seg_tokens[mode] * len(sel) * k
             if self.cache is not None:
-                refreshes.append(jnp.asarray(rf))
+                refreshes.append(self._put(rf))
                 slot_lists.append(slots)
                 rf_real.append(rf[:, :len(sel)])
                 if slots and min(slots) < 0:
@@ -720,7 +734,7 @@ class ServingEngine:
                                    self.cfg.d_model), self.store.dtype)
                     gathered = (z if gathered is None
                                 else jnp.concatenate([gathered, z]))
-                deltas.append(gathered)
+                deltas.append(self._put(gathered))
 
         step_flops = 0.0
         if self.cache is not None:

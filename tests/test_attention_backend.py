@@ -1,12 +1,12 @@
 """Segment-aware Pallas flash attention as the unified backend
 (DESIGN.md §attention-backend).
 
-Property tests (``interpret=True``): the kernel matches the dense XLA
-reference to ≤1e-4 on randomized pack layouts (ragged segments, padding,
-window/softcap combos, GQA ratios), the block map is always a superset
-of the elementwise mask, pack-layout switches under a fixed bucket shape
-never recompile, and the packed step family (ddim AND ddpm) is
-backend-consistent end to end.
+Property tests (the Pallas interpreter, on the CPU): the kernel matches
+the dense XLA reference to ≤1e-4 on randomized pack layouts (ragged
+segments, padding, window/softcap combos, GQA ratios), the block map is
+always a superset of the elementwise mask, pack-layout switches under a
+fixed bucket shape never recompile, and the packed step family (ddim AND
+ddpm) is backend-consistent end to end.
 """
 import dataclasses
 
@@ -291,7 +291,7 @@ def test_block_sparse_pack_pricing(tiny_dit_cfg):
     active, total = packing.pack_attention_block_stats(fcfg, [1] * r, N0)
     assert active < total
     d, L = fcfg.d_model, fcfg.num_layers
-    bq, bk = costing.effective_blocks(N0)
+    bq, bk = costing.DEFAULT_BLOCK_Q, costing.DEFAULT_BLOCK_K
     expect = L * (total - active) * costing.dense_attention_flops(bq, bk, d)
     assert dense_row - sparse_row == pytest.approx(expect)
 

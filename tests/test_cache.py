@@ -2,7 +2,7 @@
 
 The load-bearing asserts: interval=1 (refresh every step) is
 BIT-IDENTICAL to uncached sampling for ddim AND ddpm on both the plain
-pipeline and the packed engine path; interval k>1 drifts boundedly;
+pipeline and the packed engine path (each against its own uncached run); interval k>1 drifts boundedly;
 policy switches on a warm runner never recompile; and engine cache
 slots are released on retire and reused across join/leave.
 """
@@ -283,15 +283,11 @@ def _reference(pipe, plans, level, label, key):
                                   cond=jnp.asarray([label], jnp.int32)).x0[0])
 
 
-@pytest.mark.parametrize("solver", ["ddim", "ddpm"])
-def test_engine_interval1_bit_identical_packed(pipe, solver):
-    """Packed cached dispatches at interval=1 reproduce the UNCACHED
-    per-request pipeline bit-for-bit — requests join and leave
-    mid-flight, so slots churn while exactness holds."""
+def _serve_join_leave(pipe, solver, cache):
+    """Three requests, a fourth joining two steps in; returns
+    ``{id: (x0, level, label, key)}`` and the engine."""
     plans = make_plans(solver)
-    eng = ServingEngine(pipe, plans, max_tokens_per_step=256,
-                        cache=CacheSpec(policy="interval", interval=1,
-                                        split=1))
+    eng = ServingEngine(pipe, plans, max_tokens_per_step=256, cache=cache)
     spec = [(0, 0.6, 3), (1, 1.0, 7), (2, 0.6, 5)]
     keys = {rid: jax.random.PRNGKey(60 + rid) for rid, _, _ in spec}
     for rid, lvl, label in spec:
@@ -304,10 +300,33 @@ def test_engine_interval1_bit_identical_packed(pipe, solver):
     keys[late] = jax.random.PRNGKey(99)
     results += eng.run()
     assert len(results) == 4
+    out = {}
     for r in results:
         _, lvl, label = next(s for s in spec if s[0] == r.request.id)
-        ref = _reference(pipe, plans, lvl, label, keys[r.request.id])
-        np.testing.assert_array_equal(np.asarray(r.x0), ref)
+        out[r.request.id] = (np.asarray(r.x0), lvl, label,
+                             keys[r.request.id])
+    return out, eng
+
+
+@pytest.mark.parametrize("solver", ["ddim", "ddpm"])
+def test_engine_interval1_bit_identical_packed(pipe, solver):
+    """Packed cached dispatches at interval=1 reproduce the UNCACHED
+    packed engine bit-for-bit — requests join and leave mid-flight, so
+    slots churn while exactness holds — and match the per-request
+    pipeline to the cross-packing tolerance. (Packing changes the batch
+    of some matmuls, and XLA's CPU backend rounds a row of a batch-1
+    product differently from the same row in a larger batch, so the
+    packed engine and the per-request pipeline agree to ~1e-6, not
+    bitwise; the cache adds no difference of its own.)"""
+    plans = make_plans(solver)
+    cached, eng = _serve_join_leave(
+        pipe, solver, CacheSpec(policy="interval", interval=1, split=1))
+    uncached, _ = _serve_join_leave(pipe, solver, None)
+    assert cached.keys() == uncached.keys()
+    for rid, (x0, lvl, label, key) in cached.items():
+        np.testing.assert_array_equal(x0, uncached[rid][0])
+        ref = _reference(pipe, plans, lvl, label, key)
+        np.testing.assert_allclose(x0, ref, atol=1e-5, rtol=1e-5)
     assert eng.store.n_active == 0          # every slot released on retire
 
 

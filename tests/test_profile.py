@@ -548,3 +548,22 @@ def test_rules_attribution_allows_host_arithmetic():
         / "src/repro/telemetry/attribution.py"
     text = src.read_text()
     assert _lint_attr(text) == []
+
+
+def test_roofline_peaks_keyed_by_device_kind():
+    """Roofline terms price a program with the named chip's published
+    peaks; a device the table does not hold is an error, not a v5e
+    default."""
+    from repro.launch import roofline as rl
+    v5e = rl.device_peaks("TPU v5 lite")
+    assert v5e.flops_bf16 == 197e12 and v5e.hbm_bw == 819e9
+    assert v5e.source
+    terms = rl.roofline_terms({"flops": 197e12, "bytes accessed": 819e9 / 2},
+                              {}, 1, device_kind="TPU v5 lite")
+    assert terms["compute_s"] == pytest.approx(1.0)
+    assert terms["memory_s"] == pytest.approx(0.5)
+    assert terms["dominant"] == "compute"
+    with pytest.raises(ValueError, match="no published peaks"):
+        rl.device_peaks("cpu")
+    with pytest.raises(ValueError):
+        rl.roofline_terms({"flops": 1.0}, {}, 1, device_kind="TPU v9")

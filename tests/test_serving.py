@@ -155,7 +155,11 @@ def test_request_cost_flops_counts_parallel_padding(flexi):
     plan = SamplingPlan(T=T, budget=FlexiSchedule.weak_first(T, 3),
                         guidance_scale=1.5)
     base = request_cost_flops(fcfg, plan, sp=1)
-    assert base == pytest.approx(plan.flops(fcfg))
+    # priced at the plan's own backend: the flash kernel pads a row
+    # shorter than one 128-token tile up to the tile, so it is not the
+    # dense-N² convention on this small model
+    assert base == pytest.approx(plan.flops(fcfg,
+                                            attn_backend=plan.attn_backend))
     padded = request_cost_flops(fcfg, plan, sp=3)   # 64 % 3 != 0 → padding
     assert padded > base
 

@@ -1,6 +1,6 @@
 """Direct unit tests for runtime/sharding.py: dp_axes / batch_spec /
 token_spec / rules_for (incl. the sequence-parallel 'tokens' rule) and the
-jax-version-portable get_abstract_mesh shim behind ``constrain`` —
+ambient-mesh lookup (``jax.sharding.get_abstract_mesh``) behind ``constrain`` —
 previously only exercised indirectly through train/serve paths."""
 import jax
 import jax.numpy as jnp
@@ -76,12 +76,14 @@ def test_ambient_mesh_shim_and_constrain_noop():
     assert sh._ambient_axis_names() == ()
     x = jnp.ones((2, 2))
     assert sh.constrain(x, P("data", None)) is x
-    # inside a `with mesh:` context the shim surfaces the axis names and
+    # under `jax.set_mesh` the shim surfaces the axis names and
     # constrain filters specs down to the axes that exist
     m = _mesh((1, 1), ("data", "model"))
-    with m:
+    with jax.set_mesh(m):
         assert set(sh._ambient_axis_names()) == {"data", "model"}
-        y = sh.constrain(x, P("data", "nope"))          # unknown axis dropped
+        # unknown axis dropped
+        y = jax.jit(lambda a: sh.constrain(a, P("data", "nope")))(x)
         assert y.shape == x.shape
-        z = sh.constrain(x, P(("pod", "data"), None))   # tuple filtering
+        # tuple filtering
+        z = jax.jit(lambda a: sh.constrain(a, P(("pod", "data"), None)))(x)
         assert z.shape == x.shape
