@@ -1,0 +1,17 @@
+"""Chip benchmark of the FlexiDiT serving engine.
+
+One command runs one cell of ``BENCHMARK.json`` once on the chip it is
+started on::
+
+    python3 chipbench/run.py --workload <cell> --seed <n> --seconds <s> --trace <0|1>
+
+Everything that belongs to one configuration, one traffic mix or one
+per-layer metric sits in a file of its own and is found by name:
+``configs/<config>.json`` (sizes, with the plain reference module it
+names), ``traffic/<traffic>.json`` (parameters for :mod:`generator`),
+``arrivals/<arrival>.py`` (an arrival process a mix names, with the
+loop of :mod:`loops` that serves it), ``metrics/<metric>.py`` (a reader
+of the run's records or trace) and ``limits/<cell>.json`` (the
+correctness limit and the readings it was set from). Adding a cell, a
+mix, an arrival process or a metric adds files and entries only.
+"""
