@@ -1,12 +1,9 @@
-"""Telemetry overhead + correctness gates (DESIGN.md §telemetry).
+"""Telemetry correctness gates (DESIGN.md §telemetry).
 
-Three claims, all gated via ``baselines.json``:
+Two claims, both gated via ``baselines.json`` (what tracing costs is
+measured on the chip, traced against untraced runs of the benchmark;
+see PERF.md):
 
-* **overhead** — serving the same drain workload with full telemetry
-  (spans + taps) costs <3% tokens/s vs telemetry off. Taps are extra
-  data outputs of the same fused step; spans are a handful of host
-  clock reads per dispatch. Timed best-of-N, interleaved, because CPU
-  wall clocks drift.
 * **zero added recompiles** — after one warm drain per family, replaying
   the workload (a budget-mix switch each wave) compiles nothing, taps on
   or off. The tapped family is cached under its own key; turning
@@ -21,7 +18,6 @@ from __future__ import annotations
 import dataclasses
 import json
 import sys
-import time
 from pathlib import Path
 
 sys.path.insert(0, str(Path(__file__).resolve().parents[1] / "src"))
@@ -31,14 +27,10 @@ T = 12
 TRAIN_T = 100
 N_REQ = 16
 MAX_TOKENS = 4096
-REPEATS = 6                    # best-of-N timing (CPU wall noise)
 DRIFT_ATOL = 1e-5
 
 
 def _bench_cfg():
-    # Big enough that model compute dominates per-dispatch fixed costs —
-    # the overhead gate measures the marginal cost of taps, and on a toy
-    # model host/jit-call constants swamp it.
     from repro.configs import get_config
     base = get_config("dit-xl-2").reduced()
     return dataclasses.replace(
@@ -130,7 +122,7 @@ def bench_telemetry() -> None:
               f"skip_drift_max={skip_max:.2e}")
 
     # ------------------------------------------------------------------
-    # Gates 1+2: serving overhead + zero added recompiles
+    # Gate 1: zero added recompiles, latents independent of telemetry
 
     plans = {}
     for b in (0.4, 0.7, 1.0):
@@ -139,16 +131,10 @@ def bench_telemetry() -> None:
         plan.validate(cfg)
         plans[b] = plan
     levels = sorted(plans)
-    level_tokens = {}
-    for b, plan in plans.items():
-        fs = plan.resolve_schedule(cfg)
-        level_tokens[b] = 2 * sum(
-            n * dit_mod.tokens_for_mode(cfg, m) for m, n in fs.phases)
     rng = np.random.default_rng(0)
     reqs = [(int(rng.integers(0, cfg.dit.num_classes)),
              levels[int(rng.integers(0, len(levels)))])
             for _ in range(N_REQ)]
-    useful_tokens = sum(level_tokens[lvl] for _, lvl in reqs)
     menu = BucketMenu(cfg, (0, 1), MAX_TOKENS, guided=True)
 
     def drain(telemetry=None):
@@ -159,31 +145,16 @@ def bench_telemetry() -> None:
                           key=jax.random.fold_in(jax.random.PRNGKey(7), i))
         results = engine.run()
         jax.block_until_ready(results[-1].x0)
-        return engine, results
+        return results
 
     drain()                                        # warm the untapped family
     warm_off = pipe.cache_stats()["compiled"]
-    tel_warm = Telemetry(taps=True)
-    drain(tel_warm)                                # warm the tapped family
+    drain(Telemetry(taps=True))                    # warm the tapped family
     warm_on = pipe.cache_stats()["compiled"]
     tapped_family_compiles = warm_on - warm_off
 
-    dt_off = dt_on = float("inf")
-    for rep in range(REPEATS):                     # interleave AND alternate
-        tel = Telemetry(taps=True)                 # order: per-drain wall
-        legs = [("off", None), ("on", tel)]        # noise is ~10%, an order
-        if rep % 2:                                # bias would swamp the
-            legs.reverse()                         # few-% signal
-        for which, t in legs:
-            t0 = time.perf_counter()
-            engine, res = drain(t)
-            dt = time.perf_counter() - t0
-            if which == "off":
-                engine_off, res_off = engine, res
-                dt_off = min(dt_off, dt)
-            else:
-                engine_on, res_on = engine, res
-                dt_on = min(dt_on, dt)
+    tel = Telemetry(taps=True)
+    res_off, res_on = drain(), drain(tel)          # replay both families
     recompiles = pipe.cache_stats()["compiled"] - warm_on
     assert recompiles == 0, \
         f"{recompiles} recompiles during telemetry on/off replay"
@@ -193,14 +164,9 @@ def bench_telemetry() -> None:
     assert all(np.array_equal(a[i], b[i]) for i in a), \
         "telemetry changed the served latents"
 
-    tps_off = useful_tokens / dt_off
-    tps_on = useful_tokens / dt_on
-    overhead = 1.0 - tps_on / tps_off
     agg = tel.taps.aggregate()
     n_spans = tel.recorder.events_recorded
-    C.csv_row("telemetry_overhead", dt_on * 1e6,
-              f"tps_off={tps_off:.0f};tps_on={tps_on:.0f};"
-              f"overhead_frac={overhead:.4f};"
+    C.csv_row("telemetry_replay", 0.0,
               f"recompiles_after_warmup={recompiles};"
               f"tapped_family_compiles={tapped_family_compiles};"
               f"span_events={n_spans};"
@@ -212,10 +178,6 @@ def bench_telemetry() -> None:
         "drift": {"tap_vs_eager_max_err": drift_err,
                   "refresh_drift_mean": drift_refresh_mean,
                   "skip_drift_max": skip_max},
-        "overhead": {"tokens_per_s_off": tps_off,
-                     "tokens_per_s_on": tps_on,
-                     "overhead_frac": overhead,
-                     "wall_s_off": dt_off, "wall_s_on": dt_on},
         "recompiles_after_warmup": recompiles,
         "tapped_family_compiles": tapped_family_compiles,
         "spans": {"events_recorded": n_spans,

@@ -125,10 +125,11 @@ def packed_mixed_forward(params: Any, cfg: ModelConfig,  # repro: traced
 
     # per-group token streams [n_g, N_m, d] and conditioning vectors [n_g, d]
     toks, cvecs = [], []
-    for g, (mode, n) in enumerate(groups):
-        toks.append(dit_mod.embed_mode_tokens(params, xs[g], cfg, mode))
-        cvecs.append(dit_mod.condition_vector(params, ts[g], conds[g], cfg,
-                                              dtype))
+    with jax.named_scope("embed"):
+        for g, (mode, n) in enumerate(groups):
+            toks.append(dit_mod.embed_mode_tokens(params, xs[g], cfg, mode))
+            cvecs.append(dit_mod.condition_vector(params, ts[g], conds[g],
+                                                  cfg, dtype))
 
     # flat segment list (group, index-within-group, tokens)
     segs: List[Tuple[int, int, int]] = []
@@ -137,41 +138,46 @@ def packed_mixed_forward(params: Any, cfg: ModelConfig,  # repro: traced
     rows = assign_rows([s[2] for s in segs], capacity)
     n_seg = len(segs)
 
-    # adaLN conditioning is applied per token but COMPUTED per segment:
-    # every block projects the [S+1, d] segment conditioning (last row =
-    # zeros for padding) and gathers it token-wise — identical values to
-    # a per-token projection at 1/N_seg the matmul cost
-    seg_c = jnp.concatenate(
-        [jnp.stack([cvecs[segs[s][0]][segs[s][1]] for s in range(n_seg)]),
-         jnp.zeros((1, d), dtype)]) if n_seg else jnp.zeros((1, d), dtype)
+    with jax.named_scope("pack_rows"):
+        # adaLN conditioning is applied per token but COMPUTED per
+        # segment: every block projects the [S+1, d] segment conditioning
+        # (last row = zeros for padding) and gathers it token-wise —
+        # identical values to a per-token projection at 1/N_seg the
+        # matmul cost
+        seg_c = jnp.concatenate(
+            [jnp.stack([cvecs[segs[s][0]][segs[s][1]]
+                        for s in range(n_seg)]),
+             jnp.zeros((1, d), dtype)]) if n_seg else jnp.zeros((1, d),
+                                                                dtype)
 
-    row_toks, row_seg, row_idx, placement = [], [], [], {}
-    sid = 0
-    for r, row in enumerate(rows):
-        parts, sparts, iparts, off = [], [], [], 0
-        for si in row:
-            g, i, n = segs[si]
-            parts.append(toks[g][i])
-            sparts.append(jnp.full((n,), sid, jnp.int32))
-            iparts.append(jnp.full((n,), si, jnp.int32))
-            placement[(g, i)] = (r, off)
-            sid += 1
-            off += n
-        if off < capacity:
-            pad = capacity - off
-            parts.append(jnp.zeros((pad, d), dtype))
-            sparts.append(jnp.full((pad,), -1, jnp.int32))
-            iparts.append(jnp.full((pad,), n_seg, jnp.int32))
-        row_toks.append(jnp.concatenate(parts))
-        row_seg.append(jnp.concatenate(sparts))
-        row_idx.append(jnp.concatenate(iparts))
-    packed = jnp.stack(row_toks)                     # [R, C, d]
-    segment_ids = jnp.stack(row_seg)                 # [R, C]
-    token_idx = jnp.stack(row_idx)                   # [R, C] → seg_c row
+        row_toks, row_seg, row_idx, placement = [], [], [], {}
+        sid = 0
+        for r, row in enumerate(rows):
+            parts, sparts, iparts, off = [], [], [], 0
+            for si in row:
+                g, i, n = segs[si]
+                parts.append(toks[g][i])
+                sparts.append(jnp.full((n,), sid, jnp.int32))
+                iparts.append(jnp.full((n,), si, jnp.int32))
+                placement[(g, i)] = (r, off)
+                sid += 1
+                off += n
+            if off < capacity:
+                pad = capacity - off
+                parts.append(jnp.zeros((pad, d), dtype))
+                sparts.append(jnp.full((pad,), -1, jnp.int32))
+                iparts.append(jnp.full((pad,), n_seg, jnp.int32))
+            row_toks.append(jnp.concatenate(parts))
+            row_seg.append(jnp.concatenate(sparts))
+            row_idx.append(jnp.concatenate(iparts))
+        packed = jnp.stack(row_toks)                 # [R, C, d]
+        segment_ids = jnp.stack(row_seg)             # [R, C]
+        token_idx = jnp.stack(row_idx)               # [R, C] → seg_c row
 
     def body(h, bp):
-        h = _packed_block(bp, h, seg_c, token_idx, cfg, block_mode,
-                          segment_ids, attn_backend)
+        with jax.named_scope("dit_block"):
+            h = _packed_block(bp, h, seg_c, token_idx, cfg, block_mode,
+                              segment_ids, attn_backend)
         return h, None
 
     from repro.models.common import scan_or_unroll
@@ -182,22 +188,24 @@ def packed_mixed_forward(params: Any, cfg: ModelConfig,  # repro: traced
         # cached deltas packed row-wise with the SAME placement as the
         # tokens; each token selects fresh vs replayed by its segment's
         # refresh flag (padding rides along with flag False, delta 0)
-        drow_parts = []
-        for row in rows:
-            parts, off = [], 0
-            for si in row:
-                g, i, n = segs[si]
-                parts.append(cache_deltas[g][i].astype(dtype))
-                off += n
-            if off < capacity:
-                parts.append(jnp.zeros((capacity - off, d), dtype))
-            drow_parts.append(jnp.concatenate(parts))
-        delta_rows = jnp.stack(drow_parts)           # [R, C, d]
-        refresh_flat = jnp.concatenate(
-            [jnp.asarray(cache_refresh[g]).reshape(-1).astype(bool)
-             for g in range(len(groups))])           # [n_seg]
-        rf_pad = jnp.concatenate([refresh_flat, jnp.zeros((1,), bool)])
-        rmask = jnp.take(rf_pad, token_idx)[..., None]   # [R, C, 1]
+        with jax.named_scope("pack_rows"):
+            drow_parts = []
+            for row in rows:
+                parts, off = [], 0
+                for si in row:
+                    g, i, n = segs[si]
+                    parts.append(cache_deltas[g][i].astype(dtype))
+                    off += n
+                if off < capacity:
+                    parts.append(jnp.zeros((capacity - off, d), dtype))
+                drow_parts.append(jnp.concatenate(parts))
+            delta_rows = jnp.stack(drow_parts)       # [R, C, d]
+            refresh_flat = jnp.concatenate(
+                [jnp.asarray(cache_refresh[g]).reshape(-1).astype(bool)
+                 for g in range(len(groups))])       # [n_seg]
+            rf_pad = jnp.concatenate([refresh_flat,
+                                      jnp.zeros((1,), bool)])
+            rmask = jnp.take(rf_pad, token_idx)[..., None]   # [R, C, 1]
 
         shallow, deep = dit_mod.split_blocks(params["blocks"], cache_split)
         h_s, _ = scan_or_unroll(body, packed, shallow, cfg.unroll)
@@ -215,31 +223,32 @@ def packed_mixed_forward(params: Any, cfg: ModelConfig,  # repro: traced
         tok, new_rows = jax.lax.cond(jnp.any(refresh_flat), _with_deep,
                                      _no_deep, (h_s, delta_rows))
 
-    ada = dit_mod._linear(jax.nn.silu(seg_c.astype(jnp.float32)).astype(dtype),
-                          params["final"]["ada"]["w"],
-                          params["final"]["ada"]["b"])
-    sh, sc = jnp.split(jnp.take(ada, token_idx, axis=0), 2, axis=-1)
-    tok = dit_mod._ln(tok) * (1.0 + sc) + sh
+    with jax.named_scope("final"):
+        ada = dit_mod._linear(
+            jax.nn.silu(seg_c.astype(jnp.float32)).astype(dtype),
+            params["final"]["ada"]["w"], params["final"]["ada"]["b"])
+        sh, sc = jnp.split(jnp.take(ada, token_idx, axis=0), 2, axis=-1)
+        tok = dit_mod._ln(tok) * (1.0 + sc) + sh
 
-    outs: List[jax.Array] = []
-    new_deltas: List[jax.Array] = []
-    for g, (mode, n) in enumerate(groups):
-        if n == 0:
-            outs.append(jnp.zeros((0,) + cfg.dit.latent_shape[:-1]
-                                  + (dit_mod.c_out_dim(cfg),), dtype))
+        outs: List[jax.Array] = []
+        new_deltas: List[jax.Array] = []
+        for g, (mode, n) in enumerate(groups):
+            if n == 0:
+                outs.append(jnp.zeros((0,) + cfg.dit.latent_shape[:-1]
+                                      + (dit_mod.c_out_dim(cfg),), dtype))
+                if cached:
+                    new_deltas.append(jnp.zeros((0, seg_n[g], d), dtype))
+                continue
+            slices, dslices = [], []
+            for i in range(n):
+                r, off = placement[(g, i)]
+                slices.append(tok[r, off:off + seg_n[g]])
+                if cached:
+                    dslices.append(new_rows[r, off:off + seg_n[g]])
+            outs.append(dit_mod.deembed_mode_tokens(
+                params, jnp.stack(slices), cfg, mode))
             if cached:
-                new_deltas.append(jnp.zeros((0, seg_n[g], d), dtype))
-            continue
-        slices, dslices = [], []
-        for i in range(n):
-            r, off = placement[(g, i)]
-            slices.append(tok[r, off:off + seg_n[g]])
-            if cached:
-                dslices.append(new_rows[r, off:off + seg_n[g]])
-        outs.append(dit_mod.deembed_mode_tokens(
-            params, jnp.stack(slices), cfg, mode))
-        if cached:
-            new_deltas.append(jnp.stack(dslices))
+                new_deltas.append(jnp.stack(dslices))
     return (outs, new_deltas) if cached else outs
 
 
@@ -274,23 +283,29 @@ def _packed_block(p: Any, x: jax.Array, seg_c: jax.Array,
     level via ``token_idx``) + segment-masked attention."""
     H = cfg.attn.num_heads
     dtype = x.dtype
-    ada = dit_mod._linear(jax.nn.silu(seg_c.astype(jnp.float32)).astype(dtype),
-                          p["ada"]["w"], p["ada"]["b"])
-    ada = jnp.take(ada, token_idx, axis=0)           # [R, C, 6d]
-    sh1, sc1, g1, sh2, sc2, g2 = jnp.split(ada, 6, axis=-1)
+    with jax.named_scope("adaln"):
+        ada = dit_mod._linear(
+            jax.nn.silu(seg_c.astype(jnp.float32)).astype(dtype),
+            p["ada"]["w"], p["ada"]["b"])
+        ada = jnp.take(ada, token_idx, axis=0)       # [R, C, 6d]
+        sh1, sc1, g1, sh2, sc2, g2 = jnp.split(ada, 6, axis=-1)
     lora = p.get("lora", {})
-    h = dit_mod._ln(x) * (1.0 + sc1) + sh1
-    attn = dit_mod._mha(p["attn"], h, H, lora=lora.get("attn"), mode=mode,
-                        segment_ids=segment_ids, attn_backend=attn_backend)
-    x = x + g1 * attn
-    h2 = dit_mod._ln(x) * (1.0 + sc2) + sh2
-    mlp_lora = lora.get("mlp", {})
-    h2 = dit_mod._linear(h2, p["mlp"]["w_in"], p["mlp"]["b_in"],
-                         lora=mlp_lora.get("w_in"), mode=mode)
-    h2 = jax.nn.gelu(h2.astype(jnp.float32), approximate=True).astype(dtype)
-    h2 = dit_mod._linear(h2, p["mlp"]["w_out"], p["mlp"]["b_out"],
-                         lora=mlp_lora.get("w_out"), mode=mode)
-    return x + g2 * h2
+    with jax.named_scope("attn"):
+        h = dit_mod._ln(x) * (1.0 + sc1) + sh1
+        attn = dit_mod._mha(p["attn"], h, H, lora=lora.get("attn"),
+                            mode=mode, segment_ids=segment_ids,
+                            attn_backend=attn_backend)
+        x = x + g1 * attn
+    with jax.named_scope("mlp"):
+        h2 = dit_mod._ln(x) * (1.0 + sc2) + sh2
+        mlp_lora = lora.get("mlp", {})
+        h2 = dit_mod._linear(h2, p["mlp"]["w_in"], p["mlp"]["b_in"],
+                             lora=mlp_lora.get("w_in"), mode=mode)
+        h2 = jax.nn.gelu(h2.astype(jnp.float32),
+                         approximate=True).astype(dtype)
+        h2 = dit_mod._linear(h2, p["mlp"]["w_out"], p["mlp"]["b_out"],
+                             lora=mlp_lora.get("w_out"), mode=mode)
+        return x + g2 * h2
 
 
 # ---------------------------------------------------------------------------

@@ -215,32 +215,34 @@ def make_packed_step_fn(cfg: ModelConfig, sched: sch.DiffusionSchedule,
         x_prevs, eps_taps = [], []
         for g, (mode, n) in enumerate(groups):
             t_g, tp_g = metas[g][0], metas[g][1]
-            eps, logvar = split_model_out(outs[g], cfg)
-            if guided:
-                e_c, e_u = jnp.split(eps, 2, axis=0)
-                eps_g = e_u + guidance_scale * (e_c - e_u)
-                lv = None if logvar is None else jnp.split(logvar, 2,
-                                                           axis=0)[0]
-            else:
-                eps_g, lv = eps, logvar
-            if taps:
-                eps_taps.append(taps_mod.eps_norm_tap(eps_g))
-            if solver == "ddim":
-                x_prev = sch.ddim_step(sched, xs[g], eps_g, t_g,
-                                       tp_g, 0.0, None)
-            else:
-                # per-request ancestral noise: vmap draws each request's
-                # noise from its own key, exactly as an n=1 pipeline batch
-                if lv is None:
-                    x_prev = jax.vmap(
-                        lambda x1, e1, t1, k1: sch.ddpm_step(
-                            sched, x1, e1, t1, k1, None, clip_x0)
-                    )(xs[g], eps_g, t_g, keys[g])
+            with jax.named_scope("guidance_solver"):
+                eps, logvar = split_model_out(outs[g], cfg)
+                if guided:
+                    e_c, e_u = jnp.split(eps, 2, axis=0)
+                    eps_g = e_u + guidance_scale * (e_c - e_u)
+                    lv = None if logvar is None else jnp.split(logvar, 2,
+                                                               axis=0)[0]
                 else:
-                    x_prev = jax.vmap(
-                        lambda x1, e1, t1, k1, lv1: sch.ddpm_step(
-                            sched, x1, e1, t1, k1, lv1, clip_x0)
-                    )(xs[g], eps_g, t_g, keys[g], lv)
+                    eps_g, lv = eps, logvar
+                if taps:
+                    eps_taps.append(taps_mod.eps_norm_tap(eps_g))
+                if solver == "ddim":
+                    x_prev = sch.ddim_step(sched, xs[g], eps_g, t_g,
+                                           tp_g, 0.0, None)
+                else:
+                    # per-request ancestral noise: vmap draws each
+                    # request's noise from its own key, exactly as an n=1
+                    # pipeline batch
+                    if lv is None:
+                        x_prev = jax.vmap(
+                            lambda x1, e1, t1, k1: sch.ddpm_step(
+                                sched, x1, e1, t1, k1, None, clip_x0)
+                        )(xs[g], eps_g, t_g, keys[g])
+                    else:
+                        x_prev = jax.vmap(
+                            lambda x1, e1, t1, k1, lv1: sch.ddpm_step(
+                                sched, x1, e1, t1, k1, lv1, clip_x0)
+                        )(xs[g], eps_g, t_g, keys[g], lv)
             x_prevs.append(x_prev)
         if taps:
             tap = {"eps_norm": tuple(eps_taps),

@@ -25,6 +25,7 @@ same per-phase solver-key derivation, same guidance combine.
 """
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import math
 import time
@@ -50,7 +51,7 @@ from repro.serving.metrics import RequestRecord, ServingMetrics
 from repro.serving.queue import Request, RequestQueue
 from repro.telemetry import TapSample, Telemetry
 from repro.telemetry.profile import packed_key as profile_packed_key
-from repro.telemetry.trace import REQUEST_PID
+from repro.telemetry.trace import REQUEST_PID, span
 
 ENGINE_POLICIES = ("fifo", "edf", "degrade")
 
@@ -534,40 +535,38 @@ class ServingEngine:
         compiled AND loaded (a runner that merely exists in the cache
         still stalls its first real step on compilation).
 
-        ``record=False`` skips the span (the background compile thread
-        must not interleave writes into the serving thread's
-        SpanRecorder ring or stamp a foreign clock)."""
-        record = record and self._rec is not None
-        t0 = self.clock() if record else 0.0
-        runner = self.pipe.packed_step(
-            layout, solver=self.solver,
-            guidance_scale=self.guidance_scale, clip_x0=self.clip_x0,
-            k_steps=k, cache_split=self.cache_split,
-            attn_backend=self.attn_backend, taps=self._taps)
-        xs, metas, keys, deltas, refreshes = [], [], [], [], []
-        for mode, cap in layout.groups:
-            xs.append(self._put(np.zeros((cap,) + self.cfg.dit.latent_shape,
-                                         np.float32)))
-            meta = np.zeros((k, 3, cap), np.int32)
-            meta[:, 1, :] = -1
-            metas.append(self._put(meta))
-            keys.append(self._put(np.zeros((k, cap, 2), np.uint32)))
+        ``record=False`` keeps the span out of the ring (the background
+        compile thread must not interleave writes into the serving
+        thread's SpanRecorder ring or stamp a foreign clock); the
+        profiler still sees it, on that thread."""
+        with span(self._rec if record else None, "compile") as sp:
+            if sp.on:
+                sp.set(groups=str(layout.groups), k=k, precapture=True)
+            runner = self.pipe.packed_step(
+                layout, solver=self.solver,
+                guidance_scale=self.guidance_scale, clip_x0=self.clip_x0,
+                k_steps=k, cache_split=self.cache_split,
+                attn_backend=self.attn_backend, taps=self._taps)
+            xs, metas, keys, deltas, refreshes = [], [], [], [], []
+            for mode, cap in layout.groups:
+                xs.append(self._put(np.zeros(
+                    (cap,) + self.cfg.dit.latent_shape, np.float32)))
+                meta = np.zeros((k, 3, cap), np.int32)
+                meta[:, 1, :] = -1
+                metas.append(self._put(meta))
+                keys.append(self._put(np.zeros((k, cap, 2), np.uint32)))
+                if self.cache is not None:
+                    deltas.append(self._put(jnp.zeros(
+                        (cap, self.store.mult, self._seg_tokens[mode],
+                         self.cfg.d_model), self.store.dtype)))
+                    refreshes.append(self._put(np.zeros((k, cap), bool)))
             if self.cache is not None:
-                deltas.append(self._put(jnp.zeros(
-                    (cap, self.store.mult, self._seg_tokens[mode],
-                     self.cfg.d_model), self.store.dtype)))
-                refreshes.append(self._put(np.zeros((k, cap), bool)))
-        if self.cache is not None:
-            out = runner(self.pipe.params, tuple(xs), tuple(metas),
-                         tuple(keys), tuple(deltas), tuple(refreshes))
-        else:
-            out = runner(self.pipe.params, tuple(xs), tuple(metas),
-                         tuple(keys))
-        jax.block_until_ready(out)
-        if record:
-            self._rec.complete("compile", t0, self.clock(),
-                               args={"groups": str(layout.groups), "k": k,
-                                     "precapture": True})
+                out = runner(self.pipe.params, tuple(xs), tuple(metas),
+                             tuple(keys), tuple(deltas), tuple(refreshes))
+            else:
+                out = runner(self.pipe.params, tuple(xs), tuple(metas),
+                             tuple(keys))
+            jax.block_until_ready(out)
 
     # ------------------------------------------------------------------
     # The engine iteration
@@ -576,15 +575,22 @@ class ServingEngine:
         """One engine iteration: admit arrivals, plan (cohort, bucket,
         micro-step depth k), advance the packed cohort k denoise steps in
         one dispatch, and retire finished requests. Requests that don't
-        fit the chosen bucket simply wait (no drain, no recompile)."""
+        fit the chosen bucket simply wait (no drain, no recompile).
+
+        Each phase is a :class:`~repro.telemetry.trace.span` nested in
+        ``engine.step``: ``admit``, ``plan``, ``pack``, ``compile`` (cold
+        runner fetch only), ``dispatch``, ``materialize``, ``retire``."""
+        with span(self._rec, "step"):
+            return self._step()
+
+    def _step(self) -> List[ServedResult]:
         now = self.clock()
-        n_before = len(self._inflight)
-        self._admit(now)
-        if self._rec is not None and len(self._inflight) > n_before:
-            self._rec.complete("admit", now, self.clock(),
-                               args={"admitted":
-                                     len(self._inflight) - n_before,
-                                     "queued": len(self._queue)})
+        with span(self._rec, "admit") as sp:
+            n_before = len(self._inflight)
+            self._admit(now)
+            if sp.on:
+                sp.set(admitted=len(self._inflight) - n_before,
+                       queued=len(self._queue))
         if not self._inflight:
             self._last_step_at = now
             return []
@@ -600,277 +606,230 @@ class ServingEngine:
         # (``allow_cold=False``: every compile stall is an SLA violation)
         # restricts to already-compiled layouts, falling back to a cold
         # one only when nothing warm can serve at all.
-        t_plan = self.clock() if self._rec is not None else 0.0
-        prio = sorted(self._inflight, key=self._priority)
-        top = prio[0]
-        k_cap = 1
-        top_run = min(self.steps_per_dispatch,
-                      int(top.lp.run_len[top.step]))
-        while k_cap * 2 <= top_run:
-            k_cap *= 2
-        best = None
-        for cold_pass in ((True,) if self.allow_cold else (False, True)):
-            if not cold_pass:
-                # frozen pass: only buckets with room for the highest-
-                # priority request's mode — keeps EDF live (top always
-                # advances) and k_cap (derived from top) consistent
-                warm_layouts = {
-                    kk: [l for l in ls if l.capacity_for(top.mode)]
-                    for kk, ls in self.pipe.warm_packed_layouts(
-                        solver=self.solver,
-                        guidance_scale=self.guidance_scale,
-                        clip_x0=self.clip_x0,
-                        cache_split=self.cache_split,
-                        attn_backend=self.attn_backend,
-                        taps=self._taps).items()}
-            kc = k_cap
-            while kc >= 1:
-                eligible = [f for f in prio
-                            if int(f.lp.run_len[f.step]) >= kc]
-                if not eligible:
+        with span(self._rec, "plan") as sp:
+            prio = sorted(self._inflight, key=self._priority)
+            top = prio[0]
+            k_cap = 1
+            top_run = min(self.steps_per_dispatch,
+                          int(top.lp.run_len[top.step]))
+            while k_cap * 2 <= top_run:
+                k_cap *= 2
+            best = None
+            for cold_pass in ((True,) if self.allow_cold else (False, True)):
+                if not cold_pass:
+                    # frozen pass: only buckets with room for the highest-
+                    # priority request's mode — keeps EDF live (top always
+                    # advances) and k_cap (derived from top) consistent
+                    warm_layouts = {
+                        kk: [l for l in ls if l.capacity_for(top.mode)]
+                        for kk, ls in self.pipe.warm_packed_layouts(
+                            solver=self.solver,
+                            guidance_scale=self.guidance_scale,
+                            clip_x0=self.clip_x0,
+                            cache_split=self.cache_split,
+                            attn_backend=self.attn_backend,
+                            taps=self._taps).items()}
+                kc = k_cap
+                while kc >= 1:
+                    eligible = [f for f in prio
+                                if int(f.lp.run_len[f.step]) >= kc]
+                    if not eligible:
+                        kc //= 2
+                        continue
+                    if cold_pass:
+                        idx, counts = self.menu.greedy_fit(
+                            [f.mode for f in eligible])
+                        if not idx:
+                            kc //= 2
+                            continue
+                        cand = PackLayout.for_counts(
+                            counts, guided=self.guided,
+                            row_capacity=self.menu.row_capacity)
+                        sel_by_mode: Dict[int, List[InFlight]] = {}
+                        for i in idx:
+                            sel_by_mode.setdefault(eligible[i].mode,
+                                                   []).append(eligible[i])
+                        served = len(idx)
+                    else:
+                        demand: Dict[int, int] = {}
+                        for f in eligible:
+                            demand[f.mode] = demand.get(f.mode, 0) + 1
+                        cand = self.menu.choose(
+                            demand, among=warm_layouts.get(kc, ()))
+                        if cand is None:
+                            kc //= 2
+                            continue
+                        sel_by_mode = None
+                        served = self.menu.served_by(cand, demand)
+                    score = (kc * served,
+                             1 if self._is_warm(cand, kc) else 0,
+                             -self.menu.packed_tokens(cand))
+                    if best is None or score > best[0]:
+                        best = (score, kc, cand, sel_by_mode)
                     kc //= 2
-                    continue
-                if cold_pass:
-                    idx, counts = self.menu.greedy_fit(
-                        [f.mode for f in eligible])
-                    if not idx:
-                        kc //= 2
-                        continue
-                    cand = PackLayout.for_counts(
-                        counts, guided=self.guided,
-                        row_capacity=self.menu.row_capacity)
-                    sel_by_mode: Dict[int, List[InFlight]] = {}
-                    for i in idx:
-                        sel_by_mode.setdefault(eligible[i].mode,
-                                               []).append(eligible[i])
-                    served = len(idx)
-                else:
-                    demand: Dict[int, int] = {}
-                    for f in eligible:
-                        demand[f.mode] = demand.get(f.mode, 0) + 1
-                    cand = self.menu.choose(
-                        demand, among=warm_layouts.get(kc, ()))
-                    if cand is None:
-                        kc //= 2
-                        continue
-                    sel_by_mode = None
-                    served = self.menu.served_by(cand, demand)
-                score = (kc * served,
-                         1 if self._is_warm(cand, kc) else 0,
-                         -self.menu.packed_tokens(cand))
-                if best is None or score > best[0]:
-                    best = (score, kc, cand, sel_by_mode)
-                kc //= 2
-            if best is not None:
-                break                 # frozen pass found a warm bucket
-        _, k, layout, sel_by_mode = best
-        if sel_by_mode is None:       # warm bucket: fill its capacities
-            eligible = [f for f in prio if int(f.lp.run_len[f.step]) >= k]
-            sel_by_mode = {}
-            for f in eligible:
-                sel_by_mode.setdefault(f.mode, []).append(f)
-        picked = [sel_by_mode.get(mode, [])[:cap]
-                  for mode, cap in layout.groups]
-        if self._rec is not None:
-            self._rec.complete("plan", t_plan, self.clock(),
-                               args={"k": k,
-                                     "groups": str(layout.groups),
-                                     "inflight": len(self._inflight)})
-        t_pack = self.clock() if self._rec is not None else 0.0
+                if best is not None:
+                    break                 # frozen pass found a warm bucket
+            _, k, layout, sel_by_mode = best
+            if sel_by_mode is None:       # warm bucket: fill its capacities
+                eligible = [f for f in prio
+                            if int(f.lp.run_len[f.step]) >= k]
+                sel_by_mode = {}
+                for f in eligible:
+                    sel_by_mode.setdefault(f.mode, []).append(f)
+            picked = [sel_by_mode.get(mode, [])[:cap]
+                      for mode, cap in layout.groups]
+            if sp.on:
+                sp.set(k=k, groups=str(layout.groups),
+                       inflight=len(self._inflight))
 
-        xs, metas, keys = [], [], []
-        deltas, refreshes, slot_lists, rf_real = [], [], [], []
-        real_tokens = 0
-        n_refresh = n_cached_steps = 0
-        for (mode, cap), sel in zip(layout.groups, picked):
-            pad = cap - len(sel)
-            xs.append(self._gather_latents(sel, pad))
-            meta = np.zeros((k, 3, cap), np.int32)
-            meta[:, 1, :] = -1                   # dummy slots: final step
-            kk = np.zeros((k, cap, 2), np.uint32)
-            rf = np.zeros((k, cap), bool)        # dummies never refresh
-            slots: List[int] = []
-            for i, f in enumerate(sel):
-                s = f.step
-                meta[:, 0, i] = f.lp.ts[s:s + k]
-                meta[:, 1, i] = f.lp.t_prev[s:s + k]
-                meta[:, 2, i] = f.req.cond
-                kk[:, i] = f.keys[s:s + k]
+        with span(self._rec, "pack") as sp:
+            xs, metas, keys = [], [], []
+            deltas, refreshes, slot_lists, rf_real = [], [], [], []
+            real_tokens = 0
+            n_refresh = n_cached_steps = 0
+            for (mode, cap), sel in zip(layout.groups, picked):
+                pad = cap - len(sel)
+                xs.append(self._gather_latents(sel, pad))
+                meta = np.zeros((k, 3, cap), np.int32)
+                meta[:, 1, :] = -1               # dummy slots: final step
+                kk = np.zeros((k, cap, 2), np.uint32)
+                rf = np.zeros((k, cap), bool)    # dummies never refresh
+                slots: List[int] = []
+                for i, f in enumerate(sel):
+                    s = f.step
+                    meta[:, 0, i] = f.lp.ts[s:s + k]
+                    meta[:, 1, i] = f.lp.t_prev[s:s + k]
+                    meta[:, 2, i] = f.req.cond
+                    kk[:, i] = f.keys[s:s + k]
+                    if self.cache is not None:
+                        if self._ensure_slot(f, mode):
+                            f.refresh_mask[s] = True  # fresh slot: no replay
+                        elif self.store.integrity and \
+                                not self.store.verify_slot(mode,
+                                                           f.cache_slot):
+                            # checksum mismatch: the resident delta was
+                            # corrupted out of band — force an exact
+                            # deep-block recompute; the scatter below
+                            # re-records the crc
+                            f.refresh_mask[s] = True
+                            self.metrics.total_integrity_refreshes += 1
+                        if f.cache_slot < 0:
+                            # slotless (transient alloc failure): every
+                            # micro-step refreshes, so the garbage gathered
+                            # in its row is never read and nothing scatters
+                            # back
+                            f.refresh_mask[s:s + k] = True
+                        rf[:, i] = f.refresh_mask[s:s + k]
+                        slots.append(f.cache_slot)
+                metas.append(self._put(meta))
+                keys.append(self._put(kk))
+                real_tokens += mult * self._seg_tokens[mode] * len(sel) * k
                 if self.cache is not None:
-                    if self._ensure_slot(f, mode):
-                        f.refresh_mask[s] = True     # fresh slot: no replay
-                    elif self.store.integrity and not self.store.verify_slot(
-                            mode, f.cache_slot):
-                        # checksum mismatch: the resident delta was
-                        # corrupted out of band — force an exact deep-block
-                        # recompute; the scatter below re-records the crc
-                        f.refresh_mask[s] = True
-                        self.metrics.total_integrity_refreshes += 1
-                    if f.cache_slot < 0:
-                        # slotless (transient alloc failure): every
-                        # micro-step refreshes, so the garbage gathered in
-                        # its row is never read and nothing scatters back
-                        f.refresh_mask[s:s + k] = True
-                    rf[:, i] = f.refresh_mask[s:s + k]
-                    slots.append(f.cache_slot)
-            metas.append(self._put(meta))
-            keys.append(self._put(kk))
-            real_tokens += mult * self._seg_tokens[mode] * len(sel) * k
+                    refreshes.append(self._put(rf))
+                    slot_lists.append(slots)
+                    rf_real.append(rf[:, :len(sel)])
+                    if slots and min(slots) < 0:
+                        # slotless rows gather slot 0's delta; it is
+                        # ignored (their refresh flags are all True)
+                        gathered = self.store.gather(
+                            mode, [max(sl, 0) for sl in slots])
+                    else:
+                        gathered = (self.store.gather(mode, slots)
+                                    if slots else None)
+                    if pad:
+                        z = jnp.zeros((pad, self.store.mult,
+                                       self._seg_tokens[mode],
+                                       self.cfg.d_model), self.store.dtype)
+                        gathered = (z if gathered is None
+                                    else jnp.concatenate([gathered, z]))
+                    deltas.append(self._put(gathered))
+
+            step_flops = 0.0
             if self.cache is not None:
-                refreshes.append(self._put(rf))
-                slot_lists.append(slots)
-                rf_real.append(rf[:, :len(sel)])
-                if slots and min(slots) < 0:
-                    # slotless rows gather slot 0's delta; it is ignored
-                    # (their refresh flags are all True)
-                    gathered = self.store.gather(
-                        mode, [max(sl, 0) for sl in slots])
-                else:
-                    gathered = (self.store.gather(mode, slots)
-                                if slots else None)
-                if pad:
-                    z = jnp.zeros((pad, self.store.mult,
-                                   self._seg_tokens[mode],
-                                   self.cfg.d_model), self.store.dtype)
-                    gathered = (z if gathered is None
-                                else jnp.concatenate([gathered, z]))
-                deltas.append(self._put(gathered))
+                # honest device-cost accounting: the packed executable's
+                # lax.cond is DISPATCH-wide — the deep blocks run for the
+                # whole pack whenever any cohort member refreshes a
+                # micro-step, so only all-skip micro-steps realize the deep
+                # saving. The per-request replay counts below feed the
+                # quality/staleness ledger (hit rate, histogram); the FLOPs
+                # fed to the capacity EWMA charge what the hardware ran.
+                any_ref = np.zeros(k, bool)
+                for rf in rf_real:
+                    if rf.size:
+                        any_ref |= rf.any(axis=1)
+                deep_skips = k - int(any_ref.sum())
+                for (mode, _cap), sel, rf in zip(layout.groups, picked,
+                                                 rf_real):
+                    n_refresh += int(rf.sum())
+                    n_cached_steps += k * len(sel)
+                    full = dit_nfe_flops(self.cfg, mode,
+                                         attn_backend=self.attn_backend)
+                    deep = cache_ledger.deep_block_flops(
+                        self.cfg, mode, self.cache_split,
+                        attn_backend=self.attn_backend)
+                    step_flops += mult * len(sel) * (k * full
+                                                     - deep_skips * deep)
+            else:
+                step_flops = k * sum(
+                    mult * len(sel)
+                    * dit_nfe_flops(self.cfg, mode,
+                                    attn_backend=self.attn_backend)
+                    for (mode, _cap), sel in zip(layout.groups, picked))
+            if sp.on:
+                sp.set(real_tokens=real_tokens)
 
-        step_flops = 0.0
-        if self.cache is not None:
-            # honest device-cost accounting: the packed executable's
-            # lax.cond is DISPATCH-wide — the deep blocks run for the
-            # whole pack whenever any cohort member refreshes a
-            # micro-step, so only all-skip micro-steps realize the deep
-            # saving. The per-request replay counts below feed the
-            # quality/staleness ledger (hit rate, histogram); the FLOPs
-            # fed to the capacity EWMA charge what the hardware ran.
-            any_ref = np.zeros(k, bool)
-            for rf in rf_real:
-                if rf.size:
-                    any_ref |= rf.any(axis=1)
-            deep_skips = k - int(any_ref.sum())
-            for (mode, _cap), sel, rf in zip(layout.groups, picked,
-                                             rf_real):
-                n_refresh += int(rf.sum())
-                n_cached_steps += k * len(sel)
-                full = dit_nfe_flops(self.cfg, mode,
-                                     attn_backend=self.attn_backend)
-                deep = cache_ledger.deep_block_flops(
-                    self.cfg, mode, self.cache_split,
-                    attn_backend=self.attn_backend)
-                step_flops += mult * len(sel) * (k * full
-                                                 - deep_skips * deep)
-        else:
-            step_flops = k * sum(
-                mult * len(sel)
-                * dit_nfe_flops(self.cfg, mode,
-                                attn_backend=self.attn_backend)
-                for (mode, _cap), sel in zip(layout.groups, picked))
-
-        if self._rec is not None:
-            self._rec.complete("pack", t_pack, self.clock(),
-                               args={"real_tokens": real_tokens})
-        was_warm = (self._is_warm(layout, k) if self._rec is not None
-                    else True)
-        t_fetch = self.clock() if self._rec is not None else 0.0
-        runner = self.pipe.packed_step(
-            layout, solver=self.solver,
-            guidance_scale=self.guidance_scale, clip_x0=self.clip_x0,
-            k_steps=k, cache_split=self.cache_split,
-            attn_backend=self.attn_backend, taps=self._taps)
-        if self._rec is not None and not was_warm:
-            # cold dispatch: the runner fetch traced + lowered a new
-            # executable — the stall every frozen-serving SLA fears
-            self._rec.complete("compile", t_fetch, self.clock(),
-                               args={"groups": str(layout.groups), "k": k})
-        t_disp = (self.clock()
-                  if self._rec is not None or self._profile is not None
-                  else 0.0)
-        tap = None
-        if self.cache is not None:
-            out = runner(self.pipe.params, tuple(xs),
-                         tuple(metas), tuple(keys),
-                         tuple(deltas), tuple(refreshes))
-            (outs, new_deltas, tap) = out if self._taps else (*out, None)
-            if self._faults is not None:
-                outs = self._apply_poison(outs, picked)
-            for (mode, _cap), slots, nd in zip(layout.groups, slot_lists,
-                                               new_deltas):
-                if not slots:
-                    continue
-                if min(slots) < 0:
-                    # skip slotless rows: scattering them would clobber
-                    # slot 0's owner
-                    keep = [j for j, sl in enumerate(slots) if sl >= 0]
-                    if keep:
-                        self.store.scatter(mode, [slots[j] for j in keep],
-                                           nd[np.asarray(keep, np.int32)])
-                else:
-                    self.store.scatter(mode, slots, nd[:len(slots)])
-            self.metrics.record_cache(n_refresh,
-                                      n_cached_steps - n_refresh)
-            self.metrics.set_cache_bytes(self.store.bytes_resident)
-        else:
-            out = runner(self.pipe.params, tuple(xs), tuple(metas),
-                         tuple(keys))
-            (outs, tap) = out if self._taps else (out, None)
-            if self._faults is not None:
-                outs = self._apply_poison(outs, picked)
-        if self._profile is not None:
-            # profiling waits on the device once per dispatch: wall is
-            # meaningless without it. Measurement overhead only — the
-            # executables and their outputs are untouched
-            jax.block_until_ready(outs)
-            wall_s = self.clock() - t_disp
-            pkey = profile_packed_key(
+        was_warm = self._is_warm(layout, k)
+        # a cold dispatch: the runner fetch traced + lowered a new
+        # executable — the stall every frozen-serving SLA fears
+        with (contextlib.nullcontext() if was_warm else
+              span(self._rec, "compile", groups=str(layout.groups), k=k)):
+            runner = self.pipe.packed_step(
                 layout, solver=self.solver,
                 guidance_scale=self.guidance_scale, clip_x0=self.clip_x0,
                 k_steps=k, cache_split=self.cache_split,
                 attn_backend=self.attn_backend, taps=self._taps)
-            self._profile.observe_wall(pkey, wall_s)
-            if self._attr is not None:
-                rids: List[int] = []
-                weights: List[float] = []
-                for gi, ((mode, _cap), sel) in enumerate(
-                        zip(layout.groups, picked)):
-                    full = dit_nfe_flops(self.cfg, mode,
-                                         attn_backend=self.attn_backend)
-                    deep = (cache_ledger.deep_block_flops(
-                        self.cfg, mode, self.cache_split,
-                        attn_backend=self.attn_backend)
-                        if self.cache is not None else 0.0)
-                    for i, f in enumerate(sel):
-                        rids.append(f.req.id)
-                        if self.cache is not None:
-                            # refresh-aware ledger share: skip steps pay
-                            # shallow blocks only
-                            w = mult * sum(
-                                full if r else full - deep
-                                for r in rf_real[gi][:, i])
-                        else:
-                            w = mult * k * full
-                        weights.append(float(w))
-                if rids:
-                    self._attr.attribute_dispatch(
-                        time=now,
-                        label=f"k={k} groups={layout.groups}",
-                        request_ids=rids, weights=weights,
-                        wall_ns=int(wall_s * 1e9),
-                        flops=int(step_flops),
-                        bytes_=self._profile.xla_bytes(pkey))
-            if self.controller is not None:
-                fams = {mode for (mode, _c), sel
-                        in zip(layout.groups, picked) if sel}
-                self.controller.observe_calibration(
-                    fams.pop() if len(fams) == 1 else None,
-                    step_flops, wall_s)
-        if self._rec is not None:
-            self._rec.complete(
-                "dispatch", t_disp, self.clock(),
-                args={"k": k, "groups": str(layout.groups),
-                      "requests": sum(len(s) for s in picked),
-                      "warm": was_warm})
+        with span(self._rec, "dispatch") as sp:
+            if sp.on:
+                # the request ids tie one request's dispatches together
+                sp.set(k=k, groups=str(layout.groups),
+                       requests=sum(len(s) for s in picked), warm=was_warm,
+                       ids=" ".join(str(f.req.id)
+                                    for sel in picked for f in sel))
+            t_disp = self.clock() if self._profile is not None else 0.0
+            tap = None
+            if self.cache is not None:
+                out = runner(self.pipe.params, tuple(xs),
+                             tuple(metas), tuple(keys),
+                             tuple(deltas), tuple(refreshes))
+                (outs, new_deltas, tap) = out if self._taps else (*out, None)
+                if self._faults is not None:
+                    outs = self._apply_poison(outs, picked)
+                for (mode, _cap), slots, nd in zip(layout.groups, slot_lists,
+                                                   new_deltas):
+                    if not slots:
+                        continue
+                    if min(slots) < 0:
+                        # skip slotless rows: scattering them would clobber
+                        # slot 0's owner
+                        keep = [j for j, sl in enumerate(slots) if sl >= 0]
+                        if keep:
+                            self.store.scatter(
+                                mode, [slots[j] for j in keep],
+                                nd[np.asarray(keep, np.int32)])
+                    else:
+                        self.store.scatter(mode, slots, nd[:len(slots)])
+                self.metrics.record_cache(n_refresh,
+                                          n_cached_steps - n_refresh)
+                self.metrics.set_cache_bytes(self.store.bytes_resident)
+            else:
+                out = runner(self.pipe.params, tuple(xs), tuple(metas),
+                             tuple(keys))
+                (outs, tap) = out if self._taps else (out, None)
+                if self._faults is not None:
+                    outs = self._apply_poison(outs, picked)
+            if self._profile is not None:
+                self._observe_profile(layout, k, picked, rf_real, step_flops,
+                                      now, t_disp, outs)
         if tap is not None:
             # still device arrays — the aggregator syncs at export time
             self.telemetry.taps.add(TapSample(
@@ -888,12 +847,9 @@ class ServingEngine:
             # latency derived from it) waits for the device. This is also
             # the only honest capacity sample — between syncs the clock
             # only sees host-side batch assembly, not device compute
-            t_mat = self.clock() if self._rec is not None else 0.0
-            jax.block_until_ready(outs)
+            with span(self._rec, "materialize", k=k):
+                jax.block_until_ready(outs)
             now = self.clock()
-            if self._rec is not None:
-                self._rec.complete("materialize", t_mat, now,
-                                   args={"k": k})
             if self.controller is not None and self._last_sync_at is not None \
                     and now > self._last_sync_at:
                 self.controller.observe_service(self._flops_since_sync,
@@ -901,46 +857,49 @@ class ServingEngine:
             self._flops_since_sync = 0.0
             self._last_sync_at = now
 
-        finished: List[ServedResult] = []
-        stepped = 0
-        # quarantine detection rides existing sync points only: the
-        # in-graph finite tap is read on the host after the completion
-        # branch's block_until_ready, and the retire-time check reads a
-        # latent that same sync already materialized
-        bad: set = set()
-        if self._quarantine and synced and tap is not None:
-            bad = self._scan_finite(tap, picked)
-        for g, sel in enumerate(picked):
-            for i, f in enumerate(sel):
-                f.x_src, f.x_row = outs[g], i
-                f.step += k
-                stepped += 1
-                if self._quarantine and (
-                        f.req.id in bad
-                        or (f.done
-                            and not np.isfinite(np.asarray(f.x)).all())):
-                    self._inflight.remove(f)
-                    self._quarantine_request(f, now)
-                elif f.done:
-                    self._inflight.remove(f)
-                    finished.append(self._retire(f, now))
-        cost = self._layout_costs.get(layout)
-        if cost is None:
-            cost = self._layout_costs[layout] = layout.cost(self.cfg)
-        self.metrics.record_step(now, real_tokens, cost.packed_tokens * k,
-                                 stepped)
-        if self.attn_backend in ("auto", "pallas"):
-            # cross-segment block skip ledger (DESIGN.md
-            # §attention-backend): what fraction of the pack's score
-            # tiles the segment-aware kernel never issued
-            blk = self._layout_blocks.get(layout)
-            if blk is None:
-                blk = self._layout_blocks[layout] = \
-                    layout.attention_block_stats(self.cfg)
-            self.metrics.record_attention_blocks(blk[0] * k, blk[1] * k)
-        if self._rec is not None:
-            self._rec.counter("engine", {"inflight": len(self._inflight),
-                                         "queued": len(self._queue)})
+        with span(self._rec, "retire") as sp:
+            finished: List[ServedResult] = []
+            stepped = 0
+            # quarantine detection rides existing sync points only: the
+            # in-graph finite tap is read on the host after the completion
+            # branch's block_until_ready, and the retire-time check reads a
+            # latent that same sync already materialized
+            bad: set = set()
+            if self._quarantine and synced and tap is not None:
+                bad = self._scan_finite(tap, picked)
+            for g, sel in enumerate(picked):
+                for i, f in enumerate(sel):
+                    f.x_src, f.x_row = outs[g], i
+                    f.step += k
+                    stepped += 1
+                    if self._quarantine and (
+                            f.req.id in bad
+                            or (f.done
+                                and not np.isfinite(np.asarray(f.x)).all())):
+                        self._inflight.remove(f)
+                        self._quarantine_request(f, now)
+                    elif f.done:
+                        self._inflight.remove(f)
+                        finished.append(self._retire(f, now))
+            cost = self._layout_costs.get(layout)
+            if cost is None:
+                cost = self._layout_costs[layout] = layout.cost(self.cfg)
+            self.metrics.record_step(now, real_tokens,
+                                     cost.packed_tokens * k, stepped)
+            if self.attn_backend in ("auto", "pallas"):
+                # cross-segment block skip ledger (DESIGN.md
+                # §attention-backend): what fraction of the pack's score
+                # tiles the segment-aware kernel never issued
+                blk = self._layout_blocks.get(layout)
+                if blk is None:
+                    blk = self._layout_blocks[layout] = \
+                        layout.attention_block_stats(self.cfg)
+                self.metrics.record_attention_blocks(blk[0] * k, blk[1] * k)
+            if self._rec is not None:
+                self._rec.counter("engine", {"inflight": len(self._inflight),
+                                             "queued": len(self._queue)})
+            if sp.on:
+                sp.set(retired=len(finished))
         if self._watchdog is not None:
             self._wd_ticks += 1
             drift = None
@@ -964,6 +923,59 @@ class ServingEngine:
                     attribution=self._attr, registry=self._profile)
         self._last_step_at = now
         return finished
+
+    def _observe_profile(self, layout: PackLayout, k: int,
+                         picked: List[List[InFlight]], rf_real: List,
+                         step_flops: float, now: float, t_disp: float,
+                         outs: Tuple) -> None:
+        """Profiling waits on the device once per dispatch: wall is
+        meaningless without it. Measurement overhead only — the
+        executables and their outputs are untouched."""
+        mult = 2 if self.guided else 1
+        jax.block_until_ready(outs)
+        wall_s = self.clock() - t_disp
+        pkey = profile_packed_key(
+            layout, solver=self.solver,
+            guidance_scale=self.guidance_scale, clip_x0=self.clip_x0,
+            k_steps=k, cache_split=self.cache_split,
+            attn_backend=self.attn_backend, taps=self._taps)
+        self._profile.observe_wall(pkey, wall_s)
+        if self._attr is not None:
+            rids: List[int] = []
+            weights: List[float] = []
+            for gi, ((mode, _cap), sel) in enumerate(
+                    zip(layout.groups, picked)):
+                full = dit_nfe_flops(self.cfg, mode,
+                                     attn_backend=self.attn_backend)
+                deep = (cache_ledger.deep_block_flops(
+                    self.cfg, mode, self.cache_split,
+                    attn_backend=self.attn_backend)
+                    if self.cache is not None else 0.0)
+                for i, f in enumerate(sel):
+                    rids.append(f.req.id)
+                    if self.cache is not None:
+                        # refresh-aware ledger share: skip steps pay
+                        # shallow blocks only
+                        w = mult * sum(
+                            full if r else full - deep
+                            for r in rf_real[gi][:, i])
+                    else:
+                        w = mult * k * full
+                    weights.append(float(w))
+            if rids:
+                self._attr.attribute_dispatch(
+                    time=now,
+                    label=f"k={k} groups={layout.groups}",
+                    request_ids=rids, weights=weights,
+                    wall_ns=int(wall_s * 1e9),
+                    flops=int(step_flops),
+                    bytes_=self._profile.xla_bytes(pkey))
+        if self.controller is not None:
+            fams = {mode for (mode, _c), sel
+                    in zip(layout.groups, picked) if sel}
+            self.controller.observe_calibration(
+                fams.pop() if len(fams) == 1 else None,
+                step_flops, wall_s)
 
     def _apply_poison(self, outs: Tuple, picked: List[List[InFlight]]
                       ) -> Tuple:

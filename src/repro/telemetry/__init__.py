@@ -2,20 +2,22 @@
 
 Three layers, one rule — **observability must be data, not structure**:
 
-* :mod:`repro.telemetry.trace` — host-side span/event recorder (bounded
-  ring buffer, simulated- or wall-clock) with Chrome-trace/Perfetto
-  export; instruments the request lifecycle queue admit → pack decision
-  → dispatch → device step(s) → materialization → finish plus compile
-  events.
+* :mod:`repro.telemetry.trace` — the engine's phase spans
+  (``trace.span``: always a ``jax.profiler.TraceAnnotation`` on the
+  profiler's clock) and the host-side span/event recorder they also
+  fill (bounded ring buffer, simulated- or wall-clock) with
+  Chrome-trace/Perfetto export; instruments the request lifecycle
+  queue admit → pack decision → dispatch → device step(s) →
+  materialization → finish plus compile events.
 * :mod:`repro.telemetry.taps` — on-device scalar taps threaded as extra
   **data** outputs through ``make_packed_step_fn`` (per-request eps
   norm, realized cache replay drift ``‖h_fresh − h_replay‖``, the
   kernel ledger's attention block counts). No host callbacks, no
   ``debug.print``, no recompiles: DCE of the tap outputs recovers the
   untapped jaxpr bit-for-bit (asserted in ``analysis/jaxpr_audit.py``).
-* :mod:`repro.telemetry.export` — Prometheus text-format + JSON
-  snapshot exporters over ``ServingMetrics`` summaries and tap
-  aggregates (duck-typed: this module never imports the engine).
+* :mod:`repro.telemetry.export` — the ``[metrics]`` structured log
+  line over ``ServingMetrics`` summaries and tap aggregates
+  (duck-typed: this module never imports the engine).
 
 ``Telemetry`` bundles a recorder + tap aggregator for the serving
 engine; device values cross to the host only inside

@@ -1,22 +1,16 @@
-"""Metrics exporters (DESIGN.md §telemetry).
+"""Metrics exporter (DESIGN.md §telemetry).
 
 Renders ``ServingMetrics`` summaries (the engine's ``MetricsLedger``),
-cache summaries, pipeline compile counters, and tap aggregates as:
-
-* **Prometheus text format** (``prometheus_text``) — flat
-  ``repro_<name>`` gauges with nested dicts flattened into label-free
-  suffixed names (scrape endpoint / node-exporter textfile collector);
-* **JSON snapshot** (``json_snapshot``) — one nested dict for dashboards
-  and the bench artifacts;
-* **structured log line** (``metrics_line``) — the ``--metrics-interval``
-  one-liner: ``[metrics] k=v ...`` with stable key order.
+pipeline compile counters, span-ring counters and tap aggregates as the
+structured log line (``metrics_line``) — the ``--metrics-interval``
+one-liner: ``[metrics] k=v ...`` with nested dicts flattened into
+suffixed names (NaNs dropped) and a stable key order.
 
 Everything here is duck-typed over plain dicts — the engine imports
 telemetry, so telemetry must never import the engine.
 """
 from __future__ import annotations
 
-import json
 import math
 from typing import Any, Dict, Mapping, Optional
 
@@ -38,65 +32,6 @@ def _flatten(prefix: str, node: Any, out: Dict[str, float]) -> None:
 
 def _sanitize(name: str) -> str:
     return "".join(c if c.isalnum() or c == "_" else "_" for c in name)
-
-
-def flatten_metrics(snapshot: Mapping[str, Any],
-                    prefix: str = "repro") -> Dict[str, float]:
-    """Nested summary dicts → flat ``{metric_name: value}`` (non-numeric
-    leaves and NaNs dropped — absent beats poisoned)."""
-    out: Dict[str, float] = {}
-    _flatten(_sanitize(prefix), snapshot, out)
-    return out
-
-
-def build_snapshot(summary: Optional[Mapping[str, Any]] = None,
-                   cache: Optional[Mapping[str, Any]] = None,
-                   compile_stats: Optional[Mapping[str, Any]] = None,
-                   taps: Optional[Mapping[str, Any]] = None,
-                   spans: Optional[Mapping[str, Any]] = None
-                   ) -> Dict[str, Any]:
-    """Assemble the canonical snapshot from the engine's pieces
-    (``metrics.summary(wall)``, ``metrics.cache_summary()``,
-    ``pipe.cache_stats()``, ``telemetry.taps.aggregate()``,
-    ``recorder.counters()``)."""
-    snap: Dict[str, Any] = {}
-    if summary:
-        snap["serving"] = dict(summary)
-    if cache:
-        snap["cache"] = dict(cache)
-    if compile_stats:
-        snap["compile"] = dict(compile_stats)
-    if taps:
-        snap["taps"] = dict(taps)
-    if spans:
-        snap["spans"] = dict(spans)
-    return snap
-
-
-def json_snapshot(summary: Optional[Mapping[str, Any]] = None,
-                  cache: Optional[Mapping[str, Any]] = None,
-                  compile_stats: Optional[Mapping[str, Any]] = None,
-                  taps: Optional[Mapping[str, Any]] = None,
-                  spans: Optional[Mapping[str, Any]] = None) -> str:
-    return json.dumps(build_snapshot(summary, cache, compile_stats, taps,
-                                     spans),
-                      sort_keys=True)
-
-
-def prometheus_text(summary: Optional[Mapping[str, Any]] = None,
-                    cache: Optional[Mapping[str, Any]] = None,
-                    compile_stats: Optional[Mapping[str, Any]] = None,
-                    taps: Optional[Mapping[str, Any]] = None,
-                    spans: Optional[Mapping[str, Any]] = None,
-                    prefix: str = "repro") -> str:
-    """Prometheus exposition text (type: gauge) for the snapshot."""
-    flat = flatten_metrics(build_snapshot(summary, cache, compile_stats,
-                                          taps, spans), prefix)
-    lines = []
-    for name in sorted(flat):
-        lines.append(f"# TYPE {name} gauge")
-        lines.append(f"{name} {flat[name]:.10g}")
-    return "\n".join(lines) + ("\n" if lines else "")
 
 
 #: metrics_line key order — SLA signals first, then throughput, then

@@ -6,9 +6,14 @@ observability is :mod:`repro.telemetry.taps`. The buffer is a bounded
 ring (``collections.deque(maxlen=...)``): an engine serving indefinitely
 must not grow memory per dispatch; drops are counted, not silent.
 
-Timestamps come from an injected ``clock()`` — the serving engine's
-simulated clock in tests (deterministic traces) or ``time.monotonic``
-in production. Export renders the buffer as Chrome trace-event JSON
+The engine's phases go through :class:`span`, which opens a
+``jax.profiler.TraceAnnotation`` named ``engine.<phase>`` — so a
+profiler trace shows them on the device trace's clock, nested as they
+ran — and, where a recorder is attached, pushes the same span into the
+ring as ``<phase>``. Ring timestamps come from the recorder's injected
+``clock()`` — the serving engine's simulated clock in tests
+(deterministic traces) or ``time.monotonic`` in production. Export
+renders the buffer as Chrome trace-event JSON
 (``{"traceEvents": [...]}``) loadable in Perfetto / ``chrome://tracing``:
 complete events (``ph="X"``) for spans, instants (``ph="i"``) for
 events, counters (``ph="C"``) for gauges. Request lifecycles render as
@@ -24,9 +29,13 @@ import time
 from contextlib import contextmanager
 from typing import Any, Callable, Dict, List, Optional
 
+import jax
+
 #: trace rows: engine-wide activity vs per-request lifecycle tracks
 ENGINE_PID = 1
 REQUEST_PID = 2
+#: the profiler sees an engine phase ``plan`` as ``engine.plan``
+PROFILER_PREFIX = "engine."
 
 
 @dataclasses.dataclass
@@ -117,7 +126,7 @@ class SpanRecorder:
 
     def counters(self) -> Dict[str, float]:
         """Exporter-facing health counters (satellite: silent span loss
-        must be observable in Prometheus/metrics_line)."""
+        must be observable in metrics_line)."""
         return {
             "events_recorded": float(self.events_recorded),
             "events_dropped": float(self.events_dropped),
@@ -144,3 +153,46 @@ class SpanRecorder:
 
     def by_name(self, name: str) -> List[TraceEvent]:
         return [e for e in self.events if e.name == name]
+
+
+class span:
+    """One engine phase, on the profiler's clock and in the ring.
+
+    >>> with span(engine._rec, "dispatch") as sp:
+    ...     out = runner(...)
+    ...     if sp.on:
+    ...         sp.set(k=k, ids="3,4")
+
+    Always opens ``jax.profiler.TraceAnnotation("engine.<name>")``: with
+    no profiler running that is a sub-microsecond no-op, so the phases
+    need no flag. With ``rec`` attached it also pushes the span into
+    the ring as ``<name>``, timed on ``rec.clock``. ``on`` says whether
+    anyone records the span, so callers build args only then; args set
+    inside the block (values known only at its end) reach both.
+    """
+    __slots__ = ("rec", "name", "args", "_ann", "_t0")
+
+    def __init__(self, rec: Optional[SpanRecorder], name: str, **args):
+        self.rec, self.name, self.args = rec, name, args
+
+    @property
+    def on(self) -> bool:
+        return (self.rec is not None
+                or jax.profiler.TraceAnnotation.is_enabled())
+
+    def set(self, **args) -> None:
+        self.args.update(args)
+
+    def __enter__(self) -> "span":
+        self._ann = jax.profiler.TraceAnnotation(PROFILER_PREFIX + self.name)
+        self._ann.__enter__()
+        self._t0 = self.rec.clock() if self.rec is not None else 0.0
+        return self
+
+    def __exit__(self, *exc) -> None:
+        if self.args and jax.profiler.TraceAnnotation.is_enabled():
+            self._ann.set_metadata(**self.args)
+        self._ann.__exit__(*exc)
+        if self.rec is not None:
+            self.rec.complete(self.name, self._t0, self.rec.clock(),
+                              args=self.args or None)

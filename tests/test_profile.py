@@ -463,12 +463,6 @@ def test_export_surfaces_span_counters():
                      "occupancy": 1.0, "capacity": 4}
     line = tel_export.metrics_line({"served": 2}, spans=spans)
     assert "span_dropped=2" in line and "span_occupancy=1" in line
-    text = tel_export.prometheus_text(summary={"served": 2.0}, spans=spans)
-    assert "repro_spans_events_dropped 2" in text
-    assert "repro_spans_occupancy 1" in text
-    snap = json.loads(tel_export.json_snapshot(summary={"served": 2.0},
-                                               spans=spans))
-    assert snap["spans"]["events_dropped"] == 2
 
 
 # ---------------------------------------------------------------------------

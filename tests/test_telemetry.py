@@ -24,7 +24,8 @@ from repro.pipeline.plan import CacheSpec
 from repro.serving import ServingEngine
 from repro.telemetry import TapAggregator, TapSample, Telemetry
 from repro.telemetry import export as tel_export
-from repro.telemetry.trace import ENGINE_PID, REQUEST_PID, SpanRecorder
+from repro.telemetry.trace import (ENGINE_PID, REQUEST_PID, SpanRecorder,
+                                  span)
 
 pytestmark = pytest.mark.tier1
 
@@ -159,18 +160,10 @@ def test_tap_aggregator_empty_groups_and_window():
 
 
 def test_flatten_drops_nan_and_sanitizes():
-    flat = tel_export.flatten_metrics(
-        {"a": {"p50": 1.5, "bad": float("nan")}, "ok": True, "s": "str"})
-    assert flat == {"repro_a_p50": 1.5, "repro_ok": 1.0}
-
-
-def test_prometheus_text_format():
-    text = tel_export.prometheus_text(summary={"served": 3.0},
-                                      taps={"drift": {"mean": 0.25}})
-    lines = text.strip().splitlines()
-    assert "# TYPE repro_serving_served gauge" in lines
-    assert "repro_serving_served 3" in lines
-    assert "repro_taps_drift_mean 0.25" in lines
+    line = tel_export.metrics_line(
+        {"a": {"p50": 1.5, "bad": float("nan")}, "ok": True, "s": "str",
+         "b-c": 2})
+    assert line == "[metrics] a_p50=1.5 b_c=2 ok=1"
 
 
 def test_metrics_line_order_and_content():
@@ -347,6 +340,60 @@ def test_engine_without_telemetry_records_nothing(pipe):
     eng = _make_engine(pipe, clock=FakeClock())
     _serve(eng, n=2)
     assert eng.telemetry is None
+
+
+def test_span_pushes_the_ring_under_its_phase_name():
+    rec = SpanRecorder(clock=FakeClock())
+    with span(rec, "plan", k=2) as sp:
+        assert sp.on
+        sp.set(groups="((0, 1),)")
+    ev = rec.by_name("plan")[0]
+    assert ev.ph == "X" and ev.dur > 0
+    assert ev.args == {"k": 2, "groups": "((0, 1),)"}
+    # no recorder, no profiler: nobody records, so no args are built
+    with span(None, "plan") as sp:
+        assert not sp.on
+
+
+def test_engine_phases_nest_in_step_on_the_profiler_clock(pipe, tmp_path):
+    """The engine's phases reach the profiler's own trace with no
+    Telemetry attached: on the host plane, every phase lies inside an
+    ``engine.step`` of the same thread, and ``engine.dispatch`` names
+    the requests it advances."""
+    import glob
+
+    from jax.profiler import ProfileData
+    eng = _make_engine(pipe)
+    _serve(eng, n=2)                       # compile outside the trace
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        _serve(eng, n=3)
+    finally:
+        jax.profiler.stop_trace()
+    xplane, = glob.glob(str(tmp_path / "**" / "*.xplane.pb"),
+                        recursive=True)
+    lines = [[(e.name, e.start_ns, e.start_ns + e.duration_ns,
+               dict(e.stats)) for e in line.events
+              if e.name.startswith("engine.")]
+             for plane in ProfileData.from_file(xplane).planes
+             if plane.name.startswith("/host:") for line in plane.lines]
+    evs = max(lines, key=len)
+    steps = [(a, b) for n, a, b, _ in evs if n == "engine.step"]
+    assert steps
+    seen = set()
+    for name, a, b, stats in evs:
+        if name == "engine.step":
+            continue
+        seen.add(name)
+        assert any(s0 <= a and b <= s1 for s0, s1 in steps), name
+    assert {"engine.admit", "engine.plan", "engine.pack",
+            "engine.dispatch", "engine.materialize",
+            "engine.retire"} <= seen
+    ids = set()
+    for name, _a, _b, stats in evs:
+        if name == "engine.dispatch":
+            ids |= {int(i) for i in str(stats["ids"]).split()}
+    assert ids == {2, 3, 4}                # the traced requests' ids
 
 
 # ---------------------------------------------------------------------------
