@@ -1,8 +1,12 @@
 """Segment-aware Pallas flash attention vs the XLA paths (DESIGN.md
 §attention-backend).
 
-Serving bucket shapes (dit-xl-2 geometry: 256-token rows, weak segments
-of 64 tokens) drive three measurements:
+Serving bucket shapes (DiT-XL/2-512 geometry: 1,024-token rows, weak
+segments of 256 tokens) drive three measurements. The kernel's tiles
+are sized from the row (``flash_attention.tile_plan``): a 256-token
+dit-xl-2 row is one tile, so its 64-token weak segments are computed
+then masked and skip nothing; at 1,024 tokens a 512-token tile pair
+still holds whole weak segments, which is where skipping can be seen:
 
 * **analytic** — attention FLOPs of a saturated mixed-budget pack under
   dense N² pricing vs the block-sparse ledger (the tiles the kernel
@@ -32,18 +36,19 @@ REPEATS = 5
 
 
 def _bench_cfg():
-    """dit-xl-2 token geometry (256-token rows, 64-token weak segments —
-    ``reduced()`` shrinks the latent, so pin the real 32x32 grid back)
-    at smoke width: attention shapes are what matter here."""
+    """DiT-XL/2-512 token geometry (1,024-token rows, 256-token weak
+    segments: dit-xl-2's blocks on the 64x64 latent of a 512 px image;
+    ``reduced()`` shrinks the latent, so pin the real grid) at smoke
+    width: attention shapes are what matter here."""
     from repro.configs import get_config
     base = get_config("dit-xl-2")
     red = base.reduced()
+    f, h, w, c = base.dit.latent_shape
     return dataclasses.replace(
         red, num_layers=4, d_model=128, d_ff=512,
         attn=dataclasses.replace(red.attn, num_heads=8, num_kv_heads=8,
                                  head_dim=16),
-        dit=dataclasses.replace(red.dit,
-                                latent_shape=base.dit.latent_shape))
+        dit=dataclasses.replace(red.dit, latent_shape=(f, 2 * h, 2 * w, c)))
 
 
 def _time_best(fn, *args):
@@ -75,8 +80,8 @@ def bench_attention() -> None:
     d = cfg.d_model
     H = cfg.attn.num_heads
     hd = d // H
-    N0 = dit_mod.tokens_for_mode(cfg, 0)            # row capacity (256)
-    N1 = dit_mod.tokens_for_mode(cfg, 1)            # weak segment (64)
+    N0 = dit_mod.tokens_for_mode(cfg, 0)            # row capacity (1,024)
+    N1 = dit_mod.tokens_for_mode(cfg, 1)            # weak segment (256)
     r = packing.pack_ratio(cfg, 1)
 
     # --- a saturated mixed-budget pack: the steady-state weak-heavy mix

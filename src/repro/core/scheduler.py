@@ -60,9 +60,10 @@ def dit_block_flops(cfg: ModelConfig, n_tokens: int,
     only, never the (de-)embedding (``distributed.partition``).
 
     ``attn_backend='pallas'``/``'auto'`` prices self-attention at the
-    block granularity the flash kernel launches (tiles of 128, rounded
-    up) instead of the exact N² — what the device actually issues when
-    the Pallas backend serves the request (DESIGN.md §attention-backend).
+    tiles the flash kernel launches (``flash_attention.tile_plan``, sized
+    from the row, rounded up to lanes) instead of the exact N² — what the
+    device actually issues when the Pallas backend serves the request
+    (DESIGN.md §attention-backend).
     """
     N = n_tokens
     d, L, f = cfg.d_model, cfg.num_layers, cfg.d_ff

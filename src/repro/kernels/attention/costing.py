@@ -5,25 +5,22 @@ The segment-aware flash kernel skips every kv block whose segment range
 cannot intersect the query block, so the score/value FLOPs of a packed
 row are ``4 · d · Σ_active(block_q · block_k)`` — not the dense
 ``4 · d · C²``. These helpers price that from the SAME block-map code
-the kernel runs (``kernels.attention.mask``), on the host with plain
-numpy, so the serving controller, the cache ledger, and the benches
-agree with the device to the block.
+the kernel runs (``kernels.attention.mask``), at the tiles the kernel
+launches with (``flash_attention.tile_plan``, sized from the row), on
+the host with plain numpy, so the serving controller, the cache ledger,
+and the benches agree with the device to the block.
 
 All counts are per layer, batch 1, mul+add counted separately (the
 repo-wide convention of ``core.scheduler``).
 """
 from __future__ import annotations
 
-from typing import Sequence, Tuple
+from typing import Optional, Sequence, Tuple
 
 import numpy as np
 
+from repro.kernels.attention.flash_attention import tile_plan
 from repro.kernels.attention.mask import attention_block_map
-
-# Must match the flash_attention defaults — the ledger prices what the
-# default kernel launch computes.
-DEFAULT_BLOCK_Q = 128
-DEFAULT_BLOCK_K = 128
 
 
 def dense_attention_flops(n_q: int, n_k: int, d_model: int) -> float:
@@ -47,14 +44,17 @@ def segments_to_ids(seg_lengths: Sequence[int], capacity: int) -> np.ndarray:
     return ids
 
 
-def block_map_counts(seg_ids: np.ndarray, *, block_q: int = DEFAULT_BLOCK_Q,
-                     block_k: int = DEFAULT_BLOCK_K, causal: bool = False,
+def block_map_counts(seg_ids: np.ndarray, *,
+                     block_q: Optional[int] = None,
+                     block_k: Optional[int] = None, causal: bool = False,
                      window: int = 0) -> Tuple[int, int, int, int]:
     """(active, total, bq, bk) kv-block visits for [B, S] segment ids,
-    padded to block multiples exactly as the kernel pads (a row shorter
-    than a tile is padded up to one whole tile)."""
+    at the kernel's tiles for rows of S tokens unless given, padded to
+    block multiples exactly as the kernel pads (a row shorter than a
+    tile is padded up to one whole tile)."""
     B, S = seg_ids.shape
-    bq, bk = block_q, block_k
+    plan = tile_plan(S, S, block_q=block_q, block_k=block_k)
+    bq, bk = plan.block_q, plan.block_k
 
     def padded(ids, b):
         pad = (-S) % b
@@ -71,8 +71,8 @@ def block_map_counts(seg_ids: np.ndarray, *, block_q: int = DEFAULT_BLOCK_Q,
 
 def block_sparse_attention_flops(seg_lengths: Sequence[int], capacity: int,
                                  d_model: int, *,
-                                 block_q: int = DEFAULT_BLOCK_Q,
-                                 block_k: int = DEFAULT_BLOCK_K) -> float:
+                                 block_q: Optional[int] = None,
+                                 block_k: Optional[int] = None) -> float:
     """Score/value FLOPs (one layer) the segment-aware kernel issues for
     one packed row: 4·d per visited (block_q · block_k) score tile."""
     ids = segments_to_ids(seg_lengths, capacity)
@@ -83,8 +83,8 @@ def block_sparse_attention_flops(seg_lengths: Sequence[int], capacity: int,
 
 def pack_attention_stats(row_seg_lengths: Sequence[Sequence[int]],
                          capacity: int, *,
-                         block_q: int = DEFAULT_BLOCK_Q,
-                         block_k: int = DEFAULT_BLOCK_K
+                         block_q: Optional[int] = None,
+                         block_k: Optional[int] = None
                          ) -> Tuple[int, int]:
     """(active, total) block visits for a whole pack — one entry per row,
     each a list of segment lengths. The skip rate ``1 - active/total``

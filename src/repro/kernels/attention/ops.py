@@ -7,7 +7,7 @@ from repro.kernels.attention.flash_attention import flash_attention as _fa
 
 def flash_attention(q, k, v, *, causal=True, softcap=0.0, window=0,
                     segment_ids=None, block_map=None,
-                    block_q=128, block_k=128):
+                    block_q=None, block_k=None):
     return _fa(q, k, v, causal=causal, softcap=softcap, window=window,
                segment_ids=segment_ids, block_map=block_map,
                block_q=block_q, block_k=block_k)

@@ -404,8 +404,11 @@ class ServingEngine:
             if self.controller is not None and self.policy == "degrade":
                 level = self.controller.assign(level)
             lp = self.levels[level]
-            x_T = jax.random.normal(req.key,
-                                    (1,) + self.cfg.dit.latent_shape)
+            # committed like every step output, so the step assembly's
+            # concatenate sees one signature per shape, not one per mix
+            # of committed and uncommitted parts
+            x_T = self._put(jax.random.normal(
+                req.key, (1,) + self.cfg.dit.latent_shape))
             mask = (self._level_masks[level].copy()
                     if self.cache is not None else None)
             self._inflight.append(InFlight(
@@ -472,8 +475,8 @@ class ServingEngine:
         if pad:
             z = self._zero_blocks.get(pad)
             if z is None:
-                z = self._zero_blocks[pad] = jnp.zeros(
-                    (pad,) + self.cfg.dit.latent_shape)
+                z = self._zero_blocks[pad] = self._put(jnp.zeros(
+                    (pad,) + self.cfg.dit.latent_shape))
             parts.append(z)
         return self._put(parts[0] if len(parts) == 1
                          else jnp.concatenate(parts))

@@ -22,6 +22,7 @@ from repro.core.flexify import flexify
 from repro.core.scheduler import dit_block_flops, dit_nfe_flops
 from repro.diffusion import schedule as sch
 from repro.kernels.attention import costing
+from repro.kernels.attention import flash_attention as fa_mod
 from repro.kernels.attention import mask as mask_mod
 from repro.kernels.attention import ops as attn_ops
 from repro.models import attention as attn_mod
@@ -291,7 +292,9 @@ def test_block_sparse_pack_pricing(tiny_dit_cfg):
     active, total = packing.pack_attention_block_stats(fcfg, [1] * r, N0)
     assert active < total
     d, L = fcfg.d_model, fcfg.num_layers
-    bq, bk = costing.DEFAULT_BLOCK_Q, costing.DEFAULT_BLOCK_K
+    # priced at the tiles the kernel launches with for rows of N0 tokens
+    plan = fa_mod.tile_plan(N0, N0)
+    bq, bk = plan.block_q, plan.block_k
     expect = L * (total - active) * costing.dense_attention_flops(bq, bk, d)
     assert dense_row - sparse_row == pytest.approx(expect)
 

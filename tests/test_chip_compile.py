@@ -68,14 +68,17 @@ def compiled_kernels(monkeypatch):
 
 # (B, S, H, hd, segmented): dit-xl-2 rows of 256 tokens (one patch-2
 # image, or four patch-4 ones), a lone 64-token weak row shorter than one
-# 128-token tile, t2i-transformer's 4,096 and 1,024 tokens at head dim
-# 128, and video-dit's 33,792 (a 264 x 264 block map in SMEM). Segment
-# ids are traced data, so one compile covers every packing.
+# 128-token tile, DiT-XL/2-512's 1,024-token rows, t2i-transformer's 4,096
+# and 1,024 tokens at head dim 128, and video-dit's 33,792 (a 66 x 66
+# block map in SMEM). Segment ids are traced data, so one compile covers
+# every packing.
 FLASH_CASES = {
     "xl2-packed-256": (8, 256, 16, 72, True),
     "xl2-dense-256": (8, 256, 16, 72, False),
     "xl2-packed-64": (8, 64, 16, 72, True),
     "xl2-dense-64": (8, 64, 16, 72, False),
+    "xl2-512-packed-1024": (8, 1024, 16, 72, True),
+    "xl2-512-dense-1024": (8, 1024, 16, 72, False),
     "t2i-packed-4096": (2, 4096, 16, 128, True),
     "t2i-dense-4096": (2, 4096, 16, 128, False),
     "t2i-packed-1024": (2, 1024, 16, 128, True),
@@ -99,6 +102,30 @@ def test_flash_kernel_compiles_for_v5e(one_chip, compiled_kernels, case):
             q, k, v, causal=False, segment_ids=s))
         lowered = fn.lower(*qkv, sds((B, S), jnp.int32))
     hlo = lowered.compile().as_text()
+    assert "tpu_custom_call" in hlo
+
+
+# (B, S, H, K, hd, window, softcap): causal language-model prefills that
+# run the same kernel through models/attention.py — t2i-transformer's
+# widths, gemma3-4b's sliding-window local layers (8 query heads over 4
+# kv heads of 256), gemma2-9b's soft-capped window of 4,096.
+FLASH_CAUSAL_CASES = {
+    "causal-4096": (2, 4096, 16, 16, 128, 0, 0.0),
+    "gemma3-window-4096": (2, 4096, 8, 4, 256, 1024, 0.0),
+    "gemma2-window-softcap-8192": (1, 8192, 16, 8, 256, 4096, 50.0),
+}
+
+
+@pytest.mark.parametrize("case", list(FLASH_CAUSAL_CASES))
+def test_flash_kernel_compiles_causal_for_v5e(one_chip, compiled_kernels,
+                                              case):
+    B, S, H, K, hd, window, softcap = FLASH_CAUSAL_CASES[case]
+    q = jax.ShapeDtypeStruct((B, S, H, hd), jnp.bfloat16, sharding=one_chip)
+    kv = jax.ShapeDtypeStruct((B, S, K, hd), jnp.bfloat16,
+                              sharding=one_chip)
+    fn = jax.jit(lambda q, k, v: flash_attention(
+        q, k, v, causal=True, window=window, softcap=softcap))
+    hlo = fn.lower(q, kv, kv).compile().as_text()
     assert "tpu_custom_call" in hlo
 
 

@@ -4,6 +4,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from repro.kernels.attention import flash_attention as fa_mod
 from repro.kernels.attention import ops as attn_ops
 from repro.kernels.attention import ref as attn_ref
 from repro.kernels.patch_embed import ops as pe_ops
@@ -12,6 +13,7 @@ from repro.kernels.patch_embed.patch_embed import (patch_deembed_pallas,
                                                    patch_embed_pallas)
 from repro.kernels.ssd import ops as ssd_ops
 from repro.kernels.ssd import ref as ssd_ref
+from test_chip_compile import FLASH_CASES
 
 ATTN_CASES = [
     # B, S, H, K, hd, causal, softcap, window, dtype
@@ -40,6 +42,70 @@ def test_flash_attention_allclose(case):
     np.testing.assert_allclose(np.asarray(out, np.float32),
                                np.asarray(want, np.float32),
                                atol=tol, rtol=tol)
+
+
+# DiT rows at head dim 72, as segment lengths per row: a 1,024-token
+# image, four 256-token weak images, a mixed 1,024/256 pack, and a
+# 256-token row of four 64-token weak images (padding fills the rest)
+DIT_PACKS = {
+    "one-1024": (1024, [[1024]]),
+    "four-256": (1024, [[256] * 4]),
+    "mixed-1024-256": (1024, [[1024], [256] * 3]),
+    "four-64": (256, [[64] * 4]),
+}
+
+
+@pytest.mark.parametrize("case", list(DIT_PACKS))
+def test_flash_attention_dit_packs(case):
+    S, rows = DIT_PACKS[case]
+    B, H, hd = len(rows), 4, 72
+    seg = np.full((B, S), -1, np.int32)
+    for r, lengths in enumerate(rows):
+        seg[r, :sum(lengths)] = np.repeat(np.arange(len(lengths)), lengths)
+    seg = jnp.asarray(seg)
+    ks = jax.random.split(jax.random.PRNGKey(S + B), 3)
+    q, k, v = (jax.random.normal(kk, (B, S, H, hd), jnp.bfloat16)
+               for kk in ks)
+    out = attn_ops.flash_attention(q, k, v, causal=False, segment_ids=seg)
+    want = attn_ref.attention_ref(q, k, v, causal=False, segment_ids=seg)
+    np.testing.assert_allclose(np.asarray(out, np.float32),
+                               np.asarray(want, np.float32),
+                               atol=2e-2, rtol=2e-2)
+
+
+@pytest.mark.parametrize("heads", [1, 2, 4, 8])
+def test_flash_attention_heads_per_step_gqa(heads):
+    """A step's heads share one kv head (heads < group), span whole kv
+    groups (heads > group), or match one group: the index maps fetch the
+    right kv heads in every case."""
+    B, S, H, K, hd = 2, 256, 8, 2, 32
+    ks = jax.random.split(jax.random.PRNGKey(heads), 3)
+    q = jax.random.normal(ks[0], (B, S, H, hd))
+    k = jax.random.normal(ks[1], (B, S, K, hd))
+    v = jax.random.normal(ks[2], (B, S, K, hd))
+    seg = jnp.asarray(np.repeat([[0] * 100 + [1] * 120 + [-1] * 36], B, 0))
+    plan = fa_mod.TilePlan(128, 128, heads)
+    out = jax.jit(lambda q, k, v, s: fa_mod.flash_attention_planned(
+        q, k, v, plan, causal=True, segment_ids=s))(q, k, v, seg)
+    want = attn_ref.attention_ref(q, k, v, causal=True, segment_ids=seg)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(want),
+                               atol=2e-5, rtol=2e-5)
+
+
+@pytest.mark.parametrize("case", list(FLASH_CASES))
+def test_tile_plan_fits_vmem(case):
+    """Every shape the chip-compile rehearsal compiles gets whole-head,
+    lane-aligned tiles that fit the kernel's VMEM budget."""
+    B, S, H, hd, _segmented = FLASH_CASES[case]
+    plan = fa_mod.tile_plan(S, S, H, H, hd, 2)
+    assert plan.block_q % 128 == 0 and plan.block_k % 128 == 0
+    assert plan.block_q <= max(fa_mod.TILE_CAP, 128)
+    assert H % plan.heads == 0
+    assert fa_mod.step_vmem_bytes(plan, hd, 2, plan.heads) \
+        <= fa_mod.VMEM_PLAN
+    # padding stays under one lane group per tile
+    nq = -(-S // plan.block_q)
+    assert nq * plan.block_q - S < 128 * nq
 
 
 SSD_CASES = [(2, 64, 4, 16, 8, 16), (1, 96, 2, 32, 16, 32),

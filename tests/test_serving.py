@@ -262,6 +262,35 @@ def test_engine_matches_per_request_sampling(pipe, flexi, solver):
     assert math.isfinite(eng.metrics.latency_percentiles()["p99"])
 
 
+def test_step_latents_are_committed(pipe, monkeypatch):
+    """Fresh latents and padding join a step committed to the engine's
+    device, like the last step's outputs: the step assembly's concatenate
+    then compiles once per shape, not once per mix of committed and
+    uncommitted parts (which showed as a late warm-up compile)."""
+    plans = make_plans()
+    clk = FakeClock()
+    eng = ServingEngine(pipe, plans, max_tokens_per_step=256,
+                        policy="fifo", clock=clk)
+    assert eng._device is not None
+    seen = []
+    concatenate = jnp.concatenate
+
+    def recording(parts, *a, **kw):
+        if all(getattr(p, "ndim", 0) == 1 + len(pipe.cfg.dit.latent_shape)
+               and not isinstance(p, jax.core.Tracer) for p in parts):
+            seen.append([p.committed for p in parts])
+        return concatenate(parts, *a, **kw)
+
+    monkeypatch.setattr(jnp, "concatenate", recording)
+    for label in (1, 2, 3):                 # admitted in one step
+        eng.submit(cond=label, budget=1.0)
+    eng.step()
+    clk.advance(0.01)
+    eng.submit(cond=4, budget=1.0)          # joins stepped latents
+    eng.step()
+    assert seen and all(all(c) for c in seen), seen
+
+
 def test_edf_orders_by_deadline_under_contention(pipe):
     """With capacity for one full request per step, EDF serves the later
     arrival with the earlier deadline first; FIFO does not."""
