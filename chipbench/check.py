@@ -1,6 +1,6 @@
 """The comparison that decides ``correct``: each sampled request's x0, as
 the window served it, against the plain reference run on the same seeded
-weights, label, budget and PRNG key. The number compared is the worst
+weights, condition, budget and PRNG key. The number compared is the worst
 relative RMS error over the sample, beside the check that every request
 was served at its own budget and that there was something to compare."""
 from __future__ import annotations
@@ -10,24 +10,22 @@ from typing import Dict, List
 
 import numpy as np
 
-from chipbench import work
-
 
 def reference_x0(cell, params, sample: List, precision: str = "f32"
                  ) -> Dict[int, np.ndarray]:
     """The reference's x0 for each sampled request (index into sample),
-    one batch per budget tier."""
+    one batch per budget tier, on the steps the configuration's work
+    module gives the budget."""
     s = cell.traffic["sampler"]
     groups: Dict[float, List[int]] = {}
     for i, r in enumerate(sample):
         groups.setdefault(r.budget, []).append(i)
     out: Dict[int, np.ndarray] = {}
     for budget, idx in sorted(groups.items()):
-        modes = work.step_modes(cell.config, s["T"], budget)
+        modes = cell.work.step_modes(cell.config, s["T"], budget)
         x0 = cell.reference.sample(
             params, cell.config, np.stack([sample[i].key for i in idx]),
-            [sample[i].label for i in idx], modes, s["T"],
-            s["guidance_scale"], precision=precision)
+            [sample[i].cond for i in idx], modes, s, precision=precision)
         for j, i in enumerate(idx):
             out[i] = x0[j]
     return out

@@ -1,5 +1,6 @@
-"""Plain reference of the class-conditioned FlexiDiT sampler, and the
-seeded weights that the program and the reference both run.
+"""Plain reference of the class-conditioned FlexiDiT sampler (DDIM,
+classifier-free guidance), and the seeded weights that the program and
+the reference both run.
 
 It follows DiT (Peebles & Xie 2023: adaLN-Zero blocks, sin-cos positions,
 learned sigma) and FlexiDiT (Anagnostidis et al. 2025, section 3: one set
@@ -290,18 +291,24 @@ MODEL_KEYS = ("num_layers", "d_model", "d_ff", "num_heads", "head_dim",
               "underlying_patch_size", "num_classes", "learn_sigma")
 
 
-def sample(P, cfg: Dict, keys: np.ndarray, labels: Sequence[int],
-           modes: Sequence[int], T: int, guidance: float,
+def sample(P, cfg: Dict, keys: np.ndarray, conds: Sequence[int],
+           modes: Sequence[int], sampler: Dict,
            precision: str = "f32") -> np.ndarray:
     """x0 of a batch of requests that share one step schedule (``modes``,
-    one patch mode per step). ``keys``: [B, 2] uint32, each request's
-    PRNG key; its prior is ``normal(key, (1, *latent_shape))``."""
+    one patch mode per step), each conditioned on its class label in
+    ``conds``, by the traffic mix's ``sampler`` (``T`` DDIM steps,
+    ``guidance_scale``). ``keys``: [B, 2] uint32, each request's PRNG
+    key; its prior is ``normal(key, (1, *latent_shape))``."""
+    if sampler["solver"] != "ddim":
+        raise ValueError(f"the reference samples with DDIM, not "
+                         f"{sampler['solver']!r}")
+    T, guidance = sampler["T"], sampler["guidance_scale"]
     ls = tuple(cfg["latent_shape"])
     x = jnp.concatenate([jax.random.normal(jnp.asarray(k, jnp.uint32),
                                            (1,) + ls) for k in keys])
     acp = linear_alphas_cumprod(cfg["noise_schedule"])
     ts = timesteps(cfg["noise_schedule"]["steps"], T)
-    labels = jnp.asarray(labels, jnp.int32)
+    labels = jnp.asarray(conds, jnp.int32)
     items = _freeze(cfg)
     for i, t in enumerate(ts):
         a_t = acp[t]

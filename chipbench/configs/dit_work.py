@@ -1,11 +1,19 @@
-"""Needed work of the DiT path: the operations and bytes the algorithm
-requires, computed from a configuration file's sizes.
+"""Needed work of the class-conditioned FlexiDiT configurations: the
+operations and bytes the algorithm requires, computed from a
+configuration file's sizes, and the patch mode of each denoising step
+that a relative-compute budget gives.
 
 Counts are of useful work only: real segments (no padding rows, no dummy
 pack slots), dense per-segment attention (no tile rounding), CFG counted
 as the two forward passes it needs. A multiply-add is 2 operations.
 These functions are the benchmark's own; they import nothing of the
 program, so a change to the program's cost model cannot move them.
+
+The blocks' dense matmuls (:func:`block_flops`) are, per token per
+layer, 2·d·3d for q, k and v, 2·d·d for the output projection and
+2·2·d·d_ff for the MLP, and per segment per layer 2·d·6d for the adaLN
+modulation; attention's own QK^T and PV are the flash kernel's and are
+left out.
 """
 from __future__ import annotations
 
@@ -90,3 +98,19 @@ def request_attention(cfg: Dict, modes: Sequence[int], guided: bool,
     fl = sum(attention_flops(tokens(cfg, m), d) for m in modes)
     by = sum(attention_bytes(tokens(cfg, m), d, itemsize) for m in modes)
     return {"flops": mult * L * fl, "bytes": mult * L * by}
+
+
+def block_flops(cfg: Dict, mode: int) -> float:
+    """Every block's matmuls over one image at ``mode``."""
+    d, f = cfg["d_model"], cfg["d_ff"]
+    n = tokens(cfg, mode)
+    per_layer = (n * (2.0 * d * 3 * d + 2.0 * d * d + 2.0 * 2 * d * f)
+                 + 2.0 * d * 6 * d)
+    return cfg["num_layers"] * per_layer
+
+
+def request_block_flops(cfg: Dict, modes: Sequence[int], guided: bool
+                        ) -> float:
+    """Block matmul work of the given denoising steps of one request."""
+    mult = 2 if guided else 1
+    return mult * sum(block_flops(cfg, m) for m in modes)

@@ -26,8 +26,8 @@ def control_checks(cell: spec.Cell, seed: int, params) -> dict:
     """The comparison of a run, with the control's x0 in place of the
     served one, over the sample a run would draw from the window's
     requests of ``seed``."""
-    reqs = generator.generate(cell.traffic, cell.config["num_classes"],
-                              seed, generator.STREAM_WINDOW, 30.0)
+    reqs = generator.generate(cell.traffic, cell.draw_conds, seed,
+                              generator.STREAM_WINDOW, 30.0)
     sample = generator.sample_for_check(cell.traffic, reqs, seed)
     low = check.reference_x0(cell, params, sample, precision="fp8")
     return check.compare(cell, params, sample, low,

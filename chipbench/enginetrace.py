@@ -7,11 +7,11 @@ The engine stamps each phase of ``ServingEngine.step`` as a host span
 names its parts with ``jax.named_scope`` (``embed``, ``pack_rows``,
 ``dit_block`` holding ``adaln``, ``attn`` and ``mlp``, ``final``,
 ``guidance_solver``), which reach each device op's HLO ``op_name``
-metadata. :func:`normalise` reads both besides what
-:func:`chipbench.devtrace.normalise` reads, in the same lists:
+metadata. :func:`normalise` reads both beside the lists that
+:func:`chipbench.devtrace.reduce` reads:
 
-``device_ops``    as ``devtrace``'s, in the same order
-``host_spans``    as ``devtrace``'s (``chipbench.*``)
+``device_ops``    as ``devtrace`` describes them, sorted by start
+``host_spans``    as ``devtrace`` describes them (``chipbench.*``)
 ``engine_spans``  ``[[name, start_ns, dur_ns], ...]`` of ``engine.*``
 ``op_names``      one HLO ``op_name`` per device op (``""`` where the
                   trace gives none), in ``device_ops`` order
@@ -24,7 +24,7 @@ from __future__ import annotations
 import collections
 from typing import Dict, Iterable, List, Tuple
 
-from chipbench import devtrace
+from chipbench import devtrace, xspace
 
 ENGINE_PREFIX = "engine."
 SYNC_SPAN = "engine.materialize"
@@ -39,125 +39,60 @@ UNSCOPED = "unscoped"           # device time under no scope
 
 
 def normalise(xplane_path: str, device_id: int = 0) -> Dict:
-    """``devtrace.normalise``'s lists, the engine's spans and each
-    device op's ``op_name`` (:func:`op_name_table`)."""
-    from jax.profiler import ProfileData
-    pd = ProfileData.from_file(xplane_path)
-    table = op_name_table(xplane_path, f"/device:TPU:{device_id}")
+    """Device ``device_id``'s op events with each op's ``op_name``, the
+    harness's ``chipbench.*`` spans and the engine's ``engine.*`` spans.
+    Only the device plane's op line and the host planes' events named
+    so are read event by event."""
+    device = f"/device:TPU:{device_id}"
     ops: List[list] = []
     spans: List[list] = []
     engine: List[list] = []
     found = False
-    for plane in pd.planes:
-        m = devtrace.DEVICE_PLANE.match(plane.name)
-        if m and int(m.group(1)) == device_id:
+    for plane in xspace.read(xplane_path).planes:
+        if plane.name == device:
+            meta = op_metadata(plane)
             for line in plane.lines:
-                if line.name == devtrace.OP_LINE:
-                    found = True
-                    ops.extend([devtrace.op_name(e.name), int(e.start_ns),
-                                int(e.duration_ns), table.get(e.name, "")]
-                               for e in line.events)
+                if line.name != devtrace.OP_LINE:
+                    continue
+                found = True
+                ts = line.timestamp_ns
+                ops.extend([*meta.get(e.metadata_id, ("", "")),
+                            ts + e.offset_ps // 1000, e.duration_ps // 1000]
+                           for e in line.events)
         elif plane.name.startswith("/host:"):
+            names = {m.key: m.value.name for m in plane.event_metadata
+                     if m.value.name.startswith((devtrace.SPAN_PREFIX,
+                                                 ENGINE_PREFIX))}
             for line in plane.lines:
-                for e in line.events:
-                    if e.name.startswith(devtrace.SPAN_PREFIX):
-                        spans.append([e.name, int(e.start_ns),
-                                      int(e.duration_ns)])
-                    elif e.name.startswith(ENGINE_PREFIX):
-                        engine.append([e.name, int(e.start_ns),
-                                       int(e.duration_ns)])
+                for ev in xspace.events(line, names):
+                    (engine if ev[0].startswith(ENGINE_PREFIX)
+                     else spans).append(ev)
     if not found:
-        raise RuntimeError(f"no {devtrace.OP_LINE!r} line on "
-                           f"/device:TPU:{device_id} in {xplane_path}")
-    ops.sort(key=lambda e: e[1])
+        raise RuntimeError(f"no {devtrace.OP_LINE!r} line on {device} in "
+                           f"{xplane_path}")
+    ops.sort(key=lambda e: e[2])
     spans.sort(key=lambda e: e[1])
     engine.sort(key=lambda e: (e[1], -e[2]))
-    return {"device_ops": [o[:3] for o in ops], "host_spans": spans,
-            "engine_spans": engine, "op_names": [o[3] for o in ops]}
+    return {"device_ops": [[o[0], o[2], o[3]] for o in ops],
+            "host_spans": spans, "engine_spans": engine,
+            "op_names": [o[1] for o in ops]}
 
 
-def _varint(buf, i: int) -> Tuple[int, int]:
-    out = shift = 0
-    while True:
-        b = buf[i]
-        i += 1
-        out |= (b & 0x7F) << shift
-        if b < 0x80:
-            return out, i
-        shift += 7
-
-
-def _fields(buf, start: int, end: int):
-    """``(field number, value)`` of the protobuf message in
-    ``buf[start:end]``: an int for a varint, ``(start, end)`` of the
-    bytes of a length-delimited field; fixed-width fields are skipped."""
-    i = start
-    while i < end:
-        key, i = _varint(buf, i)
-        kind = key & 7
-        if kind == 0:
-            v, i = _varint(buf, i)
-            yield key >> 3, v
-        elif kind == 2:
-            n, i = _varint(buf, i)
-            yield key >> 3, (i, i + n)
-            i += n
-        elif kind in (1, 5):
-            i += 8 if kind == 1 else 4
-        else:
-            raise ValueError(f"protobuf wire type {kind} at byte {i}")
-
-
-def op_name_table(xplane_path: str, plane_name: str) -> Dict[str, str]:
-    """Event name -> ``tf_op`` stat of the events' metadata on one plane
-    of an ``.xplane.pb`` (an ``XSpace``). A TPU's op events carry their
-    HLO ``op_name`` there, not on the event, and
-    ``jax.profiler.ProfileData`` does not show it; this reads just the plane's metadata maps off the wire
-    (XSpace.planes = 1; XPlane: name = 2, event_metadata = 4,
-    stat_metadata = 5; XEventMetadata: name = 2, stats = 5; XStat:
-    metadata_id = 1, str_value = 5, ref_value = 7)."""
-    with open(xplane_path, "rb") as f:
-        buf = memoryview(f.read())
-
-    def text(span) -> str:
-        return bytes(buf[span[0]:span[1]]).decode("utf-8", "replace")
-
-    for fno, plane in _fields(buf, 0, len(buf)):
-        if fno != 1:
-            continue
-        fields = list(_fields(buf, *plane))
-        if not any(k == 2 and text(v) == plane_name for k, v in fields):
-            continue
-        stat_names: Dict[int, str] = {}
-        events = []
-        for k, v in fields:
-            if k not in (4, 5):
-                continue
-            entry = dict(_fields(buf, *v))
-            if 2 not in entry:
-                continue
-            if k == 5:
-                meta = dict(_fields(buf, *entry[2]))
-                stat_names[meta.get(1, 0)] = text(meta.get(2, (0, 0)))
-            else:
-                events.append(entry[2])
-        out: Dict[str, str] = {}
-        for ev in events:
-            name, stats = None, []
-            for k, v in _fields(buf, *ev):
-                if k == 2:
-                    name = text(v)
-                elif k == 5:
-                    stats.append(dict(_fields(buf, *v)))
-            for st in stats:
-                if stat_names.get(st.get(1)) != OP_NAME_STAT:
-                    continue
-                if 5 in st:
-                    out[name] = text(st[5])
-                elif 7 in st:
-                    out[name] = stat_names.get(st[7], "")
-        return out
-    return {}
+def op_metadata(plane) -> Dict[int, Tuple[str, str]]:
+    """Metadata id -> (op, ``tf_op`` stat) on a device plane: ``op`` as
+    :func:`chipbench.devtrace.op_name` gives it, the stat as a string or
+    the name of the stat it refers to (``""`` where there is none). A
+    TPU keeps each op's HLO ``op_name`` there, on the metadata, not on
+    the event."""
+    stat_names = {m.key: m.value.name for m in plane.stat_metadata}
+    out: Dict[int, Tuple[str, str]] = {}
+    for m in plane.event_metadata:
+        tf_op = ""
+        for st in m.value.stats:
+            if stat_names.get(st.metadata_id) == OP_NAME_STAT:
+                tf_op = st.str_value or stat_names.get(st.ref_value, "")
+        out[m.key] = (devtrace.op_name(m.value.name), tf_op)
+    return out
 
 
 def scope_path(op_name: str) -> str:
@@ -165,14 +100,6 @@ def scope_path(op_name: str) -> str:
     ``jit(step)/while/body/dit_block/attn/dot_general`` ->
     ``dit_block/attn``; ``""`` where it holds none."""
     return "/".join(p for p in op_name.split("/") if p in SCOPES)
-
-
-def _window(trace: Dict) -> Tuple[int, int]:
-    win = [s for s in trace["host_spans"] if s[0] == devtrace.WINDOW_SPAN]
-    if len(win) != 1:
-        raise RuntimeError(f"expected one {devtrace.WINDOW_SPAN} span, "
-                           f"found {len(win)}")
-    return win[0][1], win[0][1] + win[0][2]
 
 
 def innermost(spans: Iterable[Tuple[str, int, int]]
@@ -229,46 +156,33 @@ def reduce(trace: Dict) -> Dict:
     ``engine.materialize``, and device time of the ops' own by the
     scope they ran under. ``engine_spans`` 0 means the program stamped
     none, and ``scoped_ops`` 0 that its ops carry no scope."""
-    w0, w1 = _window(trace)
+    w = devtrace.window_ops(trace)
+    w0, w1 = w.w0, w.w1
     ops = trace["device_ops"]
     metas = trace.get("op_names") or [""] * len(ops)
-    kept = []
-    for (name, s, d), meta in zip(ops, metas):
-        a, b = max(s, w0), min(s + d, w1)
-        if b > a:
-            kept.append((name, a, b, meta))
-    # in the order devtrace._self_times walks them, so its list lines up
-    kept.sort(key=lambda e: (e[1], -e[2]))
-    clipped = [e[:3] for e in kept]
-    busy = devtrace._union([(a, b) for _n, a, b in clipped])
-    gaps, prev = [], w0
-    for a, b in busy:
-        if a > prev:
-            gaps.append((prev, a))
-        prev = max(prev, b)
-    if w1 > prev:
-        gaps.append((prev, w1))
     eng = [(n, max(s, w0), min(s + d, w1))
            for n, s, d in trace.get("engine_spans", ())
            if min(s + d, w1) > max(s, w0)]
-    idle = dict(_overlap(innermost(eng), gaps))
-    idle_ns = sum(b - a for a, b in gaps)
+    idle = dict(_overlap(innermost(eng), w.gaps))
+    idle_ns = sum(b - a for a, b in w.gaps)
     idle[OUTSIDE] = idle_ns - sum(idle.values())
     sync_ns = sum(b - a for n, a, b in eng if n == SYNC_SPAN)
 
     by_scope: Dict[str, int] = collections.Counter()
     block_ns = 0
     scoped = 0
-    paths: Dict[str, str] = {}
-    for (name, _a, _b, meta), (_n, own) in zip(
-            kept, devtrace._self_times(clipped)):
-        path = paths.get(meta)
-        if path is None:
-            path = paths[meta] = scope_path(meta)
+    paths: Dict[str, Tuple[str, bool]] = {}
+    for i, own in zip(w.index, w.own):
+        meta = metas[i]
+        known = paths.get(meta)
+        if known is None:
+            path = scope_path(meta)
+            known = paths[meta] = (path,
+                                   path.split("/")[0] == BLOCK_SCOPE)
+        path, in_block = known
         scoped += bool(path)
         by_scope[path or UNSCOPED] += own
-        if path.split("/")[0] == BLOCK_SCOPE and \
-                devtrace.op_group(name) != "flash_attention":
+        if in_block and devtrace.op_group(ops[i][0]) != "flash_attention":
             block_ns += own
     return {
         "engine_spans": len(eng),

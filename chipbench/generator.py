@@ -4,9 +4,10 @@ under ``traffic/``; this module turns it and a seed into requests.
 Every seed gets the same work and the same order: the arrival process
 (``arrivals/<arrival>.py``, found by name) gives the due times, budget
 tiers come in exact shares by largest remainder, and both are ordered
-by the mix's ``order_seed``, so every seed gets one schedule. Labels and
-each request's PRNG key are drawn from the seed; they change values,
-not work.
+by the mix's ``order_seed``, so every seed gets one schedule. Each
+request's condition (drawn by the configuration's program module, see
+:mod:`chipbench.spec`) and its PRNG key come from the seed; they change
+values, not work.
 
 Parameters of a mix:
 
@@ -28,7 +29,7 @@ import dataclasses
 import importlib
 from pathlib import Path
 from types import ModuleType
-from typing import Dict, List
+from typing import Callable, Dict, List, Sequence
 
 import numpy as np
 
@@ -43,7 +44,7 @@ STREAM_WINDOW, STREAM_TAIL, STREAM_SAMPLE, STREAM_WARMUP = 1, 2, 3, 16
 class Req:
     index: int
     due_s: float          # offset from the stream's start
-    label: int
+    cond: object          # the request's condition, as the program takes it
     budget: float
     key: np.ndarray       # uint32[2], the request's PRNG key
 
@@ -72,9 +73,11 @@ def arrival(traffic: Dict) -> ModuleType:
     return importlib.import_module(f"chipbench.arrivals.{name}")
 
 
-def generate(traffic: Dict, num_classes: int, seed: int, stream: int,
-             seconds: float) -> List[Req]:
-    """Requests of one stream of ``seconds``, in arrival order."""
+def generate(traffic: Dict, draw_conds: Callable[[np.random.Generator, int],
+                                                 Sequence],
+             seed: int, stream: int, seconds: float) -> List[Req]:
+    """Requests of one stream of ``seconds``, in arrival order;
+    ``draw_conds(rng, n)`` gives their conditions."""
     process = arrival(traffic)
     rng = rng_for(seed, stream)
     order = rng_for(traffic["order_seed"], stream)
@@ -84,9 +87,9 @@ def generate(traffic: Dict, num_classes: int, seed: int, stream: int,
                                   tier_counts(traffic["tiers"], n))])
     order.shuffle(budgets)
     due = process.offsets(traffic, n, order)
-    labels = rng.integers(0, num_classes, n)
+    conds = draw_conds(rng, n)
     keys = rng.integers(0, 2 ** 32, size=(n, 2), dtype=np.uint32)
-    return [Req(i, float(due[i]), int(labels[i]), float(budgets[i]), keys[i])
+    return [Req(i, float(due[i]), conds[i], float(budgets[i]), keys[i])
             for i in range(n)]
 
 

@@ -76,7 +76,7 @@ class OpenLoop:
         self.engine, self.cell, self.seed = engine, cell, seed
 
     def _generate(self, traffic: Dict, stream: int, seconds: float):
-        return generator.generate(traffic, self.cell.config["num_classes"],
+        return generator.generate(traffic, self.cell.draw_conds,
                                   self.seed, stream, seconds)
 
     def warm_pass(self, p: int) -> None:
@@ -109,8 +109,7 @@ class Backlog:
     def __init__(self, engine, cell, seed: int, seconds: float):
         self.engine, self.cell = engine, cell
         self.by_rid: Dict[int, Served] = {}
-        for r in generator.generate(cell.traffic,
-                                    cell.config["num_classes"], seed,
+        for r in generator.generate(cell.traffic, cell.draw_conds, seed,
                                     generator.STREAM_WINDOW, seconds):
             s = drive.submit(engine, r, clock())
             self.by_rid[s.rid] = s

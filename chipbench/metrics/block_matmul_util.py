@@ -1,5 +1,6 @@
 """The DiT blocks' matmul utilisation, in %: the needed dense block
-work of the window's request-steps (:mod:`chipbench.blockwork`) over
+work of the window's request-steps (the configuration's work module,
+``request_block_flops``) over
 the device time of every op the trace places under the ``dit_block``
 scope (``core/packing.py`` ``_packed_block``), less the
 ``flash_attention`` kernel's, times the device's bf16 peak. An op's

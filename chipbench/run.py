@@ -3,7 +3,8 @@
     python3 chipbench/run.py --workload <cell> --seed <n> --seconds <s> --trace <0|1>
 
 Set-up (``setup_s``, from process start to the window's first instant):
-the weights from the seed on the device, the program's engine, its
+the weights from the seed on the device, the engine that the
+configuration's program module builds over the cell's chips, its
 warm-set ladder, and warm-up passes of the cell's own traffic (see
 :mod:`chipbench.loops`) until passes in a row compile nothing. Then the
 measured window, the check of what it served against the plain reference, and
@@ -98,7 +99,7 @@ def warm(cell: spec.Cell, seed: int, devs, seconds: float,
     log(f"cell {cell.name}: {dev.platform} {dev.device_kind} x{len(devs)}, "
         f"seed {seed}")
     params = cell.reference.make_params(cfg, seed, device=dev)
-    engine = drive.build_engine(cfg, traffic, params)
+    engine = cell.program.build(cfg, traffic, params, devs[:cell.chips])
     n_ladder = engine.precapture_warm_set()
     log(f"warm-set ladder: {n_ladder} layouts; {counter.count} executables "
         f"obtained so far, {counter.cache_hits} of them cache hits")
@@ -129,7 +130,7 @@ def measure(pp: Prepared, seconds: float, trace: bool,
     """The measured window. Returns the run's records: what the metric
     readers read, the end-to-end metrics and the finished requests."""
     import jax
-    from chipbench import devtrace, drive
+    from chipbench import devtrace, drive, enginetrace
     cell, engine = pp.cell, pp.engine
     cfg = cell.config
     traffic = traffic or cell.traffic
@@ -163,7 +164,7 @@ def measure(pp: Prepared, seconds: float, trace: bool,
     population, unfinished = pp.load.outcome(served, t0, start, end)
     finished = [s for s in population if s.finish is not None]
     failed = len(engine.quarantined) + unfinished
-    wk = drive.window_steps(cfg, traffic, served, start, end, t0)
+    wk = drive.window_steps(cell, traffic, served, start, end, t0)
     run: Dict = {"cell": cell.name, "config": cfg, "traffic": traffic,
                  "chips": cell.chips, "peak": pp.peak,
                  "window_s": window_s,
@@ -188,13 +189,17 @@ def measure(pp: Prepared, seconds: float, trace: bool,
             f"{percentile(lateness, 50) * 1e3:.3f} ms, max "
             f"{max(lateness) * 1e3:.3f} ms")
     if trace:
-        norm = devtrace.normalise(devtrace.find_xplane(trace_dir),
-                                  device_id=pp.dev.id)
+        norm = enginetrace.normalise(devtrace.find_xplane(trace_dir),
+                                     device_id=pp.dev.id)
         shutil.rmtree(trace_dir, ignore_errors=True)
-        run["trace"] = devtrace.reduce(norm)
-        tr = run["trace"]
+        tr = run["trace"] = devtrace.reduce(norm)
+        eng = tr["engine"] = enginetrace.reduce(norm)
         log(f"trace: busy {tr['busy_s']:.4f}s of {tr['window_s']:.4f}s, "
             f"kernels {tr['kernel_s']}")
+        log(f"idle by engine phase {eng['idle_s']}")
+        log(f"device time by scope "
+            f"{enginetrace.by_top_scope(eng['device_s_by_scope'])}; by "
+            f"scope path {eng['device_s_by_scope']}")
     return {"run": run, "e2e": e2e, "finished": finished,
             "attempted": len(population), "failed": failed}
 

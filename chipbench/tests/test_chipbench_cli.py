@@ -63,6 +63,11 @@ def test_every_cell_finds_its_files(cell):
         assert callable(spec.metric_reader(name))
     lim = c.limits["rel_rms"]
     assert lim["limit"] > 0
+    assert callable(c.program.build) and callable(c.program.draw_conds)
+    for fn in ("step_modes", "request_flops", "request_attention",
+               "request_block_flops"):
+        assert callable(getattr(c.work, fn))
+    assert callable(c.reference.make_params) and callable(c.reference.sample)
     cfg_file = {x["name"]: x for x in BENCH["configs"]}[
         {w["name"]: w for w in BENCH["workloads"]}[cell]["config"]]["file"]
     assert cfg_file.startswith(BENCH["paths"][0] + "/")

@@ -3,15 +3,16 @@ idle time by engine phase, the device time by scope, the readers built
 on them, and the dense block work they divide by."""
 import hashlib
 import json
+import types
 from pathlib import Path
 
 import pytest
 
-from chipbench import blockwork, devtrace, drive, enginetrace, generator
-from chipbench import peaks, spec, work
+from chipbench import devtrace, drive, enginetrace, generator, peaks, spec
 
 DATA = Path(__file__).resolve().parent / "data"
 CONFIGS = Path(__file__).resolve().parents[1] / "configs"
+work = spec.load_module(CONFIGS / "dit_work.py")
 MS = 1_000_000
 PEAK = peaks.peak_for("TPU v5 lite")
 MLP = "jit(step)/jit(main)/while/body/dit_block/mlp/dot_general"
@@ -202,10 +203,10 @@ def test_block_work_matches_hand_arithmetic():
     c = json.loads((CONFIGS / "dit-xl-2.json").read_text())
     d, L = 1152, 28
     for mode, n in ((0, 256), (1, 64)):
-        assert blockwork.block_flops(c, mode) == pytest.approx(
+        assert work.block_flops(c, mode) == pytest.approx(
             L * (24 * n * d * d + 12 * d * d))
         # with attention, the blocks' whole work
-        assert blockwork.block_flops(c, mode) + L * work.attention_flops(
+        assert work.block_flops(c, mode) + L * work.attention_flops(
             n, d) == pytest.approx(L * work.layer_flops(c, n))
     tiny = json.loads((DATA / "tiny-dit.json").read_text())
     d, f, L = 64, 256, 2
@@ -213,30 +214,30 @@ def test_block_work_matches_hand_arithmetic():
     assert n == 16
     hand = L * (n * (2 * d * 3 * d + 2 * d * d + 2 * 2 * d * f)
                 + 2 * d * 6 * d)
-    assert blockwork.block_flops(tiny, 0) == hand
-    assert blockwork.request_block_flops(tiny, [0, 0, 1], guided=True) \
-        == 2 * (2 * hand + blockwork.block_flops(tiny, 1))
+    assert work.block_flops(tiny, 0) == hand
+    assert work.request_block_flops(tiny, [0, 0, 1], guided=True) \
+        == 2 * (2 * hand + work.block_flops(tiny, 1))
 
 
 def test_window_block_work_counts_the_window_steps():
-    """The same request-steps as ``drive.window_steps``: a request
-    finished before the window counts nothing, one finished in it its
-    steps from the window's start, one in flight up to the window's end."""
+    """The block work of ``drive.window_steps``'s request-steps: a
+    request finished before the window counts nothing, one finished in
+    it its steps from the window's start, one in flight up to the
+    window's end."""
     tiny = json.loads((DATA / "tiny-dit.json").read_text())
     traffic = {"sampler": {"T": 4, "guidance_scale": 1.5}}
+    cell = types.SimpleNamespace(config=tiny, work=work)
 
     def served(rid, finish, budget=1.0):
-        r = generator.Req(index=rid, due_s=0.0, label=1, budget=budget,
+        r = generator.Req(index=rid, due_s=0.0, cond=1, budget=budget,
                           key=None)
         return drive.Served(r, 0.0, 0.0, rid, finish=finish)
 
     reqs = [served(0, 0.5), served(1, 2.0), served(2, None)]
     start, end = {1: 1, 2: 0}, {2: 3}
-    got = blockwork.window_block_flops(tiny, traffic, reqs, start, end,
-                                       t0=1.0)
-    per_step = 2 * blockwork.block_flops(tiny, 0)
-    assert got == pytest.approx((3 + 3) * per_step)
-    steps = drive.window_steps(tiny, traffic, reqs, start, end, 1.0)
+    steps = drive.window_steps(cell, traffic, reqs, start, end, 1.0)
+    per_step = 2 * work.block_flops(tiny, 0)
+    assert steps["block_flops"] == pytest.approx((3 + 3) * per_step)
     assert steps["request_steps"] == 6
 
 

@@ -10,7 +10,7 @@ from pathlib import Path
 import jax
 import pytest
 
-from chipbench import check, control, run, spec
+from chipbench import check, control, devtrace, enginetrace, peaks, run, spec
 
 DATA = Path(__file__).resolve().parent / "data"
 SEED = 2 ** 33 + 5
@@ -94,6 +94,26 @@ def test_control_reads_above_the_limit(prepared):
     assert check.correct(0, checks) is False
     assert checks["rel_rms_max"]["value"] > checks["rel_rms_max"]["limit"]
     assert checks["compared"]["value"] >= 1
+
+
+def test_traced_window_reads_the_engine_split(prepared, monkeypatch):
+    """A traced window's records hold the engine reduction and the block
+    work that the engine readers read. The CPU's trace has no device op
+    line, so the recorded chip excerpt stands in for it."""
+    ex = json.loads((DATA / "trace_excerpt_engine.json").read_text())
+    trace = {k: ex[k] for k in ("device_ops", "host_spans", "engine_spans")}
+    trace["op_names"] = [ex["op_name_table"][i] for i in ex["op_name_ids"]]
+    monkeypatch.setattr(devtrace, "find_xplane", lambda d: d)
+    monkeypatch.setattr(enginetrace, "normalise",
+                        lambda path, device_id=0: trace)
+    r = run.measure(prepared, 1.5, True)["run"]
+    assert r["trace"]["engine"]["engine_spans"] == 42
+    assert r["trace"]["engine"]["idle_in_engine_s"] > 0
+    assert r["work"]["block_flops"] > 0
+    r["peak"] = peaks.peak_for("TPU v5 lite")
+    for name in ("engine_idle_share.open", "sync_wait_share",
+                 "block_matmul_util"):
+        assert spec.metric_reader(name)(r) > 0, name
 
 
 def test_result_line_schema(prepared, capsys):

@@ -88,14 +88,9 @@ def test_recorded_chip_trace():
     assert r["busy_s"] == pytest.approx(0.017423646)
     assert r["kernel_s"]["flash_attention"] == pytest.approx(0.002950526)
     assert "flash_attention" in dict(r["device_ops"])
-    w0 = trace["host_spans"][0][1]
-    w1 = w0 + trace["host_spans"][0][2]
-    clipped = [(n, max(s, w0), min(s + d, w1))
-               for n, s, d in trace["device_ops"]
-               if min(s + d, w1) > max(s, w0)]
-    own = devtrace._self_times(clipped)
-    assert min(t for _n, t in own) >= 0
-    assert sum(t for _n, t in own) / 1e9 == pytest.approx(r["busy_s"])
+    own = devtrace.window_ops(trace).own
+    assert min(own) >= 0
+    assert sum(own) / 1e9 == pytest.approx(r["busy_s"])
     gaps = dict(r["idle_gaps"])
     assert sum(gaps.values()) == pytest.approx(r["window_s"] - r["busy_s"])
     assert max(gaps, key=gaps.get) == "chipbench.wait_arrival"

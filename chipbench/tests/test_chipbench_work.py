@@ -5,9 +5,10 @@ from pathlib import Path
 
 import pytest
 
-from chipbench import work
+from chipbench import spec
 
 CONFIGS = Path(__file__).resolve().parents[1] / "configs"
+work = spec.load_module(CONFIGS / "dit_work.py")
 
 
 def cfg(name):
@@ -43,10 +44,10 @@ def test_attention_work_and_intensity():
 def test_step_schedule_matches_the_program(name, budget):
     """The reference serves the same weak/powerful steps that the
     program resolves for a budget."""
-    from chipbench import drive
     from repro.pipeline import SamplingPlan
+    program = spec.load_module(CONFIGS / "dit_program.py")
     c = cfg(name)
-    mcfg = drive.program_config(c)
+    mcfg = program.model_config(c)
     plan = SamplingPlan(T=50, budget=budget, solver="ddim",
                         guidance_scale=1.5)
     phases = plan.resolve_schedule(mcfg).phases
