@@ -91,11 +91,22 @@ class DiTConfig:
     flex_patch_sizes: Tuple[Tuple[int, int, int], ...] = ((1, 4, 4),)
     # Underlying patch size p' the flexible embed weights are stored at.
     underlying_patch_size: Tuple[int, int, int] = (1, 4, 4)
-    # Conditioning: 'class' (adaLN label embedding), 'text' (cross-attn), 'none'
+    # Conditioning: 'class' (adaLN label embedding), 'text' (cross-attn),
+    # 'joint' (MMDiT, SD3: text tokens run through their own weights and
+    # share every block's attention with the image tokens; the last
+    # block only gives the text's keys and values), 'none'
     conditioning: str = "class"
     num_classes: int = 1000
     text_len: int = 77
     text_dim: int = 0            # 0 → d_model
+    # 'joint': width of the pooled text vector added to the timestep's
+    pooled_dim: int = 0
+    # 'joint' (MMDiT-X): the leading blocks whose image stream also runs
+    # an image-only self-attention
+    dual_attention_layers: int = 0
+    # side of the sin-cos grid positions are cropped from (centred; SD3's
+    # pos_embed_max_size); 0: positions of the latent's own patch centres
+    pos_embed_max_size: int = 0
     learn_sigma: bool = True     # c_out = 2 * c_in
     # LoRA conversion recipe (Sec 3.2); 0 = shared-params recipe (Sec 3.1).
     lora_rank: int = 0

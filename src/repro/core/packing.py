@@ -88,7 +88,9 @@ def packed_mixed_forward(params: Any, cfg: ModelConfig,  # repro: traced
 
     ``groups``: static ``((mode, n_segments), ...)``, one entry per mode;
     ``xs[g]``: [n_g, F, H, W, C] latents; ``ts[g]``: [n_g] timesteps;
-    ``conds[g]``: [n_g] class labels. Rows of ``row_capacity`` tokens
+    ``conds[g]``: [n_g] class labels, or under joint conditioning the
+    pair (context [n_g, text_len, text_dim], pooled [n_g, pooled_dim]).
+    Rows of ``row_capacity`` tokens
     (default: the mode-0 sequence length) are filled first-fit-decreasing,
     attention is block-diagonal via segment ids, and adaLN conditioning is
     applied per token — so each segment's output equals its unpacked NFE.
@@ -116,6 +118,12 @@ def packed_mixed_forward(params: Any, cfg: ModelConfig,  # repro: traced
         raise ValueError("mixed-mode packs need mode-independent blocks "
                          "(LoRA recipe adapters are per-mode); pack "
                          "uniformly or merge/disable LoRA")
+    if cfg.dit.conditioning == "joint":
+        if cache_split is not None:
+            raise ValueError("the activation cache does not serve "
+                             "joint-attention models")
+        return _packed_joint_forward(params, cfg, groups, xs, ts, conds,
+                                     row_capacity, attn_backend)
     block_mode = modes_present[0] if len(modes_present) == 1 else 0
     d = cfg.d_model
     from repro.models.common import dtype_of
@@ -252,6 +260,218 @@ def packed_mixed_forward(params: Any, cfg: ModelConfig,  # repro: traced
     return (outs, new_deltas) if cached else outs
 
 
+@dataclasses.dataclass(frozen=True)
+class _JointRows:
+    """Static row geometry of a joint (MMDiT) pack. The image tokens of
+    every segment sit in one flat array and the text tokens in another,
+    both in row order, so each stream's matmuls run on its real tokens
+    alone; attention lays each row out as [its segments' image tokens |
+    their text tokens | padding]."""
+    img: Tuple[Tuple[int, int], ...]     # per row: range in the image array
+    txt: Tuple[Tuple[int, int], ...]     # per row: range in the text array
+    capacity: int
+    joint_ids: np.ndarray    # [R, C] a request's id on its image and text
+    image_ids: np.ndarray    # [R, C] the same on image tokens only
+
+    def rows(self, img: jax.Array, txt: Optional[jax.Array] = None
+             ) -> jax.Array:
+        """``[R, C, ...]`` rows of the two streams' tokens; text slots are
+        zeros where ``txt`` is None."""
+        out = []
+        for (a, b), (c, e) in zip(self.img, self.txt):
+            parts = [img[a:b]] + ([txt[c:e]] if txt is not None else [])
+            pad = self.capacity - (b - a) - (e - c if txt is not None else 0)
+            if pad:
+                parts.append(jnp.zeros((pad,) + img.shape[1:], img.dtype))
+            out.append(jnp.concatenate(parts) if len(parts) > 1
+                       else parts[0])
+        return jnp.stack(out)
+
+    def unrows(self, rows: jax.Array) -> Tuple[jax.Array, jax.Array]:
+        """The image and the text tokens of ``rows``, as flat arrays."""
+        img, txt = [], []
+        for r, ((a, b), (c, e)) in enumerate(zip(self.img, self.txt)):
+            img.append(rows[r, :b - a])
+            txt.append(rows[r, b - a:b - a + e - c])
+        return jnp.concatenate(img), jnp.concatenate(txt)
+
+
+def _stream_qkv(p: Any, h: jax.Array, H: int, kv_only: bool = False
+                ) -> Tuple[Optional[jax.Array], jax.Array, jax.Array]:
+    """One stream's q, k, v ``[N, H, hd]`` from its modulated tokens
+    ``h`` [N, d], q and k RMS-normed per head where the block has norms;
+    ``kv_only``: no queries."""
+    N, d = h.shape
+
+    def proj(n):
+        return dit_mod._linear(h, p[f"w{n}"], p[f"b{n}"]).reshape(N, H,
+                                                                   d // H)
+    q = None if kv_only else proj("q")
+    k, v = proj("k"), proj("v")
+    if "k_norm" in p:
+        with jax.named_scope("qk_norm"):
+            k = dit_mod._qk_norm(k, p["k_norm"])
+            if q is not None:
+                q = dit_mod._qk_norm(q, p["q_norm"])
+    return q, k, v
+
+
+def _packed_joint_block(p: Any, x: jax.Array, c: jax.Array,
+                        seg_c: jax.Array, img_idx: jax.Array,
+                        txt_idx: jax.Array, geo: _JointRows,
+                        cfg: ModelConfig, attn_backend: str
+                        ) -> Tuple[jax.Array, jax.Array]:
+    """One MMDiT(-X) block over the flat image tokens ``x`` and text
+    tokens ``c`` of a pack (SD3.5's ``JointTransformerBlock``): per-stream
+    adaLN-Zero from each segment's conditioning, one attention over each
+    request's [image; text] tokens, in a dual block an image-only
+    self-attention (``attn2``) on the same normed input, per-stream MLPs.
+    The last block's text stream gives keys and values only."""
+    H, d = cfg.attn.num_heads, cfg.d_model
+    dtype = x.dtype
+    px, pc = p["x"], p["c"]
+    dual, last = "attn2" in px, "mlp" not in pc
+
+    def mod(ada, idx, n):
+        """Each segment's modulation, gathered to its tokens, in n parts."""
+        out = dit_mod._linear(s, ada["w"], ada["b"])
+        return jnp.split(jnp.take(out, idx, axis=0), n, axis=-1)
+
+    def mlp(mp, h):
+        h = dit_mod._linear(h, mp["w_in"], mp["b_in"])
+        h = jax.nn.gelu(h.astype(jnp.float32), approximate=True).astype(dtype)
+        return dit_mod._linear(h, mp["w_out"], mp["b_out"])
+
+    with jax.named_scope("adaln"):
+        s = jax.nn.silu(seg_c.astype(jnp.float32)).astype(dtype)
+        sh1, sc1, g1, sh2, sc2, g2 = mod(px["ada"], img_idx, 6)
+        if dual:
+            sh3, sc3, g3 = mod(px["ada2"], img_idx, 3)
+    with jax.named_scope("context"):
+        if last:            # continuous adaLN: (scale, shift)
+            c_sc, c_sh = mod(pc["ada"], txt_idx, 2)
+            hc = dit_mod._ln(c) * (1.0 + c_sc) + c_sh
+        else:
+            csh1, csc1, cg1, csh2, csc2, cg2 = mod(pc["ada"], txt_idx, 6)
+            hc = dit_mod._ln(c) * (1.0 + csc1) + csh1
+        cq, ck, cv = _stream_qkv(pc["attn"], hc, H, kv_only=last)
+    with jax.named_scope("attn"):
+        nx = dit_mod._ln(x)
+        with jax.named_scope("joint_attn"):
+            q, k, v = _stream_qkv(px["attn"], nx * (1.0 + sc1) + sh1, H)
+            o_img, o_txt = geo.unrows(dit_mod._attend(
+                geo.rows(q, cq), geo.rows(k, ck), geo.rows(v, cv),
+                jnp.asarray(geo.joint_ids), attn_backend=attn_backend))
+            out = x + g1 * dit_mod._linear(o_img.reshape(-1, d),
+                                           px["attn"]["wo"], px["attn"]["bo"])
+        if dual:
+            with jax.named_scope("attn2"):
+                q2, k2, v2 = _stream_qkv(px["attn2"], nx * (1.0 + sc3) + sh3,
+                                         H)
+                o2, _ = geo.unrows(dit_mod._attend(
+                    geo.rows(q2), geo.rows(k2), geo.rows(v2),
+                    jnp.asarray(geo.image_ids), attn_backend=attn_backend))
+                out = out + g3 * dit_mod._linear(
+                    o2.reshape(-1, d), px["attn2"]["wo"], px["attn2"]["bo"])
+        x = out
+    with jax.named_scope("mlp"):
+        x = x + g2 * mlp(px["mlp"], dit_mod._ln(x) * (1.0 + sc2) + sh2)
+    if not last:
+        with jax.named_scope("context"):
+            c = c + cg1 * dit_mod._linear(o_txt.reshape(-1, d),
+                                          pc["attn"]["wo"], pc["attn"]["bo"])
+            c = c + cg2 * mlp(pc["mlp"], dit_mod._ln(c) * (1.0 + csc2)
+                              + csh2)
+    return x, c
+
+
+def _packed_joint_forward(params: Any, cfg: ModelConfig,
+                          groups: Tuple[Tuple[int, int], ...],
+                          xs: Sequence[jax.Array], ts: Sequence[jax.Array],
+                          conds: Sequence[Tuple[jax.Array, jax.Array]],
+                          row_capacity: Optional[int],
+                          attn_backend: str) -> List[jax.Array]:
+    """:func:`packed_mixed_forward` of a joint-attention (MMDiT) model:
+    each segment is one request's image tokens at its patch mode plus its
+    ``text_len`` text tokens, which share one row and one segment id."""
+    from repro.models.common import dtype_of, scan_or_unroll
+    dtype = dtype_of(cfg.compute_dtype)
+    n_txt = cfg.dit.text_len
+    n_img = [dit_mod.tokens_for_mode(cfg, m) for m, _ in groups]
+    capacity = row_capacity or max(dit_mod.segment_tokens(cfg, m)
+                                   for m in [0] + [m for m, _ in groups])
+
+    toks, ctxs, cvecs = [], [], []
+    with jax.named_scope("embed"):
+        ce = params["context_embed"]
+        for g, (mode, _n) in enumerate(groups):
+            context, pooled = conds[g]
+            toks.append(dit_mod.embed_mode_tokens(params, xs[g], cfg, mode))
+            ctxs.append(dit_mod._linear(context.astype(dtype), ce["w"],
+                                        ce["b"]))
+            cvecs.append(dit_mod.joint_condition_vector(params, ts[g], pooled,
+                                                        cfg, dtype))
+
+    segs = [(g, i) for g, (_m, n) in enumerate(groups) for i in range(n)]
+    rows = assign_rows([n_img[g] + n_txt for g, _i in segs], capacity)
+    order = [si for row in rows for si in row]       # segments, row order
+    sizes = [n_img[segs[si][0]] for si in order]
+    img_off = np.concatenate([[0], np.cumsum(sizes)])
+    img_rng, txt_rng, joint_ids, image_ids = [], [], [], []
+    pos = 0
+    for row in rows:
+        a, n = img_off[pos], len(row)
+        b = img_off[pos + n]
+        img_rng.append((int(a), int(b)))
+        txt_rng.append((pos * n_txt, (pos + n) * n_txt))
+        ids = np.full(capacity, -1, np.int32)
+        ids[:b - a] = np.repeat(np.arange(pos, pos + n), sizes[pos:pos + n])
+        ids_img = ids.copy()
+        ids[b - a:b - a + n * n_txt] = np.repeat(np.arange(pos, pos + n),
+                                                 n_txt)
+        joint_ids.append(ids)
+        image_ids.append(ids_img)
+        pos += n
+    geo = _JointRows(tuple(img_rng), tuple(txt_rng), capacity,
+                     np.stack(joint_ids), np.stack(image_ids))
+
+    with jax.named_scope("pack_rows"):
+        x = jnp.concatenate([toks[segs[si][0]][segs[si][1]] for si in order])
+        c = jnp.concatenate([ctxs[segs[si][0]][segs[si][1]] for si in order])
+        seg_c = jnp.stack([cvecs[segs[si][0]][segs[si][1]] for si in order])
+        img_idx = jnp.asarray(np.repeat(np.arange(len(order)), sizes),
+                              jnp.int32)
+        txt_idx = jnp.asarray(np.repeat(np.arange(len(order)), n_txt),
+                              jnp.int32)
+
+    def block(bp, h):
+        with jax.named_scope("dit_block"):
+            return _packed_joint_block(bp, *h, seg_c, img_idx, txt_idx, geo,
+                                       cfg, attn_backend)
+
+    h = (x, c)
+    for key, _n in dit_mod.joint_layer_groups(cfg):
+        if key == "last_block":
+            h = block(params[key], h)
+        else:
+            h, _ = scan_or_unroll(lambda hh, bp: (block(bp, hh), None), h,
+                                  params[key], cfg.unroll)
+
+    with jax.named_scope("final"):
+        ada = dit_mod._linear(
+            jax.nn.silu(seg_c.astype(jnp.float32)).astype(dtype),
+            params["final"]["ada"]["w"], params["final"]["ada"]["b"])
+        scale, shift = jnp.split(jnp.take(ada, img_idx, axis=0), 2, axis=-1)
+        x = dit_mod._ln(h[0]) * (1.0 + scale) + shift
+        at = {segs[si]: int(img_off[k]) for k, si in enumerate(order)}
+        outs: List[jax.Array] = []
+        for g, (mode, n) in enumerate(groups):
+            tok = jnp.stack([x[at[(g, i)]:at[(g, i)] + n_img[g]]
+                             for i in range(n)])
+            outs.append(dit_mod.deembed_mode_tokens(params, tok, cfg, mode))
+    return outs
+
+
 def packed_weak_forward(params: Any, x_ts: jax.Array, t: jax.Array,
                         conds: jax.Array, cfg: ModelConfig, mode: int
                         ) -> jax.Array:
@@ -339,11 +559,16 @@ def packed_row_flops(cfg: ModelConfig, modes: Sequence[int],
     default backend actually issues.
     """
     from repro.kernels.attention import costing
-    seg_tokens = [dit_mod.tokens_for_mode(cfg, m) for m in modes]
+    seg_tokens = [dit_mod.segment_tokens(cfg, m) for m in modes]
     C = capacity if capacity is not None else sum(seg_tokens)
     if sum(seg_tokens) > C:
         raise ValueError(f"segments ({sum(seg_tokens)} tokens) exceed row "
                          f"capacity {C}")
+    if cfg.dit.conditioning == "joint":
+        # a joint pack runs each stream's matmuls on real tokens only
+        # (padding reaches attention alone): its segments' own forwards
+        return float(sum(dit_nfe_flops(cfg, m, attn_backend=attn_backend)
+                         for m in modes))
     d, L = cfg.d_model, cfg.num_layers
     S = len(modes)
     fl = dit_block_flops(cfg, C)
@@ -382,8 +607,8 @@ def mixed_pack_cost(cfg: ModelConfig, modes: Sequence[int],
                     attn_backend: str = "dense") -> MixedPackCost:
     """Cost of packing one segment per entry of ``modes`` into rows of
     ``row_capacity`` tokens (default: the mode-0 length)."""
-    seg_tokens = [dit_mod.tokens_for_mode(cfg, m) for m in modes]
-    capacity = row_capacity or max([dit_mod.tokens_for_mode(cfg, 0)]
+    seg_tokens = [dit_mod.segment_tokens(cfg, m) for m in modes]
+    capacity = row_capacity or max([dit_mod.segment_tokens(cfg, 0)]
                                    + seg_tokens)
     rows = assign_rows(seg_tokens, capacity)
     fl = sum(packed_row_flops(cfg, [modes[i] for i in row], capacity,
@@ -402,8 +627,8 @@ def pack_attention_block_stats(cfg: ModelConfig, modes: Sequence[int],
     as :func:`packed_mixed_forward`). ``1 - active/total`` is the
     cross-segment block skip rate ``serving.metrics`` reports."""
     from repro.kernels.attention import costing
-    seg_tokens = [dit_mod.tokens_for_mode(cfg, m) for m in modes]
-    capacity = row_capacity or max([dit_mod.tokens_for_mode(cfg, 0)]
+    seg_tokens = [dit_mod.segment_tokens(cfg, m) for m in modes]
+    capacity = row_capacity or max([dit_mod.segment_tokens(cfg, 0)]
                                    + seg_tokens)
     rows = assign_rows(seg_tokens, capacity)
     return costing.pack_attention_stats(

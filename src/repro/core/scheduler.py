@@ -65,6 +65,8 @@ def dit_block_flops(cfg: ModelConfig, n_tokens: int,
     device actually issues when the Pallas backend serves the request
     (DESIGN.md §attention-backend).
     """
+    if cfg.dit.conditioning == "joint":
+        return _joint_block_flops(cfg, n_tokens, text_len, attn_backend)
     N = n_tokens
     d, L, f = cfg.d_model, cfg.num_layers, cfg.d_ff
     per_layer = 0.0
@@ -87,6 +89,40 @@ def dit_block_flops(cfg: ModelConfig, n_tokens: int,
     return float(L * per_layer)
 
 
+def _attention_flops(n_q: int, n_k: int, d: int, attn_backend: str) -> float:
+    """QK^T and PV of n_q queries over n_k keys; at the tiles the flash
+    kernel visits under 'pallas'/'auto'."""
+    if attn_backend in ("pallas", "auto"):
+        from repro.kernels.attention import costing
+        return costing.block_sparse_attention_flops([n_k], n_k, d) \
+            * n_q / n_k
+    return 2.0 * 2 * n_q * n_k * d
+
+
+def _joint_block_flops(cfg: ModelConfig, n_img: int,
+                       text_len: Optional[int], attn_backend: str) -> float:
+    """Every MMDiT(-X) block over one request's ``n_img`` image and
+    ``text_len`` text tokens: per stream q/k/v, output projection, MLP and
+    adaLN; one attention over both streams' tokens; in the dual blocks an
+    image-only attention and its adaLN; in the last, text keys and values
+    under a continuous adaLN, and image queries alone."""
+    T = text_len or cfg.dit.text_len
+    d, f, L = cfg.d_model, cfg.d_ff, cfg.num_layers
+    S = n_img + T
+
+    def stream(n):          # q, k, v, out projection, MLP, adaLN
+        return 2 * n * d * 4 * d + 2 * 2 * n * d * f + 2 * d * 6 * d
+
+    full = stream(n_img) + stream(T) + _attention_flops(S, S, d,
+                                                        attn_backend)
+    dual = (2 * n_img * d * 4 * d + 2 * d * 3 * d
+            + _attention_flops(n_img, n_img, d, attn_backend))
+    last = (stream(n_img) + 2 * T * d * 2 * d + 2 * d * 2 * d
+            + _attention_flops(n_img, S, d, attn_backend))
+    return float((L - 1) * full + cfg.dit.dual_attention_layers * dual
+                 + last)
+
+
 def dit_nfe_flops(cfg: ModelConfig, mode: int = 0,
                   text_len: Optional[int] = None,
                   attn_backend: str = "dense") -> float:
@@ -102,6 +138,10 @@ def dit_nfe_flops(cfg: ModelConfig, mode: int = 0,
     total += 2 * N * npix * c_in * d          # embed
     total += 2 * N * d * npix * c_out         # de-embed
     total += 2 * d * 2 * d                    # final adaLN
+    if cfg.dit.conditioning == "joint":
+        T = text_len or cfg.dit.text_len
+        total += 2 * T * (cfg.dit.text_dim or d) * d          # context embed
+        total += 2 * (cfg.dit.pooled_dim * d + d * d)         # pooled MLP
     return float(total)
 
 

@@ -11,6 +11,7 @@ direction so schedulers transfer unchanged; model conditioning uses
 """
 from __future__ import annotations
 
+import dataclasses
 from typing import Callable, List, Optional, Sequence, Tuple
 
 import jax
@@ -19,6 +20,35 @@ import numpy as np
 
 # v_fn(x, tau[B]) -> velocity prediction (= eps - x0 target)
 VFn = Callable[[jax.Array, jax.Array], jax.Array]
+
+
+@dataclasses.dataclass(frozen=True)
+class FlowSchedule:
+    """Discrete rectified-flow schedule of a model trained on
+    ``num_steps`` timesteps, conditioned on σ·``num_steps``, with SD3's
+    resolution shift σ' = shift·σ / (1 + (shift − 1)·σ) (diffusers'
+    ``FlowMatchEulerDiscreteScheduler``)."""
+    num_steps: int = 1000
+    shift: float = 1.0
+
+    def shifted(self, sigma):
+        return self.shift * sigma / (1.0 + (self.shift - 1.0) * sigma)
+
+    def sigmas(self, T: int) -> np.ndarray:
+        """The T descending σ an Euler sampler visits, as the diffusers
+        scheduler's ``set_timesteps`` gives them: evenly spaced between
+        the shifted σ of the first and of the last training timestep,
+        then shifted. The step after the last one lands on σ = 0."""
+        hi, lo = self.shifted(1.0), self.shifted(1.0 / self.num_steps)
+        return self.shifted(np.linspace(hi, lo, T))
+
+
+def euler_step(x: jax.Array, v: jax.Array, sigma: jax.Array,
+               sigma_next: jax.Array) -> jax.Array:
+    """x at ``sigma_next`` from x at ``sigma`` and the velocity v (one
+    σ per batch row)."""
+    ds = (sigma_next - sigma).reshape((-1,) + (1,) * (x.ndim - 1))
+    return x + ds * v
 
 
 def interpolate(x0: jax.Array, eps: jax.Array, tau: jax.Array) -> jax.Array:

@@ -1,9 +1,12 @@
 """Diffusion Transformer (DiT) with FlexiDiT patch-size modes.
 
-Covers the paper's three model classes:
+Covers the paper's three model classes and the joint text-image form:
   * class-conditioned DiT (adaLN-Zero, DiT-XL/2 style)      — cfg.dit.conditioning == 'class'
   * text-conditioned T2I/Emu (cross-attention conditioning) — 'text'
   * video DiT (3D patches; same blocks, longer sequences)   — latent_shape[0] > 1
+  * MMDiT(-X) (SD3 / SD3.5: text and image tokens, each through its own
+    weights, share one attention; dual blocks add image-only
+    self-attention)                                          — 'joint'
 
 A *mode* is an index into ``patch_sizes(cfg) = [p_powerful, *flex sizes]``.
 mode 0 is the pre-trained ("powerful") patch size; higher modes are "weak".
@@ -63,6 +66,13 @@ def tokens_for_mode(cfg: ModelConfig, mode: int) -> int:
     return patch_mod.num_tokens(cfg.dit.latent_shape, patch_sizes(cfg)[mode])
 
 
+def segment_tokens(cfg: ModelConfig, mode: int) -> int:
+    """Tokens one request's NFE puts in a packed row at ``mode``: its
+    image tokens, and under joint conditioning its text tokens too."""
+    n = tokens_for_mode(cfg, mode)
+    return n + cfg.dit.text_len if cfg.dit.conditioning == "joint" else n
+
+
 def c_out_dim(cfg: ModelConfig) -> int:
     c_in = cfg.dit.latent_shape[-1]
     return 2 * c_in if cfg.dit.learn_sigma else c_in
@@ -77,6 +87,81 @@ def _lora_pair(d_in: int, d_out: int, n_new: int, r: int) -> Params:
             "b": ParamSpec((n_new, r, d_out), (None, None, "embed"), init="zeros")}
 
 
+def _qk_norm_schema(cfg: ModelConfig) -> Params:
+    """Learned per-head RMS-norm scales of q and k, where the config
+    states QK-norm."""
+    if not cfg.attn.qk_norm:
+        return {}
+    hd = cfg.attn.head_dim
+    return {"q_norm": ParamSpec((hd,), (None,), init="ones"),
+            "k_norm": ParamSpec((hd,), (None,), init="ones")}
+
+
+def _mlp_schema(cfg: ModelConfig) -> Params:
+    d = cfg.d_model
+    return {"w_in": ParamSpec((d, cfg.d_ff), ("embed", "mlp")),
+            "b_in": ParamSpec((cfg.d_ff,), ("mlp",), init="zeros"),
+            "w_out": ParamSpec((cfg.d_ff, d), ("mlp", "embed")),
+            "b_out": ParamSpec((d,), ("embed",), init="zeros")}
+
+
+def _ada_schema(d: int, n: int) -> Params:
+    return {"w": ParamSpec((d, n * d), ("embed", "mlp"), init="zeros"),
+            "b": ParamSpec((n * d,), ("mlp",), init="zeros")}
+
+
+def _joint_attn_schema(cfg: ModelConfig, kv_only: bool = False) -> Params:
+    """q, k, v and output projections with biases (SD3's ``Attention``);
+    ``kv_only``: keys and values alone."""
+    d = cfg.d_model
+    names = ("k", "v") if kv_only else ("q", "k", "v", "o")
+    s: Params = {}
+    for n in names:
+        s[f"w{n}"] = ParamSpec((d, d), ("embed", "heads") if n != "o"
+                               else ("heads", "embed"))
+        s[f"b{n}"] = ParamSpec((d,), ("embed",), init="zeros")
+    norms = _qk_norm_schema(cfg)
+    if kv_only:
+        norms.pop("q_norm", None)
+    return {**s, **norms}
+
+
+def joint_block_schema(cfg: ModelConfig, dual: bool = False,
+                       last: bool = False) -> Params:
+    """One MMDiT block: an image stream ``x`` and a text stream ``c``,
+    each with its own adaLN-Zero, attention projections and MLP. A dual
+    (MMDiT-X) block's image stream adds ``attn2``, an image-only
+    self-attention, and its three modulations (``ada2``: SD3.5's 9·d
+    adaLN split 6·d + 3·d). The last block's text stream only gives keys
+    and values, under a continuous adaLN (scale, shift)."""
+    d = cfg.d_model
+    x: Params = {"ada": _ada_schema(d, 6), "attn": _joint_attn_schema(cfg),
+                 "mlp": _mlp_schema(cfg)}
+    if dual:
+        x["ada2"] = _ada_schema(d, 3)
+        x["attn2"] = _joint_attn_schema(cfg)
+    if last:
+        c: Params = {"ada": _ada_schema(d, 2),
+                     "attn": _joint_attn_schema(cfg, kv_only=True)}
+    else:
+        c = {"ada": _ada_schema(d, 6), "attn": _joint_attn_schema(cfg),
+             "mlp": _mlp_schema(cfg)}
+    return {"x": x, "c": c}
+
+
+def joint_layer_groups(cfg: ModelConfig) -> Tuple[Tuple[str, int], ...]:
+    """The joint model's blocks, in order, as ``(params key, depth)``
+    groups: the leading dual blocks, the plain ones, the last."""
+    n_dual = cfg.dit.dual_attention_layers
+    n_plain = cfg.num_layers - 1 - n_dual
+    if n_plain < 0:
+        raise ValueError(f"{n_dual} dual blocks leave no last block in "
+                         f"{cfg.num_layers} layers")
+    return tuple((k, n) for k, n in (("dual_blocks", n_dual),
+                                     ("blocks", n_plain), ("last_block", 1))
+                 if n)
+
+
 def dit_block_schema(cfg: ModelConfig) -> Params:
     d = cfg.d_model
     dc = cfg.dit.text_dim or d
@@ -88,11 +173,9 @@ def dit_block_schema(cfg: ModelConfig) -> Params:
         "attn": {"wq": ParamSpec((d, d), ("embed", "heads")),
                  "wk": ParamSpec((d, d), ("embed", "heads")),
                  "wv": ParamSpec((d, d), ("embed", "heads")),
-                 "wo": ParamSpec((d, d), ("heads", "embed"))},
-        "mlp": {"w_in": ParamSpec((d, cfg.d_ff), ("embed", "mlp")),
-                "b_in": ParamSpec((cfg.d_ff,), ("mlp",), init="zeros"),
-                "w_out": ParamSpec((cfg.d_ff, d), ("mlp", "embed")),
-                "b_out": ParamSpec((d,), ("embed",), init="zeros")},
+                 "wo": ParamSpec((d, d), ("heads", "embed")),
+                 **_qk_norm_schema(cfg)},
+        "mlp": _mlp_schema(cfg),
     }
     if cfg.dit.conditioning == "text":
         s["xattn"] = {"wq": ParamSpec((d, d), ("embed", "heads")),
@@ -128,8 +211,25 @@ def dit_schema(cfg: ModelConfig) -> Params:
                     "b2": ParamSpec((d,), ("embed",), init="zeros")},
         "final": {"ada": {"w": ParamSpec((d, 2 * d), ("embed", "mlp"), init="zeros"),
                           "b": ParamSpec((2 * d,), ("mlp",), init="zeros")}},
-        "blocks": stack_schema(dit_block_schema(cfg), cfg.num_layers),
     }
+    if dit.conditioning == "joint":
+        for key, n in joint_layer_groups(cfg):
+            block = joint_block_schema(cfg, dual=key == "dual_blocks",
+                                       last=key == "last_block")
+            s[key] = block if key == "last_block" else stack_schema(block, n)
+        dc, dp = dit.text_dim or d, dit.pooled_dim
+        s["context_embed"] = {"w": ParamSpec((dc, d), (None, "embed")),
+                              "b": ParamSpec((d,), ("embed",), init="zeros")}
+        s["pooled_embed"] = {"w1": ParamSpec((dp, d), (None, "embed")),
+                             "b1": ParamSpec((d,), ("embed",), init="zeros"),
+                             "w2": ParamSpec((d, d), ("embed", "mlp")),
+                             "b2": ParamSpec((d,), ("embed",), init="zeros")}
+        # the null prompt's encoder outputs: CFG's unconditional segment
+        s["null_text"] = {"context": ParamSpec((dit.text_len, dc),
+                                               (None, None), init="zeros"),
+                          "pooled": ParamSpec((dp,), (None,), init="zeros")}
+    else:
+        s["blocks"] = stack_schema(dit_block_schema(cfg), cfg.num_layers)
     if n_modes > 1:
         s["ps_embed"] = ParamSpec((n_modes - 1, d), (None, "embed"), init="zeros")
         s["ps_ln"] = {"scale": ParamSpec((n_modes - 1, d), (None, "embed"), init="zeros"),
@@ -190,6 +290,55 @@ def _modulate(x: jax.Array, shift: jax.Array, scale: jax.Array) -> jax.Array:
     return x * (1.0 + scale[:, None]) + shift[:, None]
 
 
+def _qk_norm(x: jax.Array, scale: jax.Array, eps: float = 1e-6
+             ) -> jax.Array:
+    """RMS norm over each head's ``[..., hd]`` features, times a learned
+    scale (SD3's ``RMSNorm(head_dim, eps=1e-6)``)."""
+    xf = x.astype(jnp.float32)
+    y = xf * jax.lax.rsqrt(jnp.mean(jnp.square(xf), axis=-1, keepdims=True)
+                           + eps)
+    return (y * scale.astype(jnp.float32)).astype(x.dtype)
+
+
+def _attend(q: jax.Array, k: jax.Array, v: jax.Array,
+            segment_ids: Optional[jax.Array] = None, unroll: bool = False,
+            attn_backend: str = "auto") -> jax.Array:
+    """Bidirectional attention of q, k, v ``[B, N, H, hd]`` on the
+    backend ``attn_backend`` resolves to; tokens attend within their
+    segment, ids < 0 mark padding. Returns ``[B, N, H, hd]``."""
+    B, N, H, hd = q.shape
+    from repro.models import attention as attn_mod
+    resolved = attn_mod.resolve_backend(attn_backend, n_tokens=N,
+                                        segmented=segment_ids is not None)
+    if resolved == "pallas":
+        # segment-aware flash kernel with block-sparse cross-segment
+        # skipping: packed rows never issue fully-masked score tiles
+        from repro.kernels.attention import ops as attn_ops
+        return attn_ops.flash_attention(q, k, v, causal=False,
+                                        segment_ids=segment_ids)
+    if resolved == "xla-blocked":
+        # long (possibly packed) video sequences: flash-style blocked path
+        # with q blocks sharded over the model axis; segment ids thread
+        # through so packed CFG never materializes [B,H,N,N] scores
+        from repro.configs.base import AttnConfig
+        acfg = AttnConfig(num_heads=H, num_kv_heads=H, head_dim=hd,
+                          use_rope=False)
+        pos = jnp.broadcast_to(jnp.arange(N, dtype=jnp.int32), (B, N))
+        return attn_mod.blocked_gqa_attend(q, k, v, positions=pos,
+                                           causal=False, window=0, cfg=acfg,
+                                           unroll=unroll,
+                                           segment_ids=segment_ids)
+    scores = jnp.einsum("bqhd,bkhd->bhqk", q, k,
+                        preferred_element_type=jnp.float32) / np.sqrt(hd)
+    if segment_ids is not None:
+        from repro.kernels.attention import mask as mask_mod
+        mask = mask_mod.segment_allowed(segment_ids, segment_ids)
+        scores = scores + jnp.where(mask, 0.0, -1e30)[:, None]
+    probs = jax.nn.softmax(scores, axis=-1)
+    return jnp.einsum("bhqk,bkhd->bqhd", probs.astype(v.dtype), v,
+                      preferred_element_type=jnp.float32).astype(q.dtype)
+
+
 def _mha(p: Params, x: jax.Array, num_heads: int, *,
          lora: Optional[Params] = None, mode: int = 0,
          segment_ids: Optional[jax.Array] = None,
@@ -201,47 +350,18 @@ def _mha(p: Params, x: jax.Array, num_heads: int, *,
     q = _linear(x, p["wq"], lora=la.get("wq"), mode=mode).reshape(B, N, num_heads, hd)
     k = _linear(x, p["wk"], lora=la.get("wk"), mode=mode).reshape(B, N, num_heads, hd)
     v = _linear(x, p["wv"], lora=la.get("wv"), mode=mode).reshape(B, N, num_heads, hd)
+    if "q_norm" in p:                    # the config states QK-norm
+        with jax.named_scope("qk_norm"):
+            q = _qk_norm(q, p["q_norm"])
+            k = _qk_norm(k, p["k_norm"])
     if parallel is not None and parallel.sp > 1:
         # sequence-parallel engine: Ulysses all-to-all / ring attention over
         # the mesh's sequence axis (repro.distributed, DESIGN.md
         # §distributed); padding tokens carry segment id -1. The backend
         # selects the post-all-to-all inner attend (Ulysses).
         o = parallel.attend(q, k, v, segment_ids=segment_ids)
-        return _linear(o.reshape(B, N, d), p["wo"], lora=la.get("wo"),
-                       mode=mode)
-    from repro.models import attention as attn_mod
-    resolved = attn_mod.resolve_backend(attn_backend, n_tokens=N,
-                                        segmented=segment_ids is not None)
-    if resolved == "pallas":
-        # segment-aware flash kernel with block-sparse cross-segment
-        # skipping: packed rows never issue fully-masked score tiles
-        from repro.kernels.attention import ops as attn_ops
-        o = attn_ops.flash_attention(q, k, v, causal=False,
-                                     segment_ids=segment_ids)
-        return _linear(o.reshape(B, N, d), p["wo"], lora=la.get("wo"),
-                       mode=mode)
-    if resolved == "xla-blocked":
-        # long (possibly packed) video sequences: flash-style blocked path
-        # with q blocks sharded over the model axis; segment ids thread
-        # through so packed CFG never materializes [B,H,N,N] scores
-        from repro.configs.base import AttnConfig
-        acfg = AttnConfig(num_heads=num_heads, num_kv_heads=num_heads,
-                          head_dim=hd, use_rope=False)
-        pos = jnp.broadcast_to(jnp.arange(N, dtype=jnp.int32), (B, N))
-        o = attn_mod.blocked_gqa_attend(q, k, v, positions=pos, causal=False,
-                                        window=0, cfg=acfg, unroll=unroll,
-                                        segment_ids=segment_ids)
-        return _linear(o.reshape(B, N, d), p["wo"], lora=la.get("wo"),
-                       mode=mode)
-    scores = jnp.einsum("bqhd,bkhd->bhqk", q, k,
-                        preferred_element_type=jnp.float32) / np.sqrt(hd)
-    if segment_ids is not None:
-        from repro.kernels.attention import mask as mask_mod
-        mask = mask_mod.segment_allowed(segment_ids, segment_ids)
-        scores = scores + jnp.where(mask, 0.0, -1e30)[:, None]
-    probs = jax.nn.softmax(scores, axis=-1)
-    o = jnp.einsum("bhqk,bkhd->bqhd", probs.astype(v.dtype), v,
-                   preferred_element_type=jnp.float32).astype(x.dtype)
+    else:
+        o = _attend(q, k, v, segment_ids, unroll, attn_backend)
     return _linear(o.reshape(B, N, d), p["wo"], lora=la.get("wo"), mode=mode)
 
 
@@ -305,6 +425,52 @@ def _pos_embed_np(latent_shape: Tuple[int, int, int, int], p: Patch,
     return patch_mod.sincos_pos_embed(d, coords)
 
 
+@functools.lru_cache(maxsize=64)
+def _cropped_pos_embed_np(latent_shape: Tuple[int, int, int, int], p: Patch,
+                          p0: Patch, d: int, max_size: int) -> np.ndarray:
+    """SD3's positions (diffusers' ``PatchEmbed`` with
+    ``pos_embed_max_size``): sin-cos features of a ``max_size``² grid
+    whose coordinates run in units of ``max_size / base`` (base: the
+    powerful grid's side), centre-cropped to the powerful grid; the first
+    half of the width encodes the column, the second the row. A coarser
+    patch ``p`` takes the mean coordinate of the powerful patches it
+    covers."""
+    F, H, W, _ = latent_shape
+    if F != 1 or p[0] != 1:
+        raise ValueError("cropped sin-cos positions are for images")
+    gh, gw = H // p0[1], W // p0[2]
+    if max(gh, gw) > max_size:
+        raise ValueError(f"a {gh}x{gw} grid exceeds pos_embed_max_size "
+                         f"{max_size}")
+    scale = gh / max_size
+
+    def coords(n, start, s):
+        return (start + s * np.arange(n) + (s - 1) / 2.0) * scale
+
+    def sincos(pos):
+        omega = 1.0 / 10_000.0 ** (np.arange(d // 4) / (d / 4.0))
+        arg = pos[:, None] * omega[None]
+        return np.concatenate([np.sin(arg), np.cos(arg)], axis=1)
+
+    rows = sincos(coords(H // p[1], (max_size - gh) // 2, p[1] // p0[1]))
+    cols = sincos(coords(W // p[2], (max_size - gw) // 2, p[2] // p0[2]))
+    emb = np.concatenate(
+        [np.broadcast_to(cols[None], (len(rows),) + cols.shape),
+         np.broadcast_to(rows[:, None], (len(rows), len(cols), d // 2))],
+        axis=-1)
+    return emb.reshape(-1, d).astype(np.float32)
+
+
+def pos_embed_np(cfg: ModelConfig, latent_shape: Tuple[int, int, int, int],
+                 mode: int) -> np.ndarray:
+    """Positional embedding ``[N_mode, d]`` of ``mode``'s tokens."""
+    p = patch_sizes(cfg)[mode]
+    if cfg.dit.pos_embed_max_size:
+        return _cropped_pos_embed_np(latent_shape, p, cfg.dit.patch_size,
+                                     cfg.d_model, cfg.dit.pos_embed_max_size)
+    return _pos_embed_np(latent_shape, p, cfg.d_model)
+
+
 def condition_vector(params: Params, t: jax.Array, cond: Any,
                      cfg: ModelConfig, dtype: jnp.dtype) -> jax.Array:
     """c = t_emb (+ class emb). t: [B] float; cond: labels [B] or None."""
@@ -315,6 +481,17 @@ def condition_vector(params: Params, t: jax.Array, cond: Any,
     if cfg.dit.conditioning == "class" and cond is not None:
         te = te + jnp.take(params["class_embed"], cond, axis=0).astype(dtype)
     return te
+
+
+def joint_condition_vector(params: Params, t: jax.Array, pooled: jax.Array,
+                           cfg: ModelConfig, dtype: jnp.dtype) -> jax.Array:
+    """SD3's conditioning vector: the timestep MLP plus the pooled-text
+    MLP. t: [B] float; pooled: [B, pooled_dim]."""
+    pe = params["pooled_embed"]
+    h = _linear(pooled.astype(dtype), pe["w1"], pe["b1"])
+    h = jax.nn.silu(h.astype(jnp.float32)).astype(dtype)
+    return (condition_vector(params, t, None, cfg, dtype)
+            + _linear(h, pe["w2"], pe["b2"]))
 
 
 def embed_mode_tokens(params: Params, x_t: jax.Array, cfg: ModelConfig,
@@ -340,7 +517,7 @@ def embed_mode_tokens(params: Params, x_t: jax.Array, cfg: ModelConfig,
     else:
         tok = patch_mod.embed_tokens_flex(params["embed"]["w_flex"],
                                           params["embed"]["b"], x_t, p, pp)
-    pos = jnp.asarray(_pos_embed_np(ls, p, cfg.d_model), dtype)
+    pos = jnp.asarray(pos_embed_np(cfg, ls, mode), dtype)
     tok = tok + pos[None]
     if mode > 0:
         tok = tok + params["ps_embed"][mode - 1].astype(dtype)[None, None]
@@ -405,6 +582,9 @@ def dit_forward(params: Params, x_t: jax.Array, t: jax.Array, cond: Any,  # repr
     the deep stack's result, not ``shallow + delta`` re-added), which is
     what makes refresh-every-step bit-identical to no cache at all."""
     dit = cfg.dit
+    if dit.conditioning == "joint":
+        raise ValueError("joint-attention (MMDiT) models run through the "
+                         "packed step: core.packing.packed_mixed_forward")
     ls = latent_shape or dit.latent_shape
     dtype = dtype_of(cfg.compute_dtype)
     tok = embed_mode_tokens(params, x_t, cfg, mode, ls)

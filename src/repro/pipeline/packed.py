@@ -16,7 +16,7 @@ matter which requests, denoise steps, or budgets flow through it —
 from __future__ import annotations
 
 import dataclasses
-from typing import Callable, Dict, Optional, Tuple
+from typing import Callable, Dict, Optional, Tuple, Union
 
 import jax
 import jax.numpy as jnp
@@ -24,11 +24,12 @@ import jax.numpy as jnp
 from repro.configs.base import ModelConfig
 from repro.core import packing
 from repro.core.guidance import split_model_out
+from repro.diffusion import flow
 from repro.diffusion import schedule as sch
 from repro.models import dit as dit_mod
 from repro.telemetry import taps as taps_mod
 
-PACKED_SOLVERS = ("ddim", "ddpm")
+PACKED_SOLVERS = ("ddim", "ddpm", "flow_euler")
 
 
 @dataclasses.dataclass(frozen=True)
@@ -66,8 +67,8 @@ class PackLayout:
     def resolve_capacity(self, cfg: ModelConfig) -> int:
         if self.row_capacity:
             return self.row_capacity
-        return max([dit_mod.tokens_for_mode(cfg, 0)]
-                   + [dit_mod.tokens_for_mode(cfg, m) for m, _ in self.groups])
+        return max([dit_mod.segment_tokens(cfg, 0)]
+                   + [dit_mod.segment_tokens(cfg, m) for m, _ in self.groups])
 
     def segment_modes(self) -> Tuple[int, ...]:
         """Flat per-segment mode list (CFG doubling applied)."""
@@ -98,7 +99,9 @@ class PackLayout:
                           row_capacity=row_capacity)
 
 
-def make_packed_step_fn(cfg: ModelConfig, sched: sch.DiffusionSchedule,
+def make_packed_step_fn(cfg: ModelConfig,
+                        sched: Union[sch.DiffusionSchedule,
+                                     flow.FlowSchedule],
                         layout: PackLayout, *, solver: str = "ddim",
                         guidance_scale: float = 1.5,
                         clip_x0: float = 0.0,
@@ -115,6 +118,15 @@ def make_packed_step_fn(cfg: ModelConfig, sched: sch.DiffusionSchedule,
     ``keys[g]`` [k, n_g, 2] uint32 per-request solver keys (DDPM
     ancestral noise; ignored by DDIM). Returns one ``x`` array per group
     after ``k_steps`` solver updates.
+
+    ``flow_euler`` (rectified flow on a ``FlowSchedule``): ``metas[g]``
+    is float32 with rows ``(σ, σ_next, unused)``, σ_next = 0 on the final
+    step; the model sees σ·``sched.num_steps`` and predicts a velocity.
+    A joint-conditioned (MMDiT) model takes one more argument,
+    ``step(params, xs, metas, keys, texts)``: ``texts[g]`` = (context
+    [n_g, text_len, text_dim], pooled [n_g, pooled_dim]), each request's
+    text encoders' outputs; CFG's unconditional segment takes the null
+    prompt's (``params["null_text"]``).
 
     ``k_steps > 1`` runs the packed step body under ``lax.scan`` — the
     engine dispatches K consecutive same-mode denoise steps in one call,
@@ -146,13 +158,41 @@ def make_packed_step_fn(cfg: ModelConfig, sched: sch.DiffusionSchedule,
     ``analysis/jaxpr_audit.py``), and taps join the runner cache key, so
     flipping telemetry never retraces a serving executable.
     """
+    step = _packed_step_fn(cfg, sched, layout, solver=solver,
+                           guidance_scale=guidance_scale, clip_x0=clip_x0,
+                           k_steps=k_steps, cache_split=cache_split,
+                           attn_backend=attn_backend, taps=taps)
+    if cfg.dit.conditioning != "joint":
+        return step
+
+    def joint_step(params, xs, metas, keys, texts):
+        return step((params, tuple(texts)), xs, metas, keys)
+    return joint_step
+
+
+def _packed_step_fn(cfg: ModelConfig,
+                    sched: Union[sch.DiffusionSchedule, flow.FlowSchedule],
+                    layout: PackLayout, *, solver: str, guidance_scale: float,
+                    clip_x0: float, k_steps: int,
+                    cache_split: Optional[int], attn_backend: str,
+                    taps: bool) -> Callable:
+    """:func:`make_packed_step_fn`'s step; a joint model's ``params`` is
+    the pair (weights, texts)."""
     if solver not in PACKED_SOLVERS:
         raise ValueError(f"packed steps support solvers {PACKED_SOLVERS}, "
                          f"got {solver!r}")
-    if cfg.dit.conditioning != "class":
-        raise ValueError("packed steps currently serve class-conditioned "
-                         "DiTs (text conditioning needs per-segment "
-                         "cross-attention plumbing)")
+    if cfg.dit.conditioning not in ("class", "joint"):
+        raise ValueError("packed steps serve class-conditioned and "
+                         "joint-attention DiTs (cross-attention text "
+                         "conditioning needs per-segment plumbing)")
+    is_flow = solver == "flow_euler"
+    if is_flow != isinstance(sched, flow.FlowSchedule):
+        raise ValueError(f"solver {solver!r} does not run on a "
+                         f"{type(sched).__name__}")
+    joint = cfg.dit.conditioning == "joint"
+    if joint and cache_split is not None:
+        raise ValueError("the activation cache does not serve "
+                         "joint-attention models")
     if k_steps < 1:
         raise ValueError(f"k_steps must be >= 1, got {k_steps}")
     if cache_split is not None and not 1 <= cache_split < cfg.num_layers:
@@ -174,19 +214,36 @@ def make_packed_step_fn(cfg: ModelConfig, sched: sch.DiffusionSchedule,
     blk_stats = layout.attention_block_stats(cfg) if taps else None
 
     def one_step(params, xs, metas, keys, deltas=None, refreshes=None):
+        if joint:
+            params, texts = params
         seg_xs, seg_ts, seg_conds = [], [], []
         seg_deltas, seg_refresh = [], []
         for g, (mode, n) in enumerate(groups):
             t_g, cond_g = metas[g][0], metas[g][2]
+            if is_flow:              # float metas: (σ, σ_next, cond)
+                t_g = t_g * sched.num_steps
+                cond_g = cond_g.astype(jnp.int32)
+            if joint:
+                cond_g = texts[g]
+                if guided:
+                    null = params["null_text"]
+                    cond_g = tuple(
+                        jnp.concatenate([a, jnp.broadcast_to(
+                            b.astype(a.dtype)[None], (n,) + b.shape)])
+                        for a, b in zip(cond_g, (null["context"],
+                                                 null["pooled"])))
+                seg_conds.append(cond_g)
             if guided:
                 seg_xs.append(jnp.concatenate([xs[g], xs[g]], axis=0))
                 seg_ts.append(jnp.concatenate([t_g, t_g], axis=0))
-                null = jnp.full((n,), null_label, jnp.int32)
-                seg_conds.append(jnp.concatenate([cond_g, null], axis=0))
+                if not joint:
+                    null = jnp.full((n,), null_label, jnp.int32)
+                    seg_conds.append(jnp.concatenate([cond_g, null], axis=0))
             else:
                 seg_xs.append(xs[g])
                 seg_ts.append(t_g)
-                seg_conds.append(cond_g)
+                if not joint:
+                    seg_conds.append(cond_g)
             if cached:
                 # [n, mult, N, d] → segment order (all cond, then all
                 # uncond) matching seg_xs; both branches share the clock
@@ -226,7 +283,9 @@ def make_packed_step_fn(cfg: ModelConfig, sched: sch.DiffusionSchedule,
                     eps_g, lv = eps, logvar
                 if taps:
                     eps_taps.append(taps_mod.eps_norm_tap(eps_g))
-                if solver == "ddim":
+                if is_flow:
+                    x_prev = flow.euler_step(xs[g], eps_g, t_g, tp_g)
+                elif solver == "ddim":
                     x_prev = sch.ddim_step(sched, xs[g], eps_g, t_g,
                                            tp_g, 0.0, None)
                 else:

@@ -390,6 +390,9 @@ class FlexiPipeline:
         ``result.trace["taps"]`` — same ``x0`` bit-for-bit.
         """
         plan.validate(self.cfg)
+        if plan.solver in FLOW_SOLVERS and plan.guidance_active:
+            raise ValueError("guided flow plans run in the serving engine's "
+                             "packed step (ServingEngine)")
         if x_T is None:
             x_T = jax.random.normal(key, (n,) + self.cfg.dit.latent_shape)
         run_key = jax.random.fold_in(key, 1)

@@ -107,8 +107,12 @@ class SamplingPlan:
                              f"{ADAPTIVE_SOLVERS}, got {self.solver!r}")
         if self.is_adaptive and self.weak_last:
             raise ValueError("weak_last only applies to static budgets")
-        if self.solver in FLOW_SOLVERS and self.guidance_scale != 0.0:
-            raise ValueError("flow solvers are unguided; set guidance_scale=0")
+        if self.solver == "flow_heun" and self.guidance_scale != 0.0:
+            raise ValueError("flow_heun is unguided; set guidance_scale=0")
+        if self.solver in FLOW_SOLVERS and self.guidance_scale != 0.0 \
+                and self.guidance_kind != "uncond":
+            raise ValueError("guided flow plans take vanilla CFG "
+                             "(guidance_kind='uncond')")
         if self.parallel is not None:
             if not isinstance(self.parallel, ParallelSpec):
                 raise ValueError(f"parallel must be a ParallelSpec, got "
@@ -143,7 +147,7 @@ class SamplingPlan:
 
     @property
     def guidance_active(self) -> bool:
-        return self.guidance_scale != 0.0 and self.solver not in FLOW_SOLVERS
+        return self.guidance_scale != 0.0
 
     def validate(self, cfg: ModelConfig) -> None:
         """cfg-dependent checks (mode indices, LoRA availability, budgets)."""

@@ -45,7 +45,7 @@ class BucketMenu:
                  row_capacity: int = 0):
         self.cfg = cfg
         self.guided = guided
-        self.row_capacity = row_capacity or dit_mod.tokens_for_mode(cfg, 0)
+        self.row_capacity = row_capacity or dit_mod.segment_tokens(cfg, 0)
         if max_tokens_per_step < self.row_capacity:
             raise ValueError(
                 f"max_tokens_per_step={max_tokens_per_step} below one row "
@@ -53,7 +53,7 @@ class BucketMenu:
         self.max_tokens = max_tokens_per_step
         self.modes = tuple(sorted(set(modes)))
         mult = 2 if guided else 1
-        self._seg_tokens = {m: dit_mod.tokens_for_mode(cfg, m)
+        self._seg_tokens = {m: dit_mod.segment_tokens(cfg, m)
                             for m in self.modes}
         chains: Dict[int, Tuple[int, ...]] = {}
         for m in self.modes:

@@ -40,11 +40,12 @@ from repro.cache import policy as cache_policy
 from repro.cache.policy import CacheSpec
 from repro.cache.store import CacheStore, TransientAllocationError
 from repro.core.scheduler import dit_nfe_flops
+from repro.diffusion import flow
 from repro.diffusion import schedule as sch
 from repro.models import dit as dit_mod
-from repro.pipeline.packed import PackLayout
+from repro.pipeline.packed import PACKED_SOLVERS, PackLayout
 from repro.pipeline.pipeline import FlexiPipeline
-from repro.pipeline.plan import SamplingPlan
+from repro.pipeline.plan import FLOW_SOLVERS, SamplingPlan
 from repro.serving.batcher import BucketMenu
 from repro.serving.controller import BudgetController
 from repro.serving.metrics import RequestRecord, ServingMetrics
@@ -61,8 +62,8 @@ class LevelPlan:
     """One budget level of the menu, fully resolved for step-wise play."""
     level: float
     plan: SamplingPlan
-    ts: np.ndarray               # descending timestep ladder [T]
-    t_prev: np.ndarray           # ts shifted, -1 terminated [T]
+    ts: np.ndarray               # descending timestep (flow: σ) ladder [T]
+    t_prev: np.ndarray           # ts shifted, -1 (flow: σ = 0) terminated
     modes: np.ndarray            # per-step patch mode [T]
     run_len: np.ndarray          # same-mode steps remaining (incl. self) [T]
     flops: float                 # analytic per-request denoising FLOPs
@@ -78,6 +79,9 @@ class InFlight:
     keys: np.ndarray             # [T, 2] per-step solver keys (host-side)
     admit: float
     seq: int
+    # joint conditioning: the request's (context, pooled) text encoder
+    # outputs on the device, uploaded once at admission
+    text: Optional[Tuple[jax.Array, jax.Array]] = None
     step: int = 0
     # cross-step activation cache (DESIGN.md §cache): this request's OWN
     # staleness clock over its ladder, plus its slot in the engine's
@@ -195,6 +199,12 @@ class ServingEngine:
         self.guidance_scale = ref.guidance_scale
         self.clip_x0 = ref.clip_x0
         self.guided = ref.guidance_active
+        self._flow = self.solver in FLOW_SOLVERS
+        self._text = self.cfg.dit.conditioning == "joint"
+        # flow metas carry σ as float32; the final step's next σ is 0
+        self._meta_dtype = np.float32 if self._flow else np.int32
+        self._final_prev = 0 if self._flow else -1
+        self._zero_texts: Dict[int, Tuple[jax.Array, jax.Array]] = {}
         # one engine = one compiled step family = one attention backend
         # (DESIGN.md §attention-backend); 'auto' resolves to the segment-
         # aware Pallas kernel inside packed steps, so FLOPs accounting
@@ -205,7 +215,8 @@ class ServingEngine:
         for b in sorted(plans):
             plan = plans[b]
             fs = plan.resolve_schedule(self.cfg)
-            ts = sch.respaced_timesteps(pipe.sched.num_steps, plan.T)
+            ts = (pipe.sched.sigmas(plan.T) if self._flow else
+                  sch.respaced_timesteps(pipe.sched.num_steps, plan.T))
             step_modes = np.concatenate(
                 [np.full(n, m, np.int64) for m, n in fs.phases if n])
             run_len = np.ones(len(step_modes), np.int64)
@@ -214,12 +225,12 @@ class ServingEngine:
                     run_len[i] = run_len[i + 1] + 1
             self.levels[b] = LevelPlan(
                 level=b, plan=plan, ts=ts,
-                t_prev=np.concatenate([ts[1:], [-1]]),
+                t_prev=np.concatenate([ts[1:], [self._final_prev]]),
                 modes=step_modes, run_len=run_len,
                 flops=plan.flops(self.cfg))
             modes.update(int(m) for m in step_modes)
         mult = 2 if self.guided else 1
-        self._seg_tokens = {m: dit_mod.tokens_for_mode(self.cfg, m)
+        self._seg_tokens = {m: dit_mod.segment_tokens(self.cfg, m)
                             for m in sorted(modes)}
         if max_tokens_per_step is None:
             max_tokens_per_step = 4 * mult * self._seg_tokens[0]
@@ -284,9 +295,10 @@ class ServingEngine:
     def _validate_menu(self, plans: Dict[float, SamplingPlan]) -> None:
         if not plans:
             raise ValueError("engine needs a non-empty plan menu")
-        if self.cfg.dit is None or self.cfg.dit.conditioning != "class":
-            raise ValueError("the serving engine currently serves "
-                             "class-conditioned DiTs")
+        if self.cfg.dit is None \
+                or self.cfg.dit.conditioning not in ("class", "joint"):
+            raise ValueError("the serving engine serves class-conditioned "
+                             "and joint-attention DiTs")
         if self.cfg.dit.lora_rank > 0:
             raise ValueError("mixed-mode packing needs mode-independent "
                              "blocks (shared-parameter recipe); per-mode "
@@ -297,9 +309,14 @@ class ServingEngine:
             if plan.is_adaptive:
                 raise ValueError("adaptive plans are per-sample host loops; "
                                  "the engine packs static schedules only")
-            if plan.solver not in ("ddim", "ddpm"):
-                raise ValueError(f"engine solvers: ddim|ddpm, got "
+            if plan.solver not in PACKED_SOLVERS:
+                raise ValueError(f"engine solvers: {PACKED_SOLVERS}, got "
                                  f"{plan.solver!r} at level {b}")
+            if (plan.solver in FLOW_SOLVERS) != isinstance(
+                    self.pipe.sched, flow.FlowSchedule):
+                raise ValueError(f"solver {plan.solver!r} at level {b} "
+                                 f"does not run on the pipeline's "
+                                 f"{type(self.pipe.sched).__name__}")
             if plan.parallel is not None:
                 raise ValueError("sequence-parallel plans can't join the "
                                  "packed engine (single-host); route them "
@@ -328,17 +345,21 @@ class ServingEngine:
                 return b
         return max(self.levels)
 
-    def submit(self, cond: int, budget: float,
+    def submit(self, cond: Any, budget: float,
                deadline: float = math.inf,
                key: Optional[jax.Array] = None) -> int:
-        """Enqueue one request; returns its id. ``key`` seeds the prior
-        draw and solver noise (default: derived from the request id)."""
+        """Enqueue one request; returns its id. ``cond``: a class label,
+        or under joint conditioning the pair (context [text_len,
+        text_dim], pooled [pooled_dim]) of the text encoders' outputs.
+        ``key`` seeds the prior draw and solver noise (default: derived
+        from the request id)."""
         rid = self._next_id
         self._next_id += 1
         if key is None:
             key = jax.random.fold_in(self._base_key, rid)
         now = self.clock()
-        req = Request(id=rid, cond=int(cond), budget=float(budget),
+        req = Request(id=rid, cond=cond if self._text else int(cond),
+                      budget=float(budget),
                       deadline=deadline, key=key)
         self._queue.submit(req, now)
         if self.controller is not None:
@@ -411,10 +432,15 @@ class ServingEngine:
                 req.key, (1,) + self.cfg.dit.latent_shape))
             mask = (self._level_masks[level].copy()
                     if self.cache is not None else None)
+            text = None
+            if self._text:
+                with span(self._rec, "upload"):
+                    text = tuple(self._put(np.asarray(a)[None])
+                                 for a in req.cond)
             self._inflight.append(InFlight(
                 req=req, lp=lp, x_src=x_T, x_row=0,
                 keys=self._solver_keys(req.key, lp),
-                admit=now, seq=self._seq, refresh_mask=mask))
+                admit=now, seq=self._seq, refresh_mask=mask, text=text))
             self._seq += 1
 
     def _priority(self, f: InFlight) -> Tuple:
@@ -480,6 +506,21 @@ class ServingEngine:
             parts.append(z)
         return self._put(parts[0] if len(parts) == 1
                          else jnp.concatenate(parts))
+
+    def _gather_text(self, sel: List[InFlight], pad: int
+                     ) -> Tuple[jax.Array, jax.Array]:
+        """A group's (context, pooled) text conditions, ``[cap, ...]``:
+        the requests' own, uploaded at admission, then cached zeros for
+        the dummy tail slots."""
+        if pad and pad not in self._zero_texts:
+            null = self.pipe.params["null_text"]
+            self._zero_texts[pad] = tuple(
+                self._put(np.zeros((pad,) + a.shape, a.dtype))
+                for a in (null["context"], null["pooled"]))
+        parts = [f.text for f in sel] + ([self._zero_texts[pad]]
+                                         if pad else [])
+        return tuple(self._put(jnp.concatenate([p[i] for p in parts]))
+                     for i in range(2))
 
     def _put(self, a) -> jax.Array:
         """A dispatch input on the engine's device."""
@@ -551,12 +592,15 @@ class ServingEngine:
                 k_steps=k, cache_split=self.cache_split,
                 attn_backend=self.attn_backend, taps=self._taps)
             xs, metas, keys, deltas, refreshes = [], [], [], [], []
+            texts = []
             for mode, cap in layout.groups:
                 xs.append(self._put(np.zeros(
                     (cap,) + self.cfg.dit.latent_shape, np.float32)))
-                meta = np.zeros((k, 3, cap), np.int32)
-                meta[:, 1, :] = -1
+                meta = np.zeros((k, 3, cap), self._meta_dtype)
+                meta[:, 1, :] = self._final_prev
                 metas.append(self._put(meta))
+                if self._text:
+                    texts.append(self._gather_text([], cap))
                 keys.append(self._put(np.zeros((k, cap, 2), np.uint32)))
                 if self.cache is not None:
                     deltas.append(self._put(jnp.zeros(
@@ -568,7 +612,7 @@ class ServingEngine:
                              tuple(keys), tuple(deltas), tuple(refreshes))
             else:
                 out = runner(self.pipe.params, tuple(xs), tuple(metas),
-                             tuple(keys))
+                             tuple(keys), *self._text_args(texts))
             jax.block_until_ready(out)
 
     # ------------------------------------------------------------------
@@ -686,15 +730,17 @@ class ServingEngine:
                        inflight=len(self._inflight))
 
         with span(self._rec, "pack") as sp:
-            xs, metas, keys = [], [], []
+            xs, metas, keys, texts = [], [], [], []
             deltas, refreshes, slot_lists, rf_real = [], [], [], []
             real_tokens = 0
             n_refresh = n_cached_steps = 0
             for (mode, cap), sel in zip(layout.groups, picked):
                 pad = cap - len(sel)
                 xs.append(self._gather_latents(sel, pad))
-                meta = np.zeros((k, 3, cap), np.int32)
-                meta[:, 1, :] = -1               # dummy slots: final step
+                if self._text:
+                    texts.append(self._gather_text(sel, pad))
+                meta = np.zeros((k, 3, cap), self._meta_dtype)
+                meta[:, 1, :] = self._final_prev  # dummy slots: final step
                 kk = np.zeros((k, cap, 2), np.uint32)
                 rf = np.zeros((k, cap), bool)    # dummies never refresh
                 slots: List[int] = []
@@ -702,7 +748,8 @@ class ServingEngine:
                     s = f.step
                     meta[:, 0, i] = f.lp.ts[s:s + k]
                     meta[:, 1, i] = f.lp.t_prev[s:s + k]
-                    meta[:, 2, i] = f.req.cond
+                    if not self._text:
+                        meta[:, 2, i] = f.req.cond
                     kk[:, i] = f.keys[s:s + k]
                     if self.cache is not None:
                         if self._ensure_slot(f, mode):
@@ -826,7 +873,7 @@ class ServingEngine:
                 self.metrics.set_cache_bytes(self.store.bytes_resident)
             else:
                 out = runner(self.pipe.params, tuple(xs), tuple(metas),
-                             tuple(keys))
+                             tuple(keys), *self._text_args(texts))
                 (outs, tap) = out if self._taps else (out, None)
                 if self._faults is not None:
                     outs = self._apply_poison(outs, picked)
@@ -926,6 +973,10 @@ class ServingEngine:
                     attribution=self._attr, registry=self._profile)
         self._last_step_at = now
         return finished
+
+    def _text_args(self, texts: List) -> Tuple:
+        """The packed step's text argument, under joint conditioning."""
+        return (tuple(texts),) if self._text else ()
 
     def _observe_profile(self, layout: PackLayout, k: int,
                          picked: List[List[InFlight]], rf_real: List,

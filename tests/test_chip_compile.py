@@ -69,9 +69,11 @@ def compiled_kernels(monkeypatch):
 # (B, S, H, hd, segmented): dit-xl-2 rows of 256 tokens (one patch-2
 # image, or four patch-4 ones), a lone 64-token weak row shorter than one
 # 128-token tile, DiT-XL/2-512's 1,024-token rows, t2i-transformer's 4,096
-# and 1,024 tokens at head dim 128, and video-dit's 33,792 (a 66 x 66
-# block map in SMEM). Segment ids are traced data, so one compile covers
-# every packing.
+# and 1,024 tokens at head dim 128, video-dit's 33,792 (a 66 x 66
+# block map in SMEM), and sd35-medium's 4,429-token rows at head dim 64
+# (a request's 4,096 image and 333 text tokens; not lane-aligned, so
+# padded to the tiles with padding ids). Segment ids are traced data, so
+# one compile covers every packing.
 FLASH_CASES = {
     "xl2-packed-256": (8, 256, 16, 72, True),
     "xl2-dense-256": (8, 256, 16, 72, False),
@@ -83,6 +85,7 @@ FLASH_CASES = {
     "t2i-dense-4096": (2, 4096, 16, 128, False),
     "t2i-packed-1024": (2, 1024, 16, 128, True),
     "video-packed-33792": (1, 33792, 24, 128, True),
+    "sd35-packed-4429": (2, 4429, 24, 64, True),
 }
 
 
@@ -175,4 +178,45 @@ def test_full_depth_packed_step_compiles_and_fits(one_chip, compiled_kernels):
     total = (mem.argument_size_in_bytes + mem.output_size_in_bytes
              + mem.temp_size_in_bytes - mem.alias_size_in_bytes)
     assert mem.argument_size_in_bytes > 1.3e9      # bf16 params, ~675M
+    assert total < device_peaks("TPU v5 lite").hbm_bytes
+
+
+def test_sd35_packed_step_compiles_and_fits(one_chip, compiled_kernels):
+    """sd35-medium's packed flow-Euler step at its published widths and
+    full depth (24 MMDiT-X blocks), two powerful and two weak requests
+    under CFG with their text conditions, compiles with the Mosaic kernel
+    and fits the chip's HBM."""
+    import json
+    from chipbench import spec
+    from repro.diffusion.flow import FlowSchedule
+    from repro.pipeline.packed import PackLayout, make_packed_step_fn
+    path = spec.HERE / "configs" / "sd35-medium.json"
+    cfg = spec.config_module(path, "sd35_program").model_config(
+        json.loads(path.read_text()))
+    layout = PackLayout(groups=((0, 2), (1, 2)), guided=True)
+    step = jax.jit(make_packed_step_fn(cfg, FlowSchedule(1000, 3.0), layout,
+                                       solver="flow_euler",
+                                       guidance_scale=4.5))
+    params = jax.eval_shape(lambda k: dit_mod.init_dit(cfg, k),
+                            jax.random.PRNGKey(0))
+    params = jax.tree.map(lambda a: jax.ShapeDtypeStruct(
+        a.shape, jnp.bfloat16, sharding=one_chip), params)
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    dit = cfg.dit
+    xs = tuple(sds((n,) + dit.latent_shape, jnp.float32)
+               for _m, n in layout.groups)
+    metas = tuple(sds((1, 3, n), jnp.float32) for _m, n in layout.groups)
+    keys = tuple(sds((1, n, 2), jnp.uint32) for _m, n in layout.groups)
+    texts = tuple((sds((n, dit.text_len, dit.text_dim), jnp.bfloat16),
+                   sds((n, dit.pooled_dim), jnp.bfloat16))
+                  for _m, n in layout.groups)
+    compiled = step.lower(params, xs, metas, keys, texts).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    mem = compiled.memory_analysis()
+    total = (mem.argument_size_in_bytes + mem.output_size_in_bytes
+             + mem.temp_size_in_bytes - mem.alias_size_in_bytes)
+    assert mem.argument_size_in_bytes > 4.4e9      # bf16 params, ~2.2B
     assert total < device_peaks("TPU v5 lite").hbm_bytes

@@ -44,21 +44,25 @@ def test_flash_attention_allclose(case):
                                atol=tol, rtol=tol)
 
 
-# DiT rows at head dim 72, as segment lengths per row: a 1,024-token
-# image, four 256-token weak images, a mixed 1,024/256 pack, and a
-# 256-token row of four 64-token weak images (padding fills the rest)
+# DiT rows, as (row length, segment lengths per row, head dim): at head
+# dim 72 a 1,024-token image, four 256-token weak images, a mixed
+# 1,024/256 pack, and a 256-token row of four 64-token weak images
+# (padding fills the rest); at head dim 64 a joint (MMDiT) row whose
+# length is no multiple of the lanes, one segment of 64 + 12 image and
+# text tokens and three of 16 + 12, as sd35-medium's tiny test packs them
 DIT_PACKS = {
-    "one-1024": (1024, [[1024]]),
-    "four-256": (1024, [[256] * 4]),
-    "mixed-1024-256": (1024, [[1024], [256] * 3]),
-    "four-64": (256, [[64] * 4]),
+    "one-1024": (1024, [[1024]], 72),
+    "four-256": (1024, [[256] * 4], 72),
+    "mixed-1024-256": (1024, [[1024], [256] * 3], 72),
+    "four-64": (256, [[64] * 4], 72),
+    "joint-unaligned-hd64": (173, [[76, 28, 28, 28], [28]], 64),
 }
 
 
 @pytest.mark.parametrize("case", list(DIT_PACKS))
 def test_flash_attention_dit_packs(case):
-    S, rows = DIT_PACKS[case]
-    B, H, hd = len(rows), 4, 72
+    S, rows, hd = DIT_PACKS[case]
+    B, H = len(rows), 4
     seg = np.full((B, S), -1, np.int32)
     for r, lengths in enumerate(rows):
         seg[r, :sum(lengths)] = np.repeat(np.arange(len(lengths)), lengths)

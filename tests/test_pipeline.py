@@ -100,7 +100,10 @@ def test_plan_validation_errors(flexi):
     with pytest.raises(ValueError, match="adaptive"):
         SamplingPlan(T=T, budget=AdaptiveBudget(), solver="dpm2")
     with pytest.raises(ValueError, match="unguided"):
-        SamplingPlan(T=T, solver="flow_euler", guidance_scale=1.5)
+        SamplingPlan(T=T, solver="flow_heun", guidance_scale=1.5)
+    with pytest.raises(ValueError, match="vanilla CFG"):
+        SamplingPlan(T=T, solver="flow_euler", guidance_scale=1.5,
+                     guidance_kind="weak_cond")
     with pytest.raises(ValueError, match="modes"):
         SamplingPlan(T=T, weak_mode=3).validate(fcfg)
     with pytest.raises(ValueError, match="LoRA"):
